@@ -21,13 +21,14 @@ scoring and packing each have exactly one implementation.
 """
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from repro.core.partition import FleetSpec, ReplicationSpec
 from repro.data.corpus import synth_corpus, synth_queries
 from repro.parallel import compat
 from repro.search.bm25 import encode_queries
-from repro.search.distributed import build_partitioned_state, make_dist_search_fn
+from repro.search.distributed import (build_partitioned_state,
+                                      dist_state_specs, make_dist_search_fn)
 from repro.search.oracle import OracleSearcher
 from repro.search.service import build_partitioned_search_app
 
@@ -96,9 +97,12 @@ mesh = compat.make_mesh(shape, ("data", "model"))
 fn = make_dist_search_fn(cfg, ("data", "model"), mesh=mesh)
 tids, qtf = encode_queries(vocab, queries, max_terms=cfg.max_terms,
                            idf=state["idf"])
-with compat.use_mesh(mesh):
-    scores, ids = jax.jit(fn)(
-        jax.tree_util.tree_map(jnp.asarray, state), tids, qtf)
+# each device holds its own partition: place the stacked state with the
+# path's NamedShardings instead of copying it whole onto device 0
+specs = dist_state_specs(("data", "model"))
+placed = {name: jax.device_put(arr, NamedSharding(mesh, specs[name]))
+          for name, arr in state.items()}
+scores, ids = jax.jit(fn)(placed, tids, qtf)
 
 for qi, q in enumerate(queries):
     want = [d for d, _ in oracle.search(q, k=10)]

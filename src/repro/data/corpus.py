@@ -32,11 +32,16 @@ def synth_corpus(n_docs: int, *, vocab: int = 5000, mean_len: int = 60,
                  seed: int = 0, zipf_a: float = 1.3) -> list[tuple[str, str]]:
     rng = np.random.default_rng(seed)
     lens = np.maximum(4, rng.lognormal(np.log(mean_len), 0.4, n_docs)).astype(int)
-    docs = []
-    for i in range(n_docs):
-        tids = rng.zipf(zipf_a, lens[i]) % vocab
-        text = " ".join(term_string(int(t)) for t in tids)
-        docs.append((f"doc{i}", text))
+    # one draw for the whole corpus: the Generator's zipf stream is the
+    # same whether it is drawn per document or at once
+    tids = (rng.zipf(zipf_a, int(lens.sum())) % vocab).tolist()
+    used = sorted(set(tids))
+    words = dict(zip(used, map(term_string, used)))
+    ends = np.cumsum(lens).tolist()
+    docs, at = [], 0
+    for i, end in enumerate(ends):
+        docs.append((f"doc{i}", " ".join([words[t] for t in tids[at:end]])))
+        at = end
     return docs
 
 

@@ -412,6 +412,9 @@ class IndexWriter:
             local_df = len(plist)                    # global-vocab-only here
             df = gs["df"].get(term, local_df) if gs else local_df  # global
             idf[ti] = math.log(1.0 + (stat_docs - df + 0.5) / (df + 0.5))
+            if not local_df:                         # no blocks here
+                offsets[ti + 1] = offsets[ti]
+                continue
             docs = np.fromiter(plist.keys(), dtype=np.int32, count=local_df)
             tfs = np.fromiter(plist.values(), dtype=np.int64, count=local_df)
             # per-posting impact for ordering

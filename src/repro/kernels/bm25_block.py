@@ -27,7 +27,8 @@ DEFAULT_BLOCK_ROWS = 8   # rows of (T*M) per grid step; B=128 lanes fixed
 
 
 def _bm25_kernel(tf_ref, dl_ref, idf_ref, params_ref, out_ref):
-    tf = tf_ref[...].astype(jnp.float32)        # (R, B)
+    # Mosaic has no uint8 -> f32 cast; widen through int32 (exact)
+    tf = tf_ref[...].astype(jnp.int32).astype(jnp.float32)   # (R, B)
     dl = dl_ref[...]                            # (R, B)
     idf = idf_ref[...]                          # (R, 1)
     k1, b, avgdl = params_ref[0], params_ref[1], params_ref[2]

@@ -20,8 +20,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.interpret import resolve_interpret
+
 
 DEFAULT_CHUNK = 1024
+# The dense tier serves f32 scores: a default-precision TPU dot rounds the
+# operands to bf16 in one MXU pass, which would break the 1e-5 relative
+# rule against float64 dots. HIGHEST keeps the scores f32-faithful at the
+# cost of several MXU passes per dot. The CPU backend ignores it.
+DOT_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _dot_topk_kernel(q_ref, c_ref, vals_ref, ids_ref, *, k: int, chunk: int,
@@ -30,25 +37,32 @@ def _dot_topk_kernel(q_ref, c_ref, vals_ref, ids_ref, *, k: int, chunk: int,
     q = q_ref[...].astype(jnp.float32)                     # (1, D)
     c = c_ref[...].astype(jnp.float32)                     # (chunk, D)
     s = jax.lax.dot_general(c, q, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)[:, 0]
+                            precision=DOT_PRECISION,
+                            preferred_element_type=jnp.float32)  # (chunk, 1)
     base = ci * chunk
-    idx = jax.lax.broadcasted_iota(jnp.int32, (chunk,), 0)
+    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, vals_ref.shape, 1)
     s = jnp.where(base + idx < n_valid, s, -jnp.inf)       # mask pad rows
 
     def body(i, carry):
-        s_cur, = carry
-        m = jnp.max(s_cur)
-        am = jnp.argmax(s_cur).astype(jnp.int32)
-        vals_ref[i] = m
-        ids_ref[i] = base + am
-        return (jnp.where(idx == am, -jnp.inf, s_cur),)
+        s_cur, vals, ids = carry
+        m = jnp.max(s_cur, axis=0, keepdims=True)          # (1, 1)
+        # first-occurrence argmax, spelled as max + min so Mosaic lowers it
+        am = jnp.min(jnp.where(s_cur == m, idx, chunk), axis=0, keepdims=True)
+        vals = jnp.where(lane == i, m, vals)
+        ids = jnp.where(lane == i, base + am, ids)
+        return jnp.where(idx == am, -jnp.inf, s_cur), vals, ids
 
-    jax.lax.fori_loop(0, k, body, (s,))
+    init = (s, jnp.full(vals_ref.shape, -jnp.inf, jnp.float32),
+            jnp.zeros(ids_ref.shape, jnp.int32))
+    _, vals, ids = jax.lax.fori_loop(0, k, body, init)
+    vals_ref[...] = vals
+    ids_ref[...] = ids
 
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk", "interpret"))
 def dot_topk(query, cands, k: int, *, chunk: int = DEFAULT_CHUNK,
-             interpret: bool = True):
+             interpret: "bool | None" = None):
     """query (D,), cands (N,D) → (vals (k,), ids (k,) i32).
 
     ``chunk`` is NEVER shrunk to N: every grid step scores a full
@@ -66,6 +80,9 @@ def dot_topk(query, cands, k: int, *, chunk: int = DEFAULT_CHUNK,
     n_chunks = (N + pad) // chunk
     q2 = query[None, :]
 
+    # each chunk's survivors land in a (1, kp) lane-aligned row: TPU
+    # blocks must tile (8, 128) or span the array's own trailing dims
+    kp = -(-k // 128) * 128
     vals, ids = pl.pallas_call(
         functools.partial(_dot_topk_kernel, k=k, chunk=chunk, n_valid=N),
         grid=(n_chunks,),
@@ -73,12 +90,14 @@ def dot_topk(query, cands, k: int, *, chunk: int = DEFAULT_CHUNK,
             pl.BlockSpec((1, D), lambda i: (0, 0)),
             pl.BlockSpec((chunk, D), lambda i: (i, 0)),
         ],
-        out_specs=[pl.BlockSpec((k,), lambda i: (i,)),
-                   pl.BlockSpec((k,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((n_chunks * k,), jnp.float32),
-                   jax.ShapeDtypeStruct((n_chunks * k,), jnp.int32)],
-        interpret=interpret,
+        out_specs=[pl.BlockSpec((None, 1, kp), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((None, 1, kp), lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n_chunks, 1, kp), jnp.float32),
+                   jax.ShapeDtypeStruct((n_chunks, 1, kp), jnp.int32)],
+        interpret=resolve_interpret(interpret),
     )(q2, cands)
+    vals = vals[:, 0, :k].reshape(-1)
+    ids = ids[:, 0, :k].reshape(-1)
 
     # mask padded candidates (their score is 0·q = 0, could beat negatives)
     valid = ids < N
@@ -88,7 +107,7 @@ def dot_topk(query, cands, k: int, *, chunk: int = DEFAULT_CHUNK,
 
 
 def dot_topk_batch(queries, cands, k: int, *, chunk: int = DEFAULT_CHUNK,
-                   interpret: bool = True):
+                   interpret: "bool | None" = None):
     """queries (Q, D), cands (N, D) → (vals (Q, k), ids (Q, k) i32).
 
     The fleet's dense micro-batch path. Q-invariant BY CONSTRUCTION: each

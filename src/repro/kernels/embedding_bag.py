@@ -7,8 +7,8 @@ it DMAs one embedding row by dynamic index and accumulates into a VMEM tile:
     out[b] = Σ_l  weight[b,l] · table[idx[b,l]]        (idx < 0 = padding)
 
 Indices/weights ride in SMEM (scalar-addressed); the table stays unblocked
-(memory_space=ANY → HBM on real hardware) and rows are fetched with dynamic
-``pl.load`` — the Pallas expression of FBGEMM's TBE row-gather. On a real
+(memory_space=ANY → HBM on real hardware) and rows are fetched by dynamic
+ref indexing — the Pallas expression of FBGEMM's TBE row-gather. On a real
 TPU deployment the table is additionally row-sharded across devices
 (see repro.models.recsys) so each core gathers from its local shard only.
 """
@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.interpret import resolve_interpret
 
 
 DEFAULT_BLOCK_BAGS = 8
@@ -37,7 +39,7 @@ def _embag_kernel(idx_ref, w_ref, table_ref, out_ref, acc_scr, *,
 
         @pl.when(i >= 0)
         def _():
-            row = pl.load(table_ref, (pl.dslice(i, 1), slice(None)))  # (1, D)
+            row = table_ref[pl.ds(i, 1), :]                      # (1, D)
             w = w_ref[b, l]
             acc_scr[b, :] = acc_scr[b, :] + row[0].astype(jnp.float32) * w
 
@@ -49,7 +51,7 @@ def _embag_kernel(idx_ref, w_ref, table_ref, out_ref, acc_scr, *,
 
 @functools.partial(jax.jit, static_argnames=("block_bags", "interpret"))
 def embedding_bag(table, idx, weights, *, block_bags: int = DEFAULT_BLOCK_BAGS,
-                  interpret: bool = True):
+                  interpret: "bool | None" = None):
     """table (V,D), idx (B,L) i32 (pad<0), weights (B,L) f32 → (B,D) f32."""
     V, D = table.shape
     Bn, L = idx.shape
@@ -66,11 +68,11 @@ def embedding_bag(table, idx, weights, *, block_bags: int = DEFAULT_BLOCK_BAGS,
         in_specs=[
             pl.BlockSpec((bb, L), lambda i: (i, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((bb, L), lambda i: (i, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((bb, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bn + pad, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, D), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(idx, weights.astype(jnp.float32), table)
     return out[:Bn]

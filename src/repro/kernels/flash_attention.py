@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.interpret import resolve_interpret
+
 NEG_INF = float("-inf")
 
 
@@ -89,7 +91,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = False, window: int | None = None,
                     kv_len: int | None = None, sm_scale: float | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: "bool | None" = None):
     """q (B,Hq,Sq,D), k (B,Hkv,Skv,D), v (B,Hkv,Skv,Dv) → (B,Hq,Sq,Dv).
 
     Hq % Hkv == 0; Dv may differ from D (MLA's v_dim ≠ qk_dim)."""
@@ -140,7 +142,7 @@ def flash_attention(q, k, v, *, causal: bool = False, window: int | None = None,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
 
     return of.reshape(B, Hkv, G, Sq, Dv).reshape(B, Hq, Sq, Dv).astype(q.dtype)

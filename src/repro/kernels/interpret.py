@@ -1,31 +1,23 @@
 """Backend-driven interpret-mode selection for the Pallas kernels.
 
 Only the CPU backend has no kernel lowering path — TPU lowers through
-Mosaic and GPU through Triton — so ``interpret`` defaults to
-``jax.default_backend() == "cpu"`` and real accelerators actually compile
-the kernels. ``REPRO_PALLAS_INTERPRET`` overrides both ways (forcing the
-interpreter on device for debugging, or off to smoke-test lowering), and
-every kernel keeps an explicit ``interpret=`` argument for tests.
+Mosaic and GPU through Triton — so ``interpret=None`` resolves to
+``jax.default_backend() == "cpu"`` and real accelerators always compile
+the kernels. Every kernel keeps an explicit ``interpret=`` argument for
+tests, which pin interpret mode on the CPU.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
 
 
-def default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() == "cpu"
-
-
 def resolve_interpret(interpret: "bool | None") -> bool:
-    """``None`` → backend default; an explicit bool always wins.
+    """``None`` → the backend default; an explicit bool always wins.
 
-    Called at trace time (interpret is a static arg), so the env/backend is
-    read once per jit cache entry — pass an explicit value to pin it.
+    Called at trace time (interpret is a static arg), so the backend is
+    read once per jit cache entry.
     """
-    return default_interpret() if interpret is None else bool(interpret)
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)

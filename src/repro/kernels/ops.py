@@ -1,53 +1,20 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""The Pallas kernels' public names.
 
-Interpret-mode selection lives in :mod:`repro.kernels.interpret`: CPU (the
-only backend with no kernel lowering) interprets, TPU/GPU compile, and
-``REPRO_PALLAS_INTERPRET`` overrides both ways. These wrappers just forward
-``interpret=None`` so the kernels resolve the backend default themselves;
-pass ``interpret=`` explicitly to pin a mode.
+Interpret-mode selection lives in :mod:`repro.kernels.interpret`: every
+kernel takes ``interpret: bool | None = None``, which resolves to the
+interpreter on the CPU (the only backend with no kernel lowering) and to
+the compiled kernel everywhere else. Pass ``interpret=`` explicitly to pin
+a mode.
 """
 
 from __future__ import annotations
 
-from repro.kernels.bm25_block import bm25_block_scores as _bm25
-from repro.kernels.bm25_pruned import bm25_pruned_topk as _bm25_pruned
-from repro.kernels.dot_topk import dot_topk as _dot_topk
-from repro.kernels.dot_topk import dot_topk_batch as _dot_topk_batch
-from repro.kernels.embedding_bag import embedding_bag as _embedding_bag
-from repro.kernels.flash_attention import flash_attention as _flash
-from repro.kernels.interpret import default_interpret as _interpret  # noqa: F401  (compat)
-from repro.kernels.topk import topk as _topk
+from repro.kernels.bm25_block import bm25_block_scores
+from repro.kernels.bm25_pruned import bm25_pruned_topk
+from repro.kernels.dot_topk import dot_topk, dot_topk_batch
+from repro.kernels.embedding_bag import embedding_bag
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.topk import topk
 
-
-def bm25_block_scores(tf, dl, idf, k1, b, avgdl, **kw):
-    return _bm25(tf, dl, idf, k1, b, avgdl, **kw)
-
-
-def bm25_pruned_topk(tf, dl, docs, idf_q, ub, valid, k1, b, avgdl, *,
-                     k, n_docs, **kw):
-    return _bm25_pruned(tf, dl, docs, idf_q, ub, valid, k1, b, avgdl,
-                        k=k, n_docs=n_docs, **kw)
-
-
-def topk(scores, k, **kw):
-    return _topk(scores, k, **kw)
-
-
-def dot_topk(query, cands, k, **kw):
-    kw.setdefault("interpret", _interpret())
-    return _dot_topk(query, cands, k, **kw)
-
-
-def dot_topk_batch(queries, cands, k, **kw):
-    kw.setdefault("interpret", _interpret())
-    return _dot_topk_batch(queries, cands, k, **kw)
-
-
-def flash_attention(q, k, v, **kw):
-    kw.setdefault("interpret", _interpret())
-    return _flash(q, k, v, **kw)
-
-
-def embedding_bag(table, idx, weights, **kw):
-    kw.setdefault("interpret", _interpret())
-    return _embedding_bag(table, idx, weights, **kw)
+__all__ = ["bm25_block_scores", "bm25_pruned_topk", "dot_topk",
+           "dot_topk_batch", "embedding_bag", "flash_attention", "topk"]

@@ -69,7 +69,7 @@ def _dot_topk_one_ref(query, cands, k, *, chunk: int = 1024):
         c = jax.lax.dynamic_slice_in_dim(cp, ci * chunk, chunk)
         parts.append(jax.lax.dot_general(
             c.astype(jnp.float32), query.astype(jnp.float32)[None, :],
-            (((1,), (1,)), ((), ())),
+            (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)[:, 0])
     scores = jnp.concatenate(parts)[:N]
     v, i = jax.lax.top_k(scores, k)

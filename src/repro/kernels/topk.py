@@ -27,15 +27,17 @@ DEFAULT_CHUNK = 16384    # f32 chunk = 64KB of VMEM
 def _local_topk_kernel(scores_ref, vals_ref, ids_ref, *, k: int, chunk: int,
                        n_live: int):
     ci = pl.program_id(0)
-    s = scores_ref[...]                                   # (chunk,)
+    s = scores_ref[...]                                   # (1, chunk)
     base = ci * chunk
-    idx = jax.lax.broadcasted_iota(jnp.int32, (chunk,), 0)
+    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, vals_ref.shape, 1)
 
     def body(i, carry):
-        s_cur, = carry
-        m = jnp.max(s_cur)
-        am = jnp.argmax(s_cur).astype(jnp.int32)
-        vals_ref[i] = m
+        s_cur, vals, ids = carry
+        m = jnp.max(s_cur, axis=1, keepdims=True)         # (1, 1)
+        # first-occurrence argmax, spelled as max + min so Mosaic lowers it
+        am = jnp.min(jnp.where(s_cur == m, idx, chunk), axis=1, keepdims=True)
+        vals = jnp.where(lane == i, m, vals)
         # Pad-lane guard: the tail chunk is padded to `chunk` with -inf, so
         # once a round's max is -inf the chunk has no live element left (a
         # padded lane, or a short chunk exhausted by k > live rounds) — emit
@@ -43,11 +45,16 @@ def _local_topk_kernel(scores_ref, vals_ref, ids_ref, *, k: int, chunk: int,
         # points at a live lane (< n_live) because only pads carry -inf at
         # entry. Legit -inf inputs get the same "absent" treatment, matching
         # the sorted accumulator's isfinite convention.
-        ids_ref[i] = jnp.where(m == -jnp.inf, n_live, base + am)
+        ids = jnp.where(lane == i,
+                        jnp.where(m == -jnp.inf, n_live, base + am), ids)
         s_cur = jnp.where(idx == am, -jnp.inf, s_cur)
-        return (s_cur,)
+        return s_cur, vals, ids
 
-    jax.lax.fori_loop(0, k, body, (s,))
+    init = (s, jnp.full(vals_ref.shape, -jnp.inf, jnp.float32),
+            jnp.full(ids_ref.shape, n_live, jnp.int32))
+    _, vals, ids = jax.lax.fori_loop(0, k, body, init)
+    vals_ref[...] = vals
+    ids_ref[...] = ids
 
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk", "interpret"))
@@ -67,16 +74,23 @@ def topk(scores, k: int, *, chunk: int = DEFAULT_CHUNK,
         scores = jnp.pad(scores, (0, pad), constant_values=-jnp.inf)
     n_chunks = (N + pad) // chunk
 
+    # Each chunk is a (1, chunk) row and each chunk's survivors a
+    # (1, kp) lane-aligned row: TPU blocks must tile (8, 128) or span
+    # the array's own trailing dims, which (k,) slices of a flat
+    # output do not.
+    kp = -(-k // 128) * 128
     vals, ids = pl.pallas_call(
         functools.partial(_local_topk_kernel, k=k, chunk=chunk, n_live=N),
         grid=(n_chunks,),
-        in_specs=[pl.BlockSpec((chunk,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((k,), lambda i: (i,)),
-                   pl.BlockSpec((k,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((n_chunks * k,), jnp.float32),
-                   jax.ShapeDtypeStruct((n_chunks * k,), jnp.int32)],
+        in_specs=[pl.BlockSpec((None, 1, chunk), lambda i: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((None, 1, kp), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((None, 1, kp), lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n_chunks, 1, kp), jnp.float32),
+                   jax.ShapeDtypeStruct((n_chunks, 1, kp), jnp.int32)],
         interpret=interpret,
-    )(scores)
+    )(scores.reshape(n_chunks, 1, chunk))
+    vals = vals[:, 0, :k].reshape(-1)
+    ids = ids[:, 0, :k].reshape(-1)
 
     # phase 2: tiny merge
     mv, mi = jax.lax.top_k(vals, k)

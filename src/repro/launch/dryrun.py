@@ -28,7 +28,6 @@ import traceback
 
 import jax
 
-from repro.parallel import compat
 from repro.configs import all_cells, build_cells
 from repro.launch.mesh import make_production_mesh
 
@@ -113,7 +112,7 @@ def run_cell(name: str, cell, mesh, mesh_name: str, out_dir: str,
         shardings = jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), specs,
             is_leaf=lambda x: isinstance(x, P))
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=shardings,
                              donate_argnums=cell.donate)
             lowered = jitted.lower(*args)

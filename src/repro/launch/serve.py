@@ -22,6 +22,7 @@ import numpy as np
 from repro.core.cost import paper_headline_cost
 from repro.core.runtime import RuntimeConfig
 from repro.data.corpus import synth_corpus, synth_queries
+from repro.launch.compile_cache import enable_compile_cache
 from repro.search.searcher import SearchConfig
 from repro.search.service import build_search_app
 
@@ -117,9 +118,10 @@ def main() -> int:
                     help="replica functions per partition (hedged scatter)")
     ap.add_argument("--hedge", type=float, default=0.0)
     ap.add_argument("--kernel", action="store_true",
-                    help="use the Pallas BM25 kernel (interpret on CPU)")
+                    help="use the Pallas BM25 impact kernel")
     args = ap.parse_args()
 
+    enable_compile_cache()
     out = run_partitioned(args) if args.partitions else run_single(args)
     print(json.dumps(out, indent=2))
     return 0

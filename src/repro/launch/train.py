@@ -30,7 +30,6 @@ from repro.data.lm import LMDataConfig, LMTokenStream
 from repro.ft.faults import FailureInjector, StragglerMonitor, run_with_restarts
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.common import init_params
-from repro.parallel import compat
 from repro.parallel.sharding import tree_named
 from repro.train.optim import OptConfig
 from repro.train.steps import init_train_state, make_train_step
@@ -113,7 +112,7 @@ def main() -> int:
     bspec = {"tokens": rules.batch_spec(None), "labels": rules.batch_spec(None)}
     bshard = tree_named(mesh, bspec)
 
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep = jax.jit(step_fn, in_shardings=(shardings, bshard),
                         donate_argnums=(0,))
 

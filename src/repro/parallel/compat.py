@@ -1,10 +1,9 @@
-"""Version-compat shims over the mesh / shard_map API surface.
+"""Mesh / shard_map helpers shared by the distributed paths.
 
-The repo targets the post-0.5 JAX API (``jax.shard_map``, ``jax.set_mesh``
-ambient meshes, ``jax.sharding.AxisType``); CI containers may carry 0.4.x
-where those names live in ``jax.experimental`` or do not exist. These
-helpers pick whichever spelling the installed JAX provides so the
-distributed search path runs on both.
+Spellings over JAX's mesh API (``jax.make_mesh`` with Auto axis types,
+``jax.shard_map`` without the varying-axes check), kept in one place so
+every caller builds meshes and shard_maps the same way. Callers install an
+ambient mesh with ``jax.set_mesh``.
 """
 
 from __future__ import annotations
@@ -12,69 +11,23 @@ from __future__ import annotations
 from typing import Any
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: tuple[int, ...], names: tuple[str, ...]):
-    """``jax.make_mesh`` with Auto axis types when the API knows them."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, names,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, names)
-
-
-def use_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    New JAX: ``jax.set_mesh``. Old JAX: the Mesh object itself is a context
-    manager that installs the thread-resources physical mesh, which
-    :func:`ambient_mesh` (and therefore ``shard_map(mesh=None)``) reads."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def ambient_mesh():
-    """The ambient mesh on old JAX (``with mesh:`` / use_mesh), else None."""
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def shard_map(body, mesh, in_specs: Any, out_specs: Any):
-    """``jax.shard_map`` (check_vma) or the experimental one (check_rep).
-
-    `mesh=None` means "use the ambient mesh" on both APIs: natively on new
-    JAX, and via a call-time :func:`ambient_mesh` lookup (so the caller
-    only needs to be inside ``use_mesh``) on old JAX.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if mesh is not None:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
-    def with_ambient(*args):
-        m = ambient_mesh()
-        if m is None:
-            raise ValueError(
-                "no ambient mesh on this JAX version — wrap the call in "
-                "repro.parallel.compat.use_mesh(mesh) or pass mesh=")
-        return _shard_map(body, mesh=m, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)(*args)
-
-    return with_ambient
+    """``jax.shard_map`` without the varying-manual-axes check; `mesh=None`
+    means "use the ambient mesh"."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def flat_axis_index(axes: tuple[str, ...]):
-    """Row-major flattened index over several mesh axes (works on JAX
-    versions where ``jax.lax.axis_index`` rejects tuples)."""
+    """Row-major flattened index over several mesh axes."""
     import jax.numpy as jnp
     pid = jnp.int32(0)
     for ax in axes:

@@ -146,8 +146,8 @@ def make_dist_search_fn(cfg: DistSearchConfig,
     """Build the shard_map'd global search fn.
 
     fn(state, term_ids (Q,T) i32, qtf (Q,T) f32) -> (scores (Q,k), ids (Q,k)),
-    replicated. Either pass ``mesh`` explicitly, or (on JAX versions with
-    ambient meshes) enter one via ``jax.set_mesh`` / ``compat.use_mesh``;
+    replicated. Either pass ``mesh`` explicitly, or enter one via
+    ``jax.set_mesh``;
     the mesh extent over `axes` must equal cfg.n_parts — one partition per
     device."""
     sspecs = dist_state_specs(axes)
@@ -168,14 +168,8 @@ def make_dist_search_fn(cfg: DistSearchConfig,
                 f"extent over {axes} ({n_dev}) — one partition per device")
 
     def fn(state, term_ids, qtf):
-        if mesh is not None:
-            _check_extent(dict(mesh.shape))
-        elif hasattr(jax.sharding, "get_abstract_mesh"):
-            _check_extent(dict(jax.sharding.get_abstract_mesh().shape))
-        else:
-            ambient = compat.ambient_mesh()
-            if ambient is not None:       # else compat.shard_map raises
-                _check_extent(dict(ambient.shape))
+        m = mesh if mesh is not None else jax.sharding.get_abstract_mesh()
+        _check_extent(dict(m.shape))
         return inner(state, term_ids, qtf)
 
     return fn

@@ -34,7 +34,7 @@ def test_ep_moe_matches_oracle_on_4x2_mesh():
         params = init_params(moe_defs(cfg, jnp.float32), jax.random.PRNGKey(0))
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 4, 16))
         mesh = compat.make_mesh((4, 2), ("data", "model"))
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             y, aux = jax.jit(
                 lambda p, x: ep_moe_ffn(p, x, cfg, mesh=mesh))(params, x)
         y_ref = moe_ffn_dense_oracle(params, x, cfg)
@@ -77,7 +77,7 @@ def test_sharded_train_step_matches_single_device():
         sh = tree_named(mesh, train_state_specs(defs, rules))
         bsh = tree_named(mesh, {"tokens": rules.batch_spec(None),
                                 "labels": rules.batch_spec(None)})
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             state2 = jax.device_put(init_train_state(
                 init_params(defs, jax.random.PRNGKey(0))), sh)
             batch2 = jax.device_put(batch, bsh)
@@ -108,7 +108,7 @@ def test_distributed_search_8_partitions_matches_oracle():
         fn = make_dist_search_fn(cfg, ("data", "model"), mesh=mesh)
         queries = synth_queries(docs, 10, seed=5)
         tids, qtf = encode_queries(vocab, queries, max_terms=cfg.max_terms)
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             scores, ids = jax.jit(fn)(
                 jax.tree_util.tree_map(jnp.asarray, state), tids, qtf)
         for qi, q in enumerate(queries):
@@ -157,13 +157,10 @@ def test_multipod_mesh_cell_lowering_smoke():
         sh = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s),
                                     cell.in_specs,
                                     is_leaf=lambda x: isinstance(x, P))
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(cell.fn, in_shardings=sh,
                                donate_argnums=cell.donate
                                ).lower(*cell.args).compile()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, list):      # 0.4.x returns [dict], newer a dict
-            ca = ca[0]
-        assert ca["flops"] > 0
+        assert compiled.cost_analysis()["flops"] > 0
         print("ok")
     """)

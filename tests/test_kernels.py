@@ -208,26 +208,11 @@ def test_topk_k_exceeds_live_with_neg_inf_inputs():
 
 
 def test_interpret_defaults_to_backend():
-    from repro.kernels.interpret import default_interpret, resolve_interpret
-    import os
-    assert jax.default_backend() == "cpu"     # this container
-    assert default_interpret() is True
+    from repro.kernels.interpret import resolve_interpret
+    assert jax.default_backend() == "cpu"     # tests run on the CPU backend
     assert resolve_interpret(None) is True
     assert resolve_interpret(False) is False  # explicit override wins
     assert resolve_interpret(True) is True
-    old = os.environ.get("REPRO_PALLAS_INTERPRET")
-    try:
-        os.environ["REPRO_PALLAS_INTERPRET"] = "0"
-        assert default_interpret() is False   # env overrides the backend
-        assert resolve_interpret(None) is False
-        assert resolve_interpret(True) is True
-        os.environ["REPRO_PALLAS_INTERPRET"] = "1"
-        assert default_interpret() is True
-    finally:
-        if old is None:
-            os.environ.pop("REPRO_PALLAS_INTERPRET", None)
-        else:
-            os.environ["REPRO_PALLAS_INTERPRET"] = old
 
 
 # -- fused dot + top-k (retrieval) ------------------------------------------------

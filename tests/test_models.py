@@ -164,7 +164,7 @@ def test_sharded_lookup_matches_take():
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     table = jax.random.normal(KEY, (64, 8))
     idx = jax.random.randint(jax.random.PRNGKey(5), (16,), 0, 64)
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = sharded_lookup_shardmap(mesh, table, idx)
     np.testing.assert_allclose(np.asarray(got), np.asarray(table)[idx],
                                rtol=1e-6)

@@ -116,7 +116,7 @@ def test_mesh_path_matches_oracle(corpus, oracle, queries):
     fn = make_dist_search_fn(cfg, ("data", "model"), mesh=mesh)
     tids, qtf = encode_queries(vocab, queries, max_terms=cfg.max_terms,
                                idf=state["idf"])
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         scores, ids = jax.jit(fn)(
             jax.tree_util.tree_map(jax.numpy.asarray, state), tids, qtf)
     for qi, q in enumerate(queries):
@@ -142,7 +142,7 @@ def test_mesh_pruned_bit_identical_to_mesh_dense(corpus, oracle, queries):
         fn = make_dist_search_fn(cfg, ("data", "model"), mesh=mesh)
         tids, qtf = encode_queries(vocab, queries, max_terms=cfg.max_terms,
                                    idf=state["idf"])
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             scores, ids = jax.jit(fn)(
                 jax.tree_util.tree_map(jax.numpy.asarray, state), tids, qtf)
         out[acc] = (np.asarray(scores), np.asarray(ids))

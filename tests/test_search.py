@@ -35,6 +35,24 @@ def _ids(hits):
     return [h[0] for h in hits]
 
 
+@pytest.mark.parametrize("n,kw", [
+    (1, {}), (300, dict(vocab=600, seed=7)),
+    (500, dict(vocab=50, mean_len=10, seed=3, zipf_a=2.0)),
+    (2000, dict(vocab=1 << 19, seed=5)),
+])
+def test_synth_corpus_matches_per_document_draws(n, kw):
+    """The whole-corpus draw reproduces the per-document loop exactly."""
+    from repro.data.corpus import term_string
+    vocab, mean_len = kw.get("vocab", 5000), kw.get("mean_len", 60)
+    rng = np.random.default_rng(kw.get("seed", 0))
+    lens = np.maximum(4, rng.lognormal(np.log(mean_len), 0.4, n)).astype(int)
+    want = []
+    for i in range(n):
+        tids = rng.zipf(kw.get("zipf_a", 1.3), lens[i]) % vocab
+        want.append((f"doc{i}", " ".join(term_string(int(t)) for t in tids)))
+    assert synth_corpus(n, **kw) == want
+
+
 @pytest.mark.parametrize("accumulator", ["dense", "sorted"])
 def test_searcher_matches_oracle(corpus, oracle, packed, accumulator):
     cfg = SearchConfig(max_blocks=64, k=10, accumulator=accumulator)
@@ -126,7 +144,7 @@ def test_distributed_search_matches_oracle(corpus, oracle):
     fn = make_dist_search_fn(cfg, ("data", "model"), mesh=mesh)
     queries = synth_queries(corpus, 8, seed=17)
     tids, qtf = encode_queries(vocab, queries, max_terms=cfg.max_terms)
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         scores, ids = jax.jit(fn)(
             jax.tree_util.tree_map(jax.numpy.asarray, state), tids, qtf)
     for qi, q in enumerate(queries):

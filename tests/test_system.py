@@ -114,6 +114,6 @@ def test_anlessini_reduced_cells_lower_on_host_mesh():
     fn, args, specs = cell.build(mesh)
     sh = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), specs,
                                 is_leaf=lambda x: isinstance(x, P))
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=sh).lower(*args).compile()
     assert compiled is not None
