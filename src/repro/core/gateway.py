@@ -23,8 +23,10 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Any, Callable
 
+from repro.core import trace
 from repro.core.runtime import (FaaSRuntime, InvocationRecord,
                                 RetriesExhausted, nearest_rank_percentiles)
 
@@ -97,10 +99,15 @@ class PendingResponse:
     window is zero); reading ``response`` before then raises — in a
     virtual-clock simulation that is always a driver bug, never a race."""
 
-    __slots__ = ("t_arrival", "_response")
+    __slots__ = ("t_arrival", "t_admitted", "dispatch", "_response")
 
     def __init__(self, t_arrival: float) -> None:
         self.t_arrival = t_arrival
+        # wall time (time.perf_counter) the request joined its window
+        self.t_admitted = 0.0
+        # sequence number of the window it rode: joins the request to that
+        # window's fleet.* spans (repro.core.trace)
+        self.dispatch: int | None = None
         self._response: Response | None = None
 
     def done(self) -> bool:
@@ -174,8 +181,10 @@ class _AdmissionQueue:
         self.policy = policy
         self.pending: list[tuple[Any, PendingResponse]] = []
         self.window_close = 0.0
+        self.window_s = 0.0                 # the open window's chosen length
         self.arrivals: list[float] = []     # trailing-rate history
-        self.waits: list[float] = []        # per-request t_dispatch - t_arrival
+        # the largest t_dispatch - t_arrival so far, on the caller's clock
+        self.max_wait_s = 0.0
         self.batch_sizes: list[int] = []    # per-flush, for introspection
         # backpressure state: consecutive max_batch flushes, the trailing
         # drain history (t_dispatch, batch size), shed arrivals, and the
@@ -207,6 +216,7 @@ class Gateway:
         self._batched: dict[tuple[str, str],
                             tuple[BatchCoordinator, "Callable | None"]] = {}
         self._queues: dict[tuple[str, str], _AdmissionQueue] = {}
+        self._dispatches = 0                # windows flushed, all routes
         # shed-notification hooks (e.g. the autoscaler counting refused
         # demand it would otherwise never see in the invocation records)
         self._on_shed: dict[tuple[str, str], Callable[[float], None]] = {}
@@ -315,16 +325,18 @@ class Gateway:
                 body = annotated
 
         q.arrivals.append(t0)
+        handle.t_admitted = time.perf_counter()
         if not q.pending:
             w = q.policy.window_s(q.rate(t0), self._route_p99(key, q))
+            q.window_s = w
             if w <= 0.0:                # sparse traffic: a lone query never
                 q.pending.append((body, handle))   # waits on a window
-                self._flush_queue(key, t0)
+                self._flush_queue(key, t0, started=handle.t_admitted)
                 return handle
             q.window_close = t0 + w
         q.pending.append((body, handle))
-        if len(q.pending) >= q.policy.max_batch:
-            self._flush_queue(key, t0, hard=True)  # hard cap: dispatch now
+        if len(q.pending) >= q.policy.max_batch:   # hard cap: dispatch now
+            self._flush_queue(key, t0, hard=True, started=handle.t_admitted)
         return handle
 
     def flush(self, now: float | None = None) -> int:
@@ -349,7 +361,13 @@ class Gateway:
             lats[-q.policy.p99_window:], qs=(0.99,))[0.99]
 
     def _flush_queue(self, key: tuple[str, str], t_dispatch: float,
-                     *, hard: bool = False) -> None:
+                     *, hard: bool = False,
+                     started: float | None = None) -> None:
+        """Dispatch the route's open window at ``t_dispatch`` (virtual).
+        ``started`` is the dispatch's wall time when an admission triggered
+        it (that request then waited for no window); else now."""
+        if started is None:
+            started = time.perf_counter()
         q = self._queues[key]
         batch, q.pending = q.pending, []
         q.batch_sizes.append(len(batch))
@@ -376,6 +394,19 @@ class Gateway:
                     t_dispatch + bp.retry_after_s(len(batch), drain))
         else:
             q.hard_flushes = 0          # the arrival process fit its window
+        self._dispatches += 1
+        seq = self._dispatches
+        waits = [started - h.t_admitted for _, h in batch]
+        for _, handle in batch:
+            handle.dispatch = seq
+        with trace.span("dispatch", dispatch=seq, requests=len(batch),
+                        wait_ms_sum=1e3 * sum(waits),
+                        wait_ms_max=1e3 * max(waits),
+                        window_ms=1e3 * q.window_s):
+            self._dispatch(key, q, batch, t_dispatch)
+
+    def _dispatch(self, key: tuple[str, str], q: _AdmissionQueue,
+                  batch: list, t_dispatch: float) -> None:
         coordinator, _ = self._batched[key]
         bodies = [b for b, _ in batch]
         arrivals = [h.t_arrival for _, h in batch]
@@ -398,25 +429,27 @@ class Gateway:
             return
         for (_, handle), (result, disp_lat) in zip(batch, results):
             wait = t_dispatch - handle.t_arrival
-            q.waits.append(wait)
+            q.max_wait_s = max(q.max_wait_s, wait)
             lat = wait + disp_lat + GATEWAY_OVERHEAD_S
             self.latencies.setdefault(key, []).append(lat)
             handle._resolve(Response(200, result, lat))
 
     def window_stats(self, method: str, path: str) -> dict:
         """Introspection for the route's admission queue: flush batch sizes
-        and per-request added waits (a sparse-traffic run must show every
-        wait at exactly zero — the window's no-added-latency contract)."""
+        and ``max_wait_s``, the largest added wait (window close less
+        arrival) on the CALLER's clock — a sparse-traffic run must show it
+        at exactly zero, the window's no-added-latency contract. The wait
+        a request spent on the wall clock is ``fleet.dispatch``'s
+        ``wait_ms_*`` (:mod:`repro.core.trace`)."""
         q = self._queues.get((method.upper(), path))
         if q is None:
             return {"batches": 0, "mean_batch": 0.0, "max_wait_s": 0.0,
-                    "waits": [], "sheds": 0, "hard_flushes": 0}
+                    "sheds": 0, "hard_flushes": 0}
         return {
             "batches": len(q.batch_sizes),
             "mean_batch": (sum(q.batch_sizes) / len(q.batch_sizes)
                            if q.batch_sizes else 0.0),
-            "max_wait_s": max(q.waits, default=0.0),
-            "waits": list(q.waits),
+            "max_wait_s": q.max_wait_s,
             "sheds": len(q.sheds),
             "hard_flushes": q.hard_flushes,
         }

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.core import trace
 from repro.core.runtime import RetriesExhausted, nearest_rank_percentiles
 
 if TYPE_CHECKING:   # type-only: autoscale/gateway/index/search import upward
@@ -504,18 +505,21 @@ class ScatterGather:
         results, records = [], []
         self.last_degraded = []
         first_err: RetriesExhausted | None = None
-        for p, group in enumerate(self.groups):
-            try:
-                result, rec = self._invoke_leg(group, payload, t0)
-            except RetriesExhausted as e:
-                if not self.degraded_ok:
-                    raise
-                first_err = first_err or e
-                self.last_degraded.append(p)
-                results.append(self._degraded_result(payload))
-                continue
-            results.append(result)
-            records.append(rec)
+        with trace.span("scatter"):
+            for p, group in enumerate(self.groups):
+                try:
+                    with trace.span("leg", partition=p) as leg:
+                        result, rec = self._invoke_leg(group, payload, t0)
+                        leg.set_metadata(cold=int(rec.cold))
+                except RetriesExhausted as e:
+                    if not self.degraded_ok:
+                        raise
+                    first_err = first_err or e
+                    self.last_degraded.append(p)
+                    results.append(self._degraded_result(payload))
+                    continue
+                results.append(result)
+                records.append(rec)
         if first_err is not None and not records:
             raise first_err             # nothing survived to answer from
         self._check_generations(results)

@@ -16,7 +16,8 @@ import time
 import jax
 import numpy as np
 
-from repro.core.cache import HydrationCache
+from repro.core import trace
+from repro.core.cache import HydrationCache, pytree_nbytes
 from repro.core.kvstore import KVStore
 from repro.core.object_store import ObjectStore
 from repro.core.refresh import GENERATION_FILE, AssetCatalog, generation_version
@@ -112,11 +113,14 @@ class Searcher:
         # instead of one per distinct batch size.
         Q = len(queries)
         Qp = 1 << max(0, (Q - 1).bit_length())
-        tids, qtf = encode_queries(self.vocab, queries + [""] * (Qp - Q),
-                                   max_terms=self.config.max_terms,
-                                   idf=self.packed.idf)
-        vals, ids = self._fn(self.state, tids, qtf)
-        return np.asarray(vals)[:Q], np.asarray(ids)[:Q]
+        with trace.span("encode", queries=Q):
+            tids, qtf = encode_queries(self.vocab, queries + [""] * (Qp - Q),
+                                       max_terms=self.config.max_terms,
+                                       idf=self.packed.idf)
+        with trace.span("bm25", queries=Q, padded=Qp,
+                        h2d_bytes=trace.host_nbytes(tids, qtf)):
+            vals, ids = self._fn(self.state, tids, qtf)
+            return np.asarray(vals)[:Q], np.asarray(ids)[:Q]
 
     def search_batch(self, queries: list[str],
                      k: int | None = None) -> list[list[tuple[int, float]]]:
@@ -205,11 +209,16 @@ class DenseSearcher:
         # specializes on Q, padding bounds compile variants at O(log batch)
         Qp = 1 << max(0, (Q - 1).bit_length())
         qarr = np.zeros((Qp, self.rows.shape[1]), dtype=np.float32)
-        for i, v in enumerate(qvecs):
-            qarr[i] = np.asarray(v, dtype=np.float32)
-        vals, ids = dot_topk_batch(qarr, self.rows, kk)
-        vals = np.asarray(vals)[:Q]
-        ids = np.asarray(ids)[:Q]
+        # dot_topk_batch makes one call per padded row, each handed its
+        # query row and the whole matrix
+        with trace.span("dense", queries=Q, padded=Qp,
+                        h2d_bytes=trace.host_nbytes(qarr)
+                        + Qp * trace.host_nbytes(self.rows)):
+            for i, v in enumerate(qvecs):
+                qarr[i] = np.asarray(v, dtype=np.float32)
+            vals, ids = dot_topk_batch(qarr, self.rows, kk)
+            vals = np.asarray(vals)[:Q]
+            ids = np.asarray(ids)[:Q]
         out = []
         for qi in range(Q):
             hits = [(int(self.row_internal[i]), float(v))
@@ -287,8 +296,11 @@ class LazyDenseSearcher:
     @property
     def searcher(self) -> DenseSearcher:
         if self._searcher is None:
-            vectors, doc_ids, live = self.lazy.combined()
-            self._searcher = DenseSearcher(vectors, doc_ids, live, self.config)
+            # the rows stay on the host: each search hands them over
+            with trace.span("hydrate", h2d_bytes=0):
+                vectors, doc_ids, live = self.lazy.combined()
+                self._searcher = DenseSearcher(vectors, doc_ids, live,
+                                               self.config)
         return self._searcher
 
 
@@ -389,7 +401,10 @@ class LazySearcher:
     @property
     def searcher(self) -> Searcher:
         if self._searcher is None:
-            self._searcher = Searcher(self.index.packed(), self.config)
+            with trace.span("hydrate") as sp:
+                self._searcher = Searcher(self.index.packed(), self.config)
+                sp.set_metadata(
+                    h2d_bytes=pytree_nbytes(self._searcher.state))
         return self._searcher
 
 
@@ -485,29 +500,41 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
             raise ValueError(f"unknown search mode: {mode!r}")
 
         def _hydrate():
-            if lazy:
-                try:
-                    return lazy_hydrate_searcher(catalog, asset, cfg, version)
-                except SuperIndexMissing:
-                    pass   # pre-lazy-layout segment: eager fallback
-            return hydrate_searcher(catalog, asset, cfg, version)
+            with trace.span("hydrate") as sp:
+                if lazy:
+                    try:
+                        hydrated = lazy_hydrate_searcher(catalog, asset, cfg,
+                                                         version)
+                    except SuperIndexMissing:
+                        pass   # pre-lazy-layout segment: eager fallback
+                    else:
+                        # its state reaches the device once its searcher
+                        # is built (LazySearcher.searcher)
+                        sp.set_metadata(h2d_bytes=0)
+                        return hydrated
+                hydrated = hydrate_searcher(catalog, asset, cfg, version)
+                sp.set_metadata(h2d_bytes=pytree_nbytes(hydrated[0].state))
+                return hydrated
 
         def _hydrate_dense():
             # cached under version+"+vec": HydrationCache.invalidate(asset)
             # drops every version of every key for the asset name, so both
-            # tiers evict together on rollover/budget pressure
-            if lazy:
-                try:
-                    dentry, sim_s = lazy_hydrate_dense_searcher(
-                        catalog, asset, cfg, version)
-                    # the live rows ARE the dense working set — pull them
-                    # inside the hydration charge (header + live spans;
-                    # tombstoned rows never move, so no backfill stage)
-                    _, more = dentry.ensure_live()
-                    return dentry, sim_s + more
-                except SuperIndexMissing:
-                    pass   # pre-lazy vector segment: eager fallback
-            return hydrate_dense_searcher(catalog, asset, cfg, version)
+            # tiers evict together on rollover/budget pressure. The rows
+            # stay on the host: each search hands them over.
+            with trace.span("hydrate", h2d_bytes=0):
+                if lazy:
+                    try:
+                        dentry, sim_s = lazy_hydrate_dense_searcher(
+                            catalog, asset, cfg, version)
+                        # the live rows ARE the dense working set — pull
+                        # them inside the hydration charge (header + live
+                        # spans; tombstoned rows never move, so no
+                        # backfill stage)
+                        _, more = dentry.ensure_live()
+                        return dentry, sim_s + more
+                    except SuperIndexMissing:
+                        pass   # pre-lazy vector segment: eager fallback
+                return hydrate_dense_searcher(catalog, asset, cfg, version)
 
         # Rollover prewarm ping: warm the head-term working set (and the
         # dense tier when asked) without evaluating a query and without
@@ -559,8 +586,9 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
                     # pull exactly the ASTs' term blocks — the same
                     # coalesced ranged GETs bring the v2 field/position
                     # rows along at the wider pitch
-                    changed, sim_s = entry.ensure_terms(
-                        {t for q in queries_ast for t in q.terms})
+                    with trace.span("hydrate", h2d_bytes=0):
+                        changed, sim_s = entry.ensure_terms(
+                            {t for q in queries_ast for t in q.terms})
                     if changed:
                         cache.note_hydration(sim_s)
                     searcher = entry.searcher
@@ -595,7 +623,8 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
                     # critical path, so it accounts as hydration (a warm
                     # instance whose view already covers the terms pays
                     # nothing here)
-                    changed, sim_s = entry.ensure_queries(queries)
+                    with trace.span("hydrate", h2d_bytes=0):
+                        changed, sim_s = entry.ensure_queries(queries)
                     if changed:
                         cache.note_hydration(sim_s)
                     searcher = entry.searcher
@@ -667,8 +696,9 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
         # own ledger line and excludes it from this request's latency
         if (need_sparse and isinstance(entry, LazySearcher)
                 and not entry.full):
-            _, bf_s = entry.backfill()
-            cache.note_backfill(asset, version, bf_s, nbytes=entry.nbytes)
+            with trace.span("backfill"):
+                _, bf_s = entry.backfill()
+                cache.note_backfill(asset, version, bf_s, nbytes=entry.nbytes)
 
         if batched:
             out = {"version": version, "results": results}

@@ -24,6 +24,7 @@ from typing import Callable, Iterable
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.autoscale import AutoscalePolicy, FleetController
 from repro.core.gateway import (BadRequest, Gateway, PendingResponse,
                                 WindowPolicy)
@@ -1000,7 +1001,8 @@ class PartitionedSearchApp:
             h.ext_id for hits in merged for h in hits if h.ext_id is not None)
         if not fetch_docs:
             return {}, 0.0
-        return self.doc_store.batch_get_billed(ext)
+        with trace.span("kv", keys=len(ext)):
+            return self.doc_store.batch_get_billed(ext)
 
     def _materialize(self, hits: list[PartitionHit], raw: dict, *,
                      terms: "list[str] | None" = None,
@@ -1155,20 +1157,22 @@ class PartitionedSearchApp:
                 per_part.append(rr[sub] if sub else rr)
             return per_part
 
-        if mode != "hybrid":
-            return [_merge_hits(tier(qi, None), k) for qi in range(n_q)]
-        out = []
-        for qi in range(n_q):
-            sparse = _merge_hits(tier(qi, None), self.search_k)
-            dense = _merge_hits(tier(qi, "dense"), self.search_k)
-            bykey = {(h.partition, h.doc_id): h for h in dense}
-            bykey.update({(h.partition, h.doc_id): h for h in sparse})
-            fused = rrf_fuse([[(h.partition, h.doc_id) for h in sparse],
-                              [(h.partition, h.doc_id) for h in dense]], k)
-            out.append([PartitionHit(key[1], score, key[0],
-                                     bykey[key].ext_id)
-                        for key, score in fused])
-        return out
+        with trace.span("merge"):
+            if mode != "hybrid":
+                return [_merge_hits(tier(qi, None), k) for qi in range(n_q)]
+            out = []
+            for qi in range(n_q):
+                sparse = _merge_hits(tier(qi, None), self.search_k)
+                dense = _merge_hits(tier(qi, "dense"), self.search_k)
+                bykey = {(h.partition, h.doc_id): h for h in dense}
+                bykey.update({(h.partition, h.doc_id): h for h in sparse})
+                fused = rrf_fuse([[(h.partition, h.doc_id) for h in sparse],
+                                  [(h.partition, h.doc_id) for h in dense]],
+                                 k)
+                out.append([PartitionHit(key[1], score, key[0],
+                                         bykey[key].ext_id)
+                            for key, score in fused])
+            return out
 
     def _search_route(self, body: dict, t_arrival: float | None
                       ) -> tuple[dict, float, InvocationRecord | None]:
@@ -1230,10 +1234,11 @@ class PartitionedSearchApp:
                                                   facet_req)
             return r
 
-        if batched:
-            result: dict = {"results": [_mat(qi) for qi in range(n_q)]}
-        else:
-            result = _mat(0)
+        with trace.span("materialize"):
+            if batched:
+                result: dict = {"results": [_mat(qi) for qi in range(n_q)]}
+            else:
+                result = _mat(0)
         result["partitions"] = [
             {"fn": r.fn, "cold": r.cold, "hydrate_s": r.hydrate_s,
              "backfill_s": r.backfill_s, "latency_s": r.latency_s,
@@ -1377,33 +1382,34 @@ class PartitionedSearchApp:
                 if pb[6] for hits in merged_by_body[bi]]
         raw, fetch_s = self._fetch_raw(need, True) if need else ({}, 0.0)
         out = []
-        for bi, (batched, texts, vecs, mode, n_q, k, fetch_docs, gen,
-                 asts, freq, snip, _favg) in enumerate(per_body):
-            braw = raw if fetch_docs else {}
-            hit_lists = [hits[:k] for hits in merged_by_body[bi]]
+        with trace.span("materialize"):
+            for bi, (batched, texts, vecs, mode, n_q, k, fetch_docs, gen,
+                     asts, freq, snip, _favg) in enumerate(per_body):
+                braw = raw if fetch_docs else {}
+                hit_lists = [hits[:k] for hits in merged_by_body[bi]]
 
-            def _mat(j: int) -> dict:
-                r = self._materialize(
-                    hit_lists[j], braw,
-                    terms=asts[j].terms if asts is not None else None,
-                    snippets=snip)
-                if freq:
-                    r["facets"] = facets_by_body[bi][j]
-                return r
+                def _mat(j: int) -> dict:
+                    r = self._materialize(
+                        hit_lists[j], braw,
+                        terms=asts[j].terms if asts is not None else None,
+                        snippets=snip)
+                    if freq:
+                        r["facets"] = facets_by_body[bi][j]
+                    return r
 
-            if batched:
-                result: dict = {"results": [_mat(j) for j in range(n_q)]}
-            else:
-                result = _mat(0)
-            result["partitions"] = [
-                {"fn": r.fn, "cold": r.cold, "hydrate_s": r.hydrate_s,
-                 "backfill_s": r.backfill_s, "latency_s": r.latency_s,
-                 "hedged": r.hedged}
-                for r in recs_by_body[bi]]
-            if gen is not None:
-                result["generation"] = gen
-            out.append((result,
-                        lat_by_body[bi] + (fetch_s if fetch_docs else 0.0)))
+                if batched:
+                    result: dict = {"results": [_mat(j) for j in range(n_q)]}
+                else:
+                    result = _mat(0)
+                result["partitions"] = [
+                    {"fn": r.fn, "cold": r.cold, "hydrate_s": r.hydrate_s,
+                     "backfill_s": r.backfill_s, "latency_s": r.latency_s,
+                     "hedged": r.hedged}
+                    for r in recs_by_body[bi]]
+                if gen is not None:
+                    result["generation"] = gen
+                out.append((result, lat_by_body[bi]
+                             + (fetch_s if fetch_docs else 0.0)))
         # same control-loop ride-along as the serial path: tick AFTER the
         # window dispatched, so keep-alive pings never race the batch for
         # a pool's idle instance
