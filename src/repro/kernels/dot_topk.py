@@ -18,6 +18,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels.interpret import resolve_interpret
@@ -60,9 +61,17 @@ def _dot_topk_kernel(q_ref, c_ref, vals_ref, ids_ref, *, k: int, chunk: int,
     ids_ref[...] = ids
 
 
-@functools.partial(jax.jit, static_argnames=("k", "chunk", "interpret"))
+def padded_rows(n: int, k: int, chunk: int = DEFAULT_CHUNK) -> int:
+    """Rows ``dot_topk`` scores for ``n`` candidates: ``n`` rounded up to
+    a multiple of its chunk, ``max(chunk, k)``."""
+    chunk = max(chunk, k)
+    return -(-n // chunk) * chunk
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("k", "chunk", "n_valid", "interpret"))
 def dot_topk(query, cands, k: int, *, chunk: int = DEFAULT_CHUNK,
-             interpret: "bool | None" = None):
+             n_valid: "int | None" = None, interpret: "bool | None" = None):
     """query (D,), cands (N,D) → (vals (k,), ids (k,) i32).
 
     ``chunk`` is NEVER shrunk to N: every grid step scores a full
@@ -71,20 +80,37 @@ def dot_topk(query, cands, k: int, *, chunk: int = DEFAULT_CHUNK,
     XLA depends on the row count's alignment — is canonical for any N.
     A 53-row partition and a 207-row full corpus score a shared row to
     IDENTICAL bits, which is what lets a fleet of uneven partitions be
-    checked uint32-bitwise against one full-corpus reference."""
+    checked uint32-bitwise against one full-corpus reference.
+
+    ``n_valid`` says that ``cands`` is already padded with zero rows to
+    ``padded_rows(n_valid, k, chunk)`` and that its first ``n_valid`` rows
+    are the candidates. The program then pads nothing, so a matrix kept on
+    the device is read where it lies; the grid, blocks and masked pad rows
+    are those of the unpadded call, and so are the result's bits."""
     N, D = cands.shape
     chunk = max(chunk, k)
-    pad = (-N) % chunk
+    interpret = resolve_interpret(interpret)
+    if n_valid is None:
+        n_valid = N
+    elif N != padded_rows(n_valid, k, chunk):
+        raise ValueError(f"{N} rows are not {n_valid} candidates padded to "
+                         f"a multiple of {chunk}")
+    elif interpret:
+        # XLA's CPU backend rounds a chunk's dot differently when the pad
+        # fuses into it: the interpreter runs the unpadded call's program
+        cands = cands[:n_valid]
+    pad = padded_rows(n_valid, k, chunk) - cands.shape[0]
     if pad:
         cands = jnp.pad(cands, ((0, pad), (0, 0)))
-    n_chunks = (N + pad) // chunk
+    n_chunks = cands.shape[0] // chunk
     q2 = query[None, :]
 
     # each chunk's survivors land in a (1, kp) lane-aligned row: TPU
     # blocks must tile (8, 128) or span the array's own trailing dims
     kp = -(-k // 128) * 128
     vals, ids = pl.pallas_call(
-        functools.partial(_dot_topk_kernel, k=k, chunk=chunk, n_valid=N),
+        functools.partial(_dot_topk_kernel, k=k, chunk=chunk,
+                          n_valid=n_valid),
         grid=(n_chunks,),
         in_specs=[
             pl.BlockSpec((1, D), lambda i: (0, 0)),
@@ -94,21 +120,23 @@ def dot_topk(query, cands, k: int, *, chunk: int = DEFAULT_CHUNK,
                    pl.BlockSpec((None, 1, kp), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((n_chunks, 1, kp), jnp.float32),
                    jax.ShapeDtypeStruct((n_chunks, 1, kp), jnp.int32)],
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(q2, cands)
     vals = vals[:, 0, :k].reshape(-1)
     ids = ids[:, 0, :k].reshape(-1)
 
     # mask padded candidates (their score is 0·q = 0, could beat negatives)
-    valid = ids < N
+    valid = ids < n_valid
     vals = jnp.where(valid, vals, -jnp.inf)
     mv, mi = jax.lax.top_k(vals, k)
     return mv, ids[mi]
 
 
 def dot_topk_batch(queries, cands, k: int, *, chunk: int = DEFAULT_CHUNK,
+                   n_valid: "int | None" = None,
                    interpret: "bool | None" = None):
-    """queries (Q, D), cands (N, D) → (vals (Q, k), ids (Q, k) i32).
+    """queries (Q, D), cands (N, D) → host arrays (vals (Q, k) f32, ids
+    (Q, k) i32); ``n_valid`` as for :func:`dot_topk`.
 
     The fleet's dense micro-batch path. Q-invariant BY CONSTRUCTION: each
     query dispatches as its own single-query ``dot_topk`` executable
@@ -120,11 +148,10 @@ def dot_topk_batch(queries, cands, k: int, *, chunk: int = DEFAULT_CHUNK,
     making a query's scores depend on how many neighbours shared its
     micro-batch window. Per-program dispatch is what lets windowed fleet
     results be checked uint32-bitwise against the one-query-at-a-time
-    reference oracle."""
+    reference oracle. The results come to the host in one transfer and
+    stack there: a device-side stack would compile a program per Q."""
     if len(queries) == 0:
-        return (jnp.zeros((0, k), jnp.float32),
-                jnp.zeros((0, k), jnp.int32))
-    out = [dot_topk(q, cands, k, chunk=chunk, interpret=interpret)
-           for q in queries]
-    return (jnp.stack([v for v, _ in out]),
-            jnp.stack([i for _, i in out]))
+        return np.zeros((0, k), np.float32), np.zeros((0, k), np.int32)
+    out = jax.device_get([dot_topk(q, cands, k, chunk=chunk, n_valid=n_valid,
+                                   interpret=interpret) for q in queries])
+    return np.stack([v for v, _ in out]), np.stack([i for _, i in out])

@@ -28,7 +28,7 @@ from repro.index.hydration import (LazyIndex, LazyVectors, SuperIndexMissing,
                                    open_partial_segment,
                                    open_partial_vector_segment)
 from repro.index.tokenizer import tokenize
-from repro.kernels.ops import dot_topk_batch
+from repro.kernels.dot_topk import dot_topk_batch, padded_rows
 from repro.search.bm25 import SearchState, encode_queries, make_search_fn
 from repro.search.query import query_from_payload
 from repro.search.structured import (StructuredUnsupported,
@@ -175,12 +175,16 @@ def hydrate_searcher(catalog: AssetCatalog, asset: str,
 class DenseSearcher:
     """Dense-tier twin of :class:`Searcher`: brute-force inner-product
     top-k over one partition's document embeddings via the fused
-    ``dot_topk`` kernel, vmapped over the query micro-batch.
+    ``dot_topk`` kernel, one single-query call per query of a batch.
 
     Tombstoned rows are COMPACTED OUT before scoring (dense scores are
     legitimately negative, so masking-by-zero can't express deletion the
     way the sparse tier's tf-zeroing does); live rows keep their relative
     order, so internal-id ascending tie-breaks match a full rebuild.
+
+    The live rows are padded with zero rows to the kernel's chunk on the
+    host and placed on the device once, here: a search hands the kernel
+    its query rows and nothing of the matrix.
     """
 
     def __init__(self, vectors: np.ndarray, doc_ids: list[str],
@@ -189,36 +193,33 @@ class DenseSearcher:
         self.doc_ids = doc_ids
         self.n_docs = len(doc_ids)
         vecs = np.asarray(vectors, dtype=np.float32)
-        self.rows = np.ascontiguousarray(vecs[np.asarray(live, bool)])
+        live = np.asarray(live, bool)
         self.row_internal = np.flatnonzero(live).astype(np.int32)
-        self.dim = vecs.shape[1] if vecs.ndim == 2 else 0
-        self.nbytes = self.rows.nbytes
+        self.n_live = len(self.row_internal)
+        self.dim = vecs.shape[1]
+        # the kernel's k, which sets its chunk and so the padded layout
+        self.k = min(self.config.k, self.n_live)
+        rows = np.zeros((padded_rows(self.n_live, self.k), self.dim),
+                        np.float32)
+        np.compress(live, vecs, axis=0, out=rows[:self.n_live])
+        self.rows = jax.device_put(rows)
+        # live rows only: the modeled hydration cost and the policy read it
+        self.nbytes = self.n_live * self.dim * 4
 
     def search_batch(self, qvecs, k: int | None = None
                      ) -> list[list[tuple[int, float]]]:
-        """Score Q query vectors in ONE vmapped kernel call; returns
+        """Score Q query vectors, one ``dot_topk`` call each; returns
         per-query [(internal_id, score), ...] — same hit-list shape as the
         sparse tier, so the coordinator merges both identically."""
         Q = len(qvecs)
-        n_live = self.rows.shape[0]
         want = self.config.k if k is None else min(k, self.config.k)
-        if Q == 0 or n_live == 0:
+        if Q == 0 or self.n_live == 0:
             return [[] for _ in range(Q)]
-        kk = min(self.config.k, n_live)
-        # pow-2 batch pad, exactly like the sparse path: the jitted kernel
-        # specializes on Q, padding bounds compile variants at O(log batch)
-        Qp = 1 << max(0, (Q - 1).bit_length())
-        qarr = np.zeros((Qp, self.rows.shape[1]), dtype=np.float32)
-        # dot_topk_batch makes one call per padded row, each handed its
-        # query row and the whole matrix
-        with trace.span("dense", queries=Q, padded=Qp,
-                        h2d_bytes=trace.host_nbytes(qarr)
-                        + Qp * trace.host_nbytes(self.rows)):
-            for i, v in enumerate(qvecs):
-                qarr[i] = np.asarray(v, dtype=np.float32)
-            vals, ids = dot_topk_batch(qarr, self.rows, kk)
-            vals = np.asarray(vals)[:Q]
-            ids = np.asarray(ids)[:Q]
+        qarr = np.asarray(qvecs, dtype=np.float32).reshape(Q, self.dim)
+        with trace.span("dense", queries=Q, padded=Q,
+                        h2d_bytes=trace.host_nbytes(qarr)):
+            vals, ids = dot_topk_batch(qarr, self.rows, self.k,
+                                       n_valid=self.n_live)
         out = []
         for qi in range(Q):
             hits = [(int(self.row_internal[i]), float(v))
@@ -296,11 +297,11 @@ class LazyDenseSearcher:
     @property
     def searcher(self) -> DenseSearcher:
         if self._searcher is None:
-            # the rows stay on the host: each search hands them over
-            with trace.span("hydrate", h2d_bytes=0):
+            with trace.span("hydrate") as sp:
                 vectors, doc_ids, live = self.lazy.combined()
                 self._searcher = DenseSearcher(vectors, doc_ids, live,
                                                self.config)
+                sp.set_metadata(h2d_bytes=self._searcher.rows.nbytes)
         return self._searcher
 
 
@@ -519,9 +520,8 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
         def _hydrate_dense():
             # cached under version+"+vec": HydrationCache.invalidate(asset)
             # drops every version of every key for the asset name, so both
-            # tiers evict together on rollover/budget pressure. The rows
-            # stay on the host: each search hands them over.
-            with trace.span("hydrate", h2d_bytes=0):
+            # tiers evict together on rollover/budget pressure
+            with trace.span("hydrate") as sp:
                 if lazy:
                     try:
                         dentry, sim_s = lazy_hydrate_dense_searcher(
@@ -531,10 +531,16 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
                         # spans; tombstoned rows never move, so no
                         # backfill stage)
                         _, more = dentry.ensure_live()
+                        # its rows reach the device once its searcher is
+                        # built (LazyDenseSearcher.searcher)
+                        sp.set_metadata(h2d_bytes=0)
                         return dentry, sim_s + more
                     except SuperIndexMissing:
                         pass   # pre-lazy vector segment: eager fallback
-                return hydrate_dense_searcher(catalog, asset, cfg, version)
+                hydrated = hydrate_dense_searcher(catalog, asset, cfg,
+                                                  version)
+                sp.set_metadata(h2d_bytes=hydrated[0].rows.nbytes)
+                return hydrated
 
         # Rollover prewarm ping: warm the head-term working set (and the
         # dense tier when asked) without evaluating a query and without

@@ -24,6 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.kernels.dot_topk import padded_rows
 from repro.search.bm25 import SearchState, make_search_fn
 
 W1_CHIP_DOCS = 2_210_456        # 8,841,823 passages / 4 chips
@@ -82,6 +83,21 @@ def test_dot_topk_compiles(one_chip):
     hlo = _compile(lambda q, c: ops.dot_topk(q, c, 10, interpret=False),
                    S((768,)), S((100_000, 768)))
     assert _kernel_compiled(hlo)
+
+
+def test_dot_topk_on_resident_rows_compiles_without_a_pad(one_chip):
+    """The served layout: one partition's live rows (69,077 in the hybrid
+    benchmark's partitions) placed already padded to the chunk, so the
+    program pads no matrix."""
+    S = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                           sharding=one_chip)
+    n = 69_077
+    hlo = _compile(lambda q, c: ops.dot_topk(q, c, 10, n_valid=n,
+                                             interpret=False),
+                   S((768,)), S((padded_rows(n, 10), 768)))
+    assert _kernel_compiled(hlo)
+    assert not [ln for ln in hlo.splitlines()
+                if " pad(" in ln and "768]" in ln]
 
 
 def test_topk_compiles(one_chip):

@@ -16,6 +16,7 @@ The load-bearing invariants:
   merged rankings, reproducible against the two oracles fused the same way.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -26,11 +27,13 @@ from repro.index.builder import (combine_vector_segments, pack_vectors,
                                  read_vector_segment, unpack_vector_superindex,
                                  write_vector_segment)
 from repro.index.hydration import LazyVectors, open_partial_vector_segment
-from repro.kernels.ops import dot_topk_batch
+from repro.kernels import dot_topk as dot_topk_module
+from repro.kernels.dot_topk import padded_rows
+from repro.kernels.ops import dot_topk, dot_topk_batch
 from repro.kernels.ref import dot_topk_batch_ref
 from repro.search.oracle import (DenseOracleSearcher, OracleSearcher,
                                  hybrid_oracle_fuse)
-from repro.search.searcher import SearchConfig
+from repro.search.searcher import DenseSearcher, SearchConfig
 from repro.search.service import build_partitioned_search_app
 
 CFG = SearchConfig(sim_exec_s=0.002, sim_write_s=0.02)
@@ -97,6 +100,59 @@ def test_partition_bits_match_full_corpus_bits():
     pv, pi = dot_topk_batch(q, c[147:], 53)         # uneven tail partition
     for v, i in zip(np.asarray(pv)[0], np.asarray(pi)[0]):
         assert np.float32(v).view(np.uint32) == full[147 + int(i)]
+
+
+@pytest.mark.parametrize("N,k", [(5, 10), (53, 10), (1024, 10), (1091, 10),
+                                 (2300, 1100)])
+def test_dot_topk_on_rows_padded_on_the_host(N, k):
+    """Rows padded with zero rows on the host and placed on the device,
+    scored with ``n_valid``, give the raw rows' bits and ids — also where
+    k > 1,024 makes the chunk k, not 1,024 (2,300 rows → 3,300). k is cut
+    to N, as the searcher cuts it."""
+    k = min(k, N)
+    rng = np.random.default_rng(N + k)
+    c = rng.standard_normal((N, DIM)).astype(np.float32)
+    q = rng.standard_normal((1, DIM)).astype(np.float32)
+    padded = np.zeros((padded_rows(N, k), DIM), np.float32)
+    padded[:N] = c
+    gv, gi = dot_topk(q[0], jax.device_put(padded), k, n_valid=N)
+    rv, ri = dot_topk(q[0], c, k)
+    wv, wi = dot_topk_batch_ref(q, c, k)
+    for v, i in ((rv, ri), (wv[0], wi[0])):
+        assert (np.asarray(gv).view(np.uint32)
+                == np.asarray(v).view(np.uint32)).all()
+        assert (np.asarray(gi) == np.asarray(i)).all()
+
+
+@pytest.mark.parametrize("dead", [False, True], ids=["all-live", "tombstoned"])
+@pytest.mark.parametrize("Q", [1, 3, 5])
+def test_dense_searcher_one_call_a_query_bitwise_vs_ref(Q, dead, monkeypatch):
+    """A batch of Q makes exactly Q ``dot_topk`` calls over the matrix the
+    searcher placed on the device, and its hits are the reference's over
+    the live rows, bit for bit: tombstoned rows are compacted out before
+    the zero-row padding."""
+    rng = np.random.default_rng(Q)
+    n = 70
+    vecs = rng.standard_normal((n, DIM)).astype(np.float32)
+    live = np.ones(n, bool)
+    if dead:
+        live[[0, 3, 4, 41, 69]] = False
+    calls = []
+
+    def counted(*args, **kw):
+        calls.append(kw.get("n_valid"))
+        return dot_topk(*args, **kw)
+
+    ds = DenseSearcher(vecs, [str(i) for i in range(n)], live, CFG)
+    monkeypatch.setattr(dot_topk_module, "dot_topk", counted)
+    q = rng.standard_normal((Q, DIM)).astype(np.float32)
+    hits = ds.search_batch(list(q))
+    assert calls == [int(live.sum())] * Q
+    wv, wi = dot_topk_batch_ref(q, vecs[live], CFG.k)
+    internal = np.flatnonzero(live)
+    for qi in range(Q):
+        assert [h[0] for h in hits[qi]] == internal[wi[qi]].tolist()
+        assert bits([h[1] for h in hits[qi]]) == bits(np.asarray(wv[qi]))
 
 
 # -- segment level: pack/write/read, quantization, lazy rows --------------------

@@ -4,12 +4,15 @@ profiler trace of a tiny partitioned fleet serving a few admission windows.
 The contract under test: every ``fleet.`` span appears, nested as the
 served path nests (dispatch ⊃ scatter ⊃ leg ⊃ encode, bm25 | dense;
 dispatch ⊃ merge, kv, materialize); each device call counts the queries
-the gateway batched, the power of two it padded them to and the bytes of
-the host arrays it handed over (an array already on the device counts
-none); a window-0 dispatch waited for nothing; and each request keeps the
-number of the window it rode.
+the gateway batched, the power of two the BM25 call padded them to (the
+dense tier makes one call a query, so pads none) and the bytes of the host
+arrays it handed over (an array already on the device counts none); a
+dense hydration counts its matrix placed on the device once; a window-0
+dispatch waited for nothing; and each request keeps the number of the
+window it rode.
 """
 
+from collections import Counter
 from pathlib import Path
 
 import jax
@@ -19,6 +22,7 @@ import pytest
 from repro.core import trace
 from repro.core.partition import FleetSpec, IndexSpec, VectorSpec
 from repro.core.runtime import RuntimeConfig
+from repro.kernels.dot_topk import padded_rows
 from repro.data.corpus import synth_corpus, synth_queries
 from repro.search.searcher import DenseSearcher, SearchConfig
 from repro.search.service import build_partitioned_search_app
@@ -116,8 +120,6 @@ def test_calls_count_batch_padding_and_host_bytes(served):
     mode, app, _, spans = served
     dispatches = _by_name(spans, "dispatch")
     assert [d[3]["requests"] for d in dispatches] == [1, 5, 1, 3]
-    offsets = app.indexer.part_doc_offsets() + [120]
-    rows = np.diff(offsets)
     for d in dispatches:
         q = d[3]["requests"]
         padded = 1 << max(0, (q - 1).bit_length())
@@ -128,11 +130,12 @@ def test_calls_count_batch_padding_and_host_bytes(served):
             assert s[3]["h2d_bytes"] == padded * CFG.max_terms * (4 + 4)
         if mode == "hybrid":
             dense = [s for s in _by_name(spans, "dense") if _inside(s, d)]
-            for p, s in enumerate(dense):
-                assert (s[3]["queries"], s[3]["padded"]) == (q, padded)
-                # each padded row's call is handed its query row and the
-                # partition's whole f32 matrix
-                assert s[3]["h2d_bytes"] == padded * (DIM + rows[p] * DIM) * 4
+            assert len(dense) == N_PARTS
+            for s in dense:
+                # one call a query, each handed its f32 query row alone:
+                # the partition's matrix already sits on the device
+                assert (s[3]["queries"], s[3]["padded"]) == (q, q)
+                assert s[3]["h2d_bytes"] == q * DIM * 4
 
 
 def test_admission_wait_and_the_request_key(served):
@@ -154,23 +157,31 @@ def test_admission_wait_and_the_request_key(served):
 
 
 def test_hydration_counts_state_placed_on_the_device(served):
-    mode, _, _, spans = served
+    mode, app, _, spans = served
     hydrate = _by_name(spans, "hydrate")
     placed = [s[3]["h2d_bytes"] for s in hydrate]
     # each partition's lazy searcher is built once its first terms land,
     # putting its index state on the device
     assert sum(b > 0 for b in placed) >= N_PARTS
     assert all(b >= 0 for b in placed)
+    if mode == "hybrid":
+        # each partition's dense matrix, its live rows padded with zero
+        # rows to the kernel's 1,024-row chunk, is placed once (at warm-up)
+        rows = np.diff(app.indexer.part_doc_offsets() + [120])
+        matrices = Counter(padded_rows(int(n), CFG.k) * DIM * 4
+                           for n in rows)
+        assert {b: Counter(placed)[b] for b in matrices} == matrices
 
 
-@pytest.mark.parametrize("rows_on", ["host", "device"])
-def test_dense_counts_only_the_host_arrays_it_hands_over(rows_on, tmp_path):
+@pytest.mark.parametrize("rows_as", ["numpy", "jax"])
+def test_dense_counts_only_the_host_arrays_it_hands_over(rows_as, tmp_path):
     rng = np.random.default_rng(63)
     n, q = 40, 3
-    ds = DenseSearcher(rng.standard_normal((n, DIM)).astype(np.float32),
-                       [str(i) for i in range(n)], np.ones(n, bool), CFG)
-    if rows_on == "device":
-        ds.rows = jax.device_put(ds.rows)
+    vectors = rng.standard_normal((n, DIM)).astype(np.float32)
+    if rows_as == "jax":
+        vectors = jax.device_put(vectors)
+    ds = DenseSearcher(vectors, [str(i) for i in range(n)], np.ones(n, bool),
+                       CFG)
     jax.profiler.start_trace(str(tmp_path))
     try:
         hits = ds.search_batch(list(rng.standard_normal((q, DIM))), k=5)
@@ -178,8 +189,7 @@ def test_dense_counts_only_the_host_arrays_it_hands_over(rows_on, tmp_path):
         jax.profiler.stop_trace()
     assert [len(h) for h in hits] == [5] * q
     (dense,) = _by_name(_spans(tmp_path), "dense")
-    padded = 4
-    rows = n * DIM * 4 if rows_on == "host" else 0
-    # the padded query rows always cross; the matrix once per call made,
-    # and not at all once it sits on the device
-    assert dense[3]["h2d_bytes"] == padded * (DIM * 4 + rows)
+    # however the rows were handed to the searcher, it placed them on the
+    # device once: a search hands over its f32 query rows alone
+    assert (dense[3]["queries"], dense[3]["padded"]) == (q, q)
+    assert dense[3]["h2d_bytes"] == q * DIM * 4
