@@ -12,6 +12,12 @@ posting-list lengths in every partition: the index the program builds has
 the same shapes, and the programs compiled for them are found in the
 compile cache, whatever the seed. The strings are assembled with numpy
 instead of per-document joins.
+
+A deployment that ingests holds ``n_extra`` documents back from set-up,
+for the window's writes to add: a second shape draw of the same kind from
+a fixed seed of its own, spelled in the seed's labels, in a fixed order,
+after the ``n_base`` documents of the initial index. A corpus without them
+is the one it was.
 """
 
 from __future__ import annotations
@@ -80,16 +86,29 @@ class Corpus:
     # document shape_doc[i], and term id t is the draw's term shape_tid[t]
     shape_doc: np.ndarray | None = None
     shape_tid: np.ndarray | None = None
+    n_extra: int = 0          # the last documents, held back from set-up
 
     @property
     def n_docs(self) -> int:
         return len(self.texts)
 
+    @property
+    def n_base(self) -> int:
+        """Documents of the initial index."""
+        return self.n_docs - self.n_extra
+
+    def ext_index(self, ext: str) -> int:
+        """The document an external id names, or -1."""
+        i = int(ext[3:]) if ext.startswith("doc") and ext[3:].isdigit() else -1
+        return i if 0 <= i < self.n_docs and self.ext_id(i) == ext else -1
+
     def ext_id(self, i: int) -> str:
         return f"doc{i}"
 
-    def docs(self) -> list[tuple[str, str]]:
-        return [(self.ext_id(i), t) for i, t in enumerate(self.texts)]
+    def docs(self, ids=None) -> list[tuple[str, str]]:
+        """(external id, text) of ``ids``, by default the initial index."""
+        ids = range(self.n_base) if ids is None else ids
+        return [(self.ext_id(i), self.texts[i]) for i in ids]
 
     def doc_len(self) -> np.ndarray:
         """Analyzed length of every document (stopwords dropped)."""
@@ -98,11 +117,14 @@ class Corpus:
         return csum[self.starts[1:]] - csum[self.starts[:-1]]
 
     def cf(self) -> np.ndarray:
-        """Collection frequency of every term id (stopwords included)."""
-        return np.bincount(self.tids, minlength=self.vocab)
+        """Collection frequency of every term id (stopwords included) in
+        the initial index."""
+        return np.bincount(self.tids[: self.starts[self.n_base]],
+                           minlength=self.vocab)
 
 
 SHAPE_SEED = 0x5EED    # the draw every seed relabels and shuffles
+EXTRA_SEED = [SHAPE_SEED, 0xADD]   # the draw of the held-back documents
 
 
 def draw(n_docs: int, *, vocab: int, mean_len: int, zipf_a: float,
@@ -115,11 +137,12 @@ def draw(n_docs: int, *, vocab: int, mean_len: int, zipf_a: float,
 
 
 def make_corpus(n_docs: int, *, vocab: int, mean_len: int, zipf_a: float,
-                seed: int, n_parts: int = 1) -> Corpus:
+                seed: int, n_parts: int = 1, n_extra: int = 0) -> Corpus:
     """The corpus of ``seed``: the draw of ``SHAPE_SEED`` with its
     non-stopword term ids permuted and its documents permuted within each
     of ``n_parts`` contiguous partitions (the program's partitioning),
-    both permutations drawn from ``seed``."""
+    both permutations drawn from ``seed``; then ``n_extra`` held-back
+    documents, the draw of ``EXTRA_SEED`` in the same labels."""
     lens, tids = draw(n_docs, vocab=vocab, mean_len=mean_len, zipf_a=zipf_a,
                       seed=SHAPE_SEED)
     rng = np.random.default_rng([seed, 0xC0A9])
@@ -135,9 +158,17 @@ def make_corpus(n_docs: int, *, vocab: int, mean_len: int, zipf_a: float,
     new_starts = np.concatenate([[0], np.cumsum(new_lens)])
     src = (np.repeat(starts[order] - new_starts[:-1], new_lens)
            + np.arange(new_starts[-1]))
-    corpus = spell(new_lens, label[tids[src]], vocab)
+    tids = tids[src]
+    if n_extra:
+        x_lens, x_tids = draw(n_extra, vocab=vocab, mean_len=mean_len,
+                              zipf_a=zipf_a, seed=EXTRA_SEED)
+        new_lens = np.concatenate([new_lens, x_lens])
+        tids = np.concatenate([tids, x_tids])
+        order = np.concatenate([order, n_docs + np.arange(n_extra)])
+    corpus = spell(new_lens, label[tids], vocab)
     corpus.shape_doc = order
     corpus.shape_tid = np.argsort(label)
+    corpus.n_extra = n_extra
     return corpus
 
 
@@ -164,11 +195,12 @@ def spell(lens: np.ndarray, tids: np.ndarray, vocab: int) -> Corpus:
                   stop=stop)
 
 
-def cell_corpus(config: dict, seed: int) -> Corpus:
-    """The corpus a deployment's file describes, for one seed."""
+def cell_corpus(config: dict, seed: int, n_extra: int = 0) -> Corpus:
+    """The corpus a deployment's file describes, for one seed, with
+    ``n_extra`` documents held back for the window's adds."""
     return make_corpus(config["n_docs"], vocab=config["vocab"],
                        mean_len=config["mean_len"], zipf_a=config["zipf_a"],
-                       seed=seed, n_parts=config["n_parts"])
+                       seed=seed, n_parts=config["n_parts"], n_extra=n_extra)
 
 
 def hash_embedding(text: str, dim: int) -> np.ndarray:

@@ -9,7 +9,11 @@ numerator, tf clamped to 255):
     score    = sum over query terms of qtf * idf * tf / (tf + k1 * (1 - b + b * dl / avgdl))
 
 Only the posting lists of the terms that the traffic queries are built,
-straight from the token ids, so the reference costs seconds. Everything
+straight from the token ids, so the reference costs seconds. Where the
+window wrote, a generation's postings (``live_postings``) keep the live
+documents alone, and N, df and avgdl are taken over them: the program's
+stated semantics, where Lucene would count a deleted document until a
+merge purges it. Everything
 here is float64 numpy; ``bench/control.py`` computes the same in bfloat16.
 """
 
@@ -55,6 +59,18 @@ def build_postings(corpus, tids: set[int]) -> Postings:
         lists.setdefault(int(t), (np.zeros(0, np.int64), np.zeros(0, np.int64)))
     n = corpus.n_docs
     return Postings(lists=lists, dl=dl, avgdl=float(dl.sum()) / n, n_docs=n)
+
+
+def live_postings(post: Postings, live: np.ndarray) -> Postings:
+    """``post`` over the documents ``live`` (a bool mask over its ids)
+    marks: their postings alone, N their number, avgdl their mean."""
+    lists = {}
+    for t, (d, tf) in post.lists.items():
+        keep = live[d]
+        lists[t] = (d[keep], tf[keep])
+    n = int(live.sum())
+    return Postings(lists=lists, dl=post.dl,
+                    avgdl=float(post.dl[live].sum()) / n, n_docs=n)
 
 
 def bm25_topk(post: Postings, query_tids: list[int], k: int, *, k1: float,

@@ -14,8 +14,22 @@ the loop plays the admission-window timer by calling ``flush(now)``, and a
 request's latency runs from its due time to the moment its handle
 resolves. The program's own (virtual-clock) latencies are never read.
 
+A deployment that refreshes (``refresh_s`` in its file) under a mix that
+writes (``writes_per_s``, ``bench/traffic.py``) also has its writes played:
+each is sent through ``add_documents`` or ``delete_documents`` at its due
+time, and every ``refresh_s`` after the window opens the loop calls
+``commit(t_arrival=due)``, recording when it was due, when it returned and
+the generation it published. The program refreshes on no thread of its
+own, so a commit blocks the loop: the queries due meanwhile are submitted
+when it returns, and their latencies, still from due time to answer, hold
+that stall. A deployment without ``refresh_s`` never commits; only sparse
+mixes may write.
+
 After the window closes the run compares every answer with the
-benchmark's own reference (``bench/reference.py``) and prints, as the last
+benchmark's own reference (``bench/reference.py``): where the run wrote,
+against the corpus as it stood at the generation the answer names (the
+documents its commits added live, those they deleted gone, BM25's N, df
+and avgdl over the live documents), by external id. It prints, as the last
 line of stdout, one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer metrics, each read by ``bench/metrics/<metric>.py``), ``device``,
@@ -55,13 +69,14 @@ from bench import reference as ref  # noqa: E402
 from bench import trace as tr  # noqa: E402
 from bench import work  # noqa: E402
 from bench.corpus import cell_corpus, embed_all, hash_embedding  # noqa: E402
-from bench.traffic import Mix, make_schedule  # noqa: E402
+from bench.traffic import Mix, Writes, make_schedule, make_writes  # noqa: E402
 
 TRACE_DIR = BENCH / ".trace"     # gitignored; emptied after every traced run
 DRAIN_S = 30.0                   # how long past the window's close answers may come
 POLL_S = 0.001                   # the window timer's resolution
 TIE_DEPTH = 50                   # reference ranks past k the near-tie rule may see
 HOST_SPANS = ("bench.submit", "bench.flush")
+FIRST_GEN = 1                    # the generation a fleet is built at
 
 
 def log(msg: str) -> None:
@@ -95,11 +110,23 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     conf = {c["name"]: c for c in spec["configs"]}[w["config"]]
     config = json.loads((root / conf["file"]).read_text())
     mix = Mix.load(root / "bench" / "traffic" / f"{w['traffic']}.json")
+    return make_cell(name, int(w["chips"]), config, mix, spec)
+
+
+def make_cell(name: str, chips: int, config: dict, mix: Mix,
+              spec: dict) -> Cell:
+    """The cell of a deployment and a mix, with the metrics of ``spec``
+    (a BENCHMARK.json) that it reports."""
+    if mix.writes and mix.mode != "sparse":
+        raise SystemExit(
+            f"bench: {name}: the mix writes under mode {mix.mode!r}; only "
+            f"sparse mixes may write, since the check scores the dense tier "
+            f"against the initial corpus alone")
 
     def mine(m):
         return "workloads" not in m or name in m["workloads"]
 
-    return Cell(name=name, chips=int(w["chips"]), config=config, mix=mix,
+    return Cell(name=name, chips=chips, config=config, mix=mix,
                 end_to_end=[m for m in spec["end_to_end"] if mine(m)],
                 per_layer=[m for m in spec["per_layer"] if mine(m)])
 
@@ -194,6 +221,7 @@ class Recorder:
         self.sizes: list[int] = []
         self.times: list[float] = []
         self.cold_legs = 0
+        self.cold_by_gen: dict = {}
         self.legs: dict[str, list] = {}
         self.on = False
         scatter, fetch = app.scatter.scatter, app.doc_store.batch_get_billed
@@ -206,7 +234,10 @@ class Recorder:
                 qs = payload.get("queries") or payload.get("qvs") or []
                 self.sizes.append(len(qs))
                 self.times.append(clock())
-                self.cold_legs += sum(bool(r.cold) for r in records)
+                cold = sum(bool(r.cold) for r in records)
+                self.cold_legs += cold
+                gen = payload.get("gen")
+                self.cold_by_gen[gen] = self.cold_by_gen.get(gen, 0) + cold
                 for qi, q in enumerate(payload.get("queries") or ()):
                     self.legs[q] = [r["results"][qi] for r in results]
             return results, lat, records
@@ -282,9 +313,10 @@ def wait_idle(app) -> None:
 
 
 def set_up(cfg: dict, mix: Mix, corpus, scheds: list, seconds: float):
-    """Build the fleet, hydrate every partition, and warm each batch shape
-    the schedules can form (``batch_bound``, or more where a schedule
-    holds more); a partition pads its batch to a power of two."""
+    """Build the fleet over the initial index, hydrate every partition,
+    and warm each batch shape the schedules can form (``batch_bound``, or
+    more where a schedule holds more); a partition pads its batch to a
+    power of two."""
     t = clock()
     app, policy = build_app(cfg, corpus.docs())
     log(f"bench: fleet built: {clock() - t:.1f} s")
@@ -320,6 +352,23 @@ def set_up(cfg: dict, mix: Mix, corpus, scheds: list, seconds: float):
 
 
 @dataclasses.dataclass
+class Commit:
+    """One refresh of the window: due, called and returned on
+    ``clock()``, the writes acknowledged before it was called, and what it
+    answered."""
+
+    due: float
+    start: float
+    done: float
+    writes_before: int        # acknowledged writes sent before the call
+    status: int               # the gateway's answer (0: it raised)
+    committed: bool
+    gen: int | None           # the generation it published
+    body: dict
+    compiles: tuple[int, int]  # programs compiled by its call and return
+
+
+@dataclasses.dataclass
 class Window:
     due: np.ndarray            # absolute due times on clock()
     done: np.ndarray           # when each handle resolved (nan: never)
@@ -327,10 +376,50 @@ class Window:
     responses: list
     t_open: float
     t_close: float
+    writes: Writes | None = None
+    write_sent: np.ndarray | None = None  # (writes,) sent in the window
+    write_ok: np.ndarray | None = None    # (writes,) acknowledged 200
+    commits: list[Commit] = dataclasses.field(default_factory=list)
+
+    @property
+    def wrote(self) -> bool:
+        return self.writes is not None and len(self.writes) > 0
+
+
+def send_write(app, corpus, writes: Writes, w: int, t_arrival: float) -> bool:
+    ids = writes.docs[w]
+    if writes.delete[w]:
+        resp = app.delete_documents([corpus.ext_id(i) for i in ids],
+                                    t_arrival=t_arrival)
+    else:
+        resp = app.add_documents(corpus.docs(ids), t_arrival=t_arrival)
+    return resp.status == 200
+
+
+def call_commit(app, t_arrival: float, writes_before: int, compiles) -> Commit:
+    c0, start = compiles(), clock()
+    try:
+        resp = app.commit(t_arrival=t_arrival)
+        status, body = resp.status, resp.body if isinstance(resp.body, dict) \
+            else {"error": str(resp.body)}
+    except Exception as e:                      # noqa: BLE001
+        status, body = 0, {"error": repr(e)}
+    committed = status == 200 and bool(body.get("committed"))
+    return Commit(due=t_arrival, start=start, done=clock(),
+                  writes_before=writes_before,
+                  status=status, committed=committed,
+                  gen=int(body["gen"]) if committed else None, body=body,
+                  compiles=(c0, compiles()))
 
 
 def run_window(app, sched, mix: Mix, t_open: float, seconds: float,
-               span) -> Window:
+               span, *, corpus=None, writes: Writes | None = None,
+               refresh_s: float | None = None, compiles=lambda: 0) -> Window:
+    """Play the schedule open-loop from ``t_open``; with ``writes`` and
+    ``refresh_s``, also send each write at its due time and commit every
+    ``refresh_s``. Events are played in the order they fell due; a commit
+    blocks the loop, and the queries due during it are submitted late,
+    their latency still counted from their due time."""
     n = len(sched)
     due = t_open + sched.due
     done = np.full(n, np.nan)
@@ -338,6 +427,13 @@ def run_window(app, sched, mix: Mix, t_open: float, seconds: float,
     handles: list = [None] * n
     pending: list[int] = []
     t_close = t_open + seconds
+    n_w = len(writes) if writes is not None else 0
+    w_due = t_open + writes.due if n_w else np.zeros(0)
+    write_sent, write_ok = np.zeros(n_w, bool), np.zeros(n_w, bool)
+    ticks = (t_open + refresh_s * np.arange(1, math.ceil(seconds / refresh_s))
+             if refresh_s else np.zeros(0))
+    commits: list[Commit] = []
+    w = c = 0
 
     def collect() -> None:
         if not pending:
@@ -351,13 +447,18 @@ def run_window(app, sched, mix: Mix, t_open: float, seconds: float,
                 keep.append(j)
         pending[:] = keep
 
+    def next_other() -> float:
+        return min(w_due[w] if w < n_w else math.inf,
+                   ticks[c] if c < len(ticks) else math.inf)
+
     i = 0
     with span("bench.window"):
         while True:
             now = clock()
-            if i < n and due[i] <= now:
+            other = next_other()
+            if i < n and due[i] <= now and due[i] <= other:
                 with span("bench.submit"):
-                    while i < n and due[i] <= clock():
+                    while i < n and due[i] <= clock() and due[i] <= other:
                         late[i] = clock() - due[i]
                         handles[i] = app.submit(
                             sched.queries[i], k=mix.k, mode=mix.mode,
@@ -366,21 +467,66 @@ def run_window(app, sched, mix: Mix, t_open: float, seconds: float,
                         i += 1
                         collect()
                 continue
+            if other <= now and now <= t_close + DRAIN_S:
+                if w < n_w and w_due[w] == other:
+                    with span("bench.write"):
+                        write_ok[w] = send_write(app, corpus, writes, w,
+                                                 float(other))
+                    write_sent[w] = True
+                    w += 1
+                else:
+                    with span("bench.commit"):
+                        commits.append(call_commit(
+                            app, float(other), int(write_ok[:w].sum()),
+                            compiles))
+                    c += 1
+                continue
             if pending:
                 with span("bench.flush"):
                     app.flush(now)
                 collect()
-            if i == n and not pending:
+            if i == n and not pending and other == math.inf:
                 break
             if now > t_close + DRAIN_S:
                 break
-            nxt = due[i] if i < n else now + POLL_S
+            nxt = min(due[i] if i < n else now + POLL_S, other)
             with span("bench.wait"):
                 time.sleep(max(0.0, min(nxt - clock(), POLL_S)))
     responses = [h.response if h is not None and h.done() else None
                  for h in handles]
     return Window(due=due, done=done, late=late, responses=responses,
-                  t_open=t_open, t_close=t_close)
+                  t_open=t_open, t_close=t_close, writes=writes,
+                  write_sent=write_sent, write_ok=write_ok, commits=commits)
+
+
+def log_commits(win: Window, cold_by_gen: dict,
+                compiles: tuple[int, int]) -> None:
+    """One line for the window's refreshes, then one per generation: its
+    commit's wall time (from call to return), the documents it added and
+    deleted, the programs compiled in the commit and while the generation
+    served (until the next commit; ``compiles`` counts the window's open
+    and close), and its cold legs."""
+    cs = win.commits
+    log(f"bench: {len(cs)} commits, {sum(c.committed for c in cs)} "
+        f"published; wall times "
+        f"{[round((c.done - c.start) * 1e3, 1) for c in cs]} ms, called "
+        f"{[round((c.start - c.due) * 1e3, 1) for c in cs]} ms after due")
+    gens = [c for c in cs if c.committed]
+    starts = [compiles[0]] + [c.compiles[1] for c in gens]
+    ends = [c.compiles[0] for c in gens] + [compiles[1]]
+    for c, a, b in zip([None] + gens, starts, ends):
+        gen = FIRST_GEN if c is None else c.gen
+        line = (f"bench: generation {gen}: cold legs "
+                f"{cold_by_gen.get(gen, 0)}, compiles while serving {b - a}")
+        if c is not None:
+            line += (f"; its commit due {c.due - win.t_open:.3f} s, called "
+                     f"{(c.start - c.due) * 1e3:.1f} ms late, took "
+                     f"{(c.done - c.start) * 1e3:.1f} ms, added "
+                     f"{c.body.get('indexed')}, deleted "
+                     f"{c.body.get('deleted')}, merged partitions "
+                     f"{c.body.get('merged')}, compiles "
+                     f"{c.compiles[1] - c.compiles[0]}")
+        log(line)
 
 
 # -- the comparison -------------------------------------------------------------------
@@ -396,35 +542,105 @@ def merge_tier(per_part: list[dict], per: int, k: int
     return [(p * per + d, -s) for s, p, d in hits[:k]]
 
 
+def generations(win: Window, corpus) -> dict[int, np.ndarray]:
+    """The live documents of every generation the window's commits
+    published: those of the initial index, plus the documents of every
+    acknowledged add sent before the commit, less those of every such
+    delete."""
+    live = np.arange(corpus.n_docs) < corpus.n_base
+    out = {FIRST_GEN: live.copy()}
+    applied = 0
+    acked = np.flatnonzero(win.write_ok) if win.wrote else np.zeros(0, int)
+    for c in win.commits:
+        if not c.committed:
+            continue
+        for w in acked[applied: c.writes_before].tolist():
+            live[win.writes.docs[w]] = not win.writes.delete[w]
+        applied = max(applied, c.writes_before)
+        out[c.gen] = live.copy()
+    return out
+
+
+def refresh_checks(win: Window) -> tuple[list[int], int]:
+    """Per answer, the generation it has to serve at least: that of the
+    newest commit that returned before it was due. And the refreshes that
+    failed: writes sent and not acknowledged, and commits that raised,
+    answered other than 200, or published nothing while acknowledged
+    writes were staged. A write the window never sent, since a stall
+    outlasted the drain, is no failure of its own: the queries it held
+    back are."""
+    done = [(c.done, c.gen) for c in win.commits if c.committed]
+    t_done = np.array([t for t, _ in done])
+    gens = [FIRST_GEN] + [g for _, g in done]
+    at = np.searchsorted(t_done, win.due, side="right")
+    staged_from = 0
+    failed = int((win.write_sent & ~win.write_ok).sum()) if win.wrote else 0
+    for c in win.commits:
+        if c.committed:
+            staged_from = c.writes_before
+        elif c.status != 200 or c.writes_before > staged_from:
+            failed += 1
+    return [gens[a] for a in at.tolist()], failed
+
+
+def doc_errors(body: dict, corpus, by_gid: bool) -> int:
+    """Fetched passages that are not the corpus's for their external id;
+    ``by_gid``: nor is the id the document's corpus index."""
+    bad = 0
+    for gid, ext, doc in zip(body["ids"], body["ext_ids"], body["docs"]):
+        i = corpus.ext_index(ext) if isinstance(ext, str) else -1
+        bad += (i < 0 or (by_gid and i != gid) or doc is None
+                or doc.get("id") != ext
+                or doc.get("contents") != corpus.texts[i])
+    return bad
+
+
 def check(win: Window, sched, corpus, post, config: dict, mix: Mix,
           legs: dict) -> tuple[dict, np.ndarray]:
     """Every answer of the window against the reference: the numbers
     compared, each with its limit, and which requests were answered
-    correctly."""
+    correctly. Where the window wrote, each answer is held to the
+    generation it names (BM25 over that generation's live documents,
+    served documents by external id), and two numbers are added:
+    ``stale``, answers older than the newest commit that returned before
+    they were due, or naming no generation a commit published; and
+    ``commit_failed`` (``refresh_checks``)."""
     limits = config["limits"]
     k, tie = mix.k, limits["score_rel_err"]
     hybrid = mix.mode == "hybrid"
-    per = -(-corpus.n_docs // config["n_parts"])
+    per = -(-corpus.n_base // config["n_parts"])
     if hybrid:
         vectors = embed_all(corpus.texts, config["vector_dim"])
     sparse, dense = ref.Tally(), ref.Tally()
-    failed = docs_bad = fused_bad = 0
+    failed = docs_bad = fused_bad = stale = 0
     good = np.zeros(len(win.responses), bool)
+    if win.wrote:
+        live = generations(win, corpus)
+        at_least, commit_failed = refresh_checks(win)
+        posts: dict = {}
+        by_gen: dict = {}
     for j, resp in enumerate(win.responses):
         if resp is None or resp.status != 200:
             failed += 1
             continue
         body = resp.body
-        got = list(zip(body["ids"], body["scores"]))
-        want_s = ref.bm25_topk(post, sched.query_tids[j], k, k1=config["k1"],
+        p = post
+        if win.wrote:
+            gen = body.get("generation")
+            if gen not in live or gen < at_least[j]:
+                stale += 1
+                continue
+            if gen not in posts:
+                posts[gen] = ref.live_postings(post, live[gen])
+            p = posts[gen]
+            got = [(corpus.ext_index(e) if isinstance(e, str) else -1, s)
+                   for e, s in zip(body["ext_ids"], body["scores"])]
+        else:
+            got = list(zip(body["ids"], body["scores"]))
+        want_s = ref.bm25_topk(p, sched.query_tids[j], k, k1=config["k1"],
                                b=config["b"], depth=TIE_DEPTH)
-        bad_docs = 0
-        if mix.fetch_docs:
-            for gid, ext, doc in zip(body["ids"], body["ext_ids"],
-                                     body["docs"]):
-                bad_docs += (ext != corpus.ext_id(gid) or doc is None
-                             or doc.get("id") != ext
-                             or doc.get("contents") != corpus.texts[gid])
+        bad_docs = (doc_errors(body, corpus, not win.wrote)
+                    if mix.fetch_docs else 0)
         docs_bad += bad_docs
         if hybrid:
             q = sched.queries[j]
@@ -451,6 +667,10 @@ def check(win: Window, sched, corpus, post, config: dict, mix: Mix,
             ts = ref.compare(got, want_s, k, tie)
             sparse.add(ts)
             good[j] = not bad_docs and ts.ok(limits["score_rel_err"])
+            if win.wrote:
+                g = by_gen.setdefault(gen, [ref.Tally(), 0])
+                g[0].add(ts)
+                g[1] += bad_docs
     checks = {"failed": (failed, limits["failed"]),
               "id_mismatch": (sparse.id_mismatch, limits["id_mismatch"]),
               "score_rel_err": (sparse.rel_err, limits["score_rel_err"])}
@@ -461,8 +681,33 @@ def check(win: Window, sched, corpus, post, config: dict, mix: Mix,
         checks["fused_mismatch"] = (fused_bad, limits["fused_mismatch"])
     if mix.fetch_docs:
         checks["doc_mismatch"] = (docs_bad, limits["doc_mismatch"])
+    if win.wrote:
+        checks["stale"] = (stale, limits["stale"])
+        checks["commit_failed"] = (commit_failed, limits["commit_failed"])
+        for gen in sorted(by_gen):
+            t, bad = by_gen[gen]
+            log(f"bench: generation {gen}: {t.answers} answers, "
+                f"{int(live[gen].sum())} live documents, id_mismatch "
+                f"{t.id_mismatch}, score_rel_err {t.rel_err!r}, "
+                f"doc_mismatch {bad}")
     return ({name: {"value": v, "limit": lim}
              for name, (v, lim) in checks.items()}, good)
+
+
+def query_postings(win: Window, corpus, post) -> list:
+    """The postings each query's work is counted over: those of the
+    generation its answer names (the initial index where it has none)."""
+    if not win.wrote:
+        return [post] * len(win.responses)
+    live, posts, out = generations(win, corpus), {}, []
+    for resp in win.responses:
+        gen = (resp.body.get("generation") if resp is not None
+               and resp.status == 200 else None)
+        gen = gen if gen in live else FIRST_GEN
+        if gen not in posts:
+            posts[gen] = ref.live_postings(post, live[gen])
+        out.append(posts[gen])
+    return out
 
 
 # -- metrics ----------------------------------------------------------------------------
@@ -498,10 +743,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, dev=None,
     log(f"bench: {cell.name} seed {seed} seconds {seconds} trace {int(trace)}")
 
     t = clock()
-    corpus = cell_corpus(cfg, seed)
+    corpus = cell_corpus(cfg, seed, n_extra=mix.n_added_docs(seconds))
     sched = make_schedule(mix, corpus, seconds, seed)
-    log(f"bench: corpus {corpus.n_docs} docs, {len(corpus.tids)} tokens, "
-        f"{len(sched)} arrivals: {clock() - t:.1f} s")
+    writes = make_writes(mix, corpus, seconds, cfg["n_parts"])
+    log(f"bench: corpus {corpus.n_base} docs (+{corpus.n_extra} to add), "
+        f"{len(corpus.tids)} tokens, {len(sched)} arrivals, {len(writes)} "
+        f"writes: {clock() - t:.1f} s")
     app = set_up(cfg, mix, corpus, [sched], seconds)
     log(f"bench: programs loaded in set-up: {clog.loads}, compiled "
         f"{clog.n}, from the persistent cache {clog.hits}")
@@ -518,7 +765,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, dev=None,
     setup_s = t_open
     recorder.on = True
     win = run_window(app, sched, mix, t_open, seconds,
-                     _span if trace else _no_span)
+                     _span if trace else _no_span, corpus=corpus,
+                     writes=writes, refresh_s=cfg.get("refresh_s"),
+                     compiles=lambda: clog.n)
     recorder.on = False
     compiles = clog.n - c0
     if trace:
@@ -554,6 +803,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, dev=None,
         f"windows, mean batch {stats['mean_batch']:.3f}; window compiles "
         f"{compiles}; cold legs {recorder.cold_legs}")
     log_segments(win, recorder.times, recorder.sizes)
+    if cfg.get("refresh_s"):
+        log_commits(win, recorder.cold_by_gen, (c0, c0 + compiles))
 
     device = {"platform": jax.devices()[0].platform,
               "kind": jax.devices()[0].device_kind,
@@ -576,6 +827,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, dev=None,
             if files else {}
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         log(f"bench: trace reduced: {clock() - t:.1f} s")
+        for name, b in sorted(red.get("fleet", {}).items()):
+            log(f"bench: {name}: {b['count']} spans, {b['span_s']!r} s, host "
+                f"{b['host_s']!r} s, {b['counters']}")
         rec = {
             "queries": len(sched),
             "latency_ms": (lat * 1e3).tolist(),
@@ -584,8 +838,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, dev=None,
             "window_compiles": compiles,
             "trace": red,
             "peak": peak or {},
-            "work": work.window_work(post, sched.query_tids,
-                                     recorder.sizes, cfg, mix),
+            "work": work.window_work(query_postings(win, corpus, post),
+                                     sched.query_tids, recorder.sizes, cfg,
+                                     mix),
         }
         metrics = {}
         for m in cell.per_layer:

@@ -109,3 +109,74 @@ def test_busy_prefix_sum_matches_interval_overlap():
         for hi in range(lo, 100, 7):
             want = sum(max(0, min(e, hi) - max(s, lo)) for s, e in merged)
             assert busy.within(lo, hi) == want
+
+
+# sha256 of json.dumps(reduce(...), sort_keys=True) of the recorded trace
+# (host spans bench.submit and bench.flush), as the reduction gave it
+# before it kept the program's spans
+PARENT_REDUCTION = \
+    "f0513171a0bf9fb05a90c75779dd2e52e01a132a980d59b50ae593128054184f"
+
+
+def test_recorded_trace_reduces_as_it_did(recorded):
+    import hashlib
+    import json
+    t, _, _ = recorded
+    red = tr.reduce(t, host_spans=("bench.submit", "bench.flush"))
+    assert "fleet" not in red and not t.fleet
+    assert hashlib.sha256(json.dumps(red, sort_keys=True).encode()
+                          ).hexdigest() == PARENT_REDUCTION
+
+
+def test_fleet_spans_get_a_block_each():
+    t = tr.Trace(
+        ops={"/device:TPU:0": [(30, 40, "%fusion.1 = f32[] x"),
+                               (70, 90, "%fusion.2 = f32[] y")]},
+        modules={"/device:TPU:0": [(30, 40, "jit_a(1)"),
+                                   (70, 90, "jit_a(1)")]},
+        spans=[(0, 200, tr.WINDOW_SPAN), (10, 100, "bench.flush")],
+        fleet=[(20, 50, "fleet.bm25", {"queries": 3, "padded": 4,
+                                       "h2d_bytes": 512}),
+               (60, 95, "fleet.bm25", {"queries": 2, "padded": 2,
+                                       "h2d_bytes": 256}),
+               (12, 98, "fleet.dispatch", {"requests": 5, "wait_ms_sum": 1.5,
+                                           "wait_ms_max": 0.75}),
+               (110, 120, "fleet.dispatch", {"requests": 1,
+                                             "wait_ms_sum": 2.0,
+                                             "wait_ms_max": 2.0}),
+               (150, 250, "fleet.kv", {"keys": 10})])     # past the window
+    red = tr.reduce(t, host_spans=("bench.flush",))
+    bm25 = red["fleet"]["fleet.bm25"]
+    assert bm25["count"] == 2
+    assert bm25["span_s"] == pytest.approx(65e-9)
+    assert bm25["host_s"] == pytest.approx((30 - 10 + 35 - 20) * 1e-9)
+    assert bm25["counters"] == {"queries": 5, "padded": 6, "h2d_bytes": 768}
+    d = red["fleet"]["fleet.dispatch"]
+    assert d["count"] == 2
+    assert d["counters"] == pytest.approx(
+        {"requests": 6, "wait_ms_sum": 3.5, "wait_ms_max": 2.0})
+    assert d["host_s"] == pytest.approx((86 - 30 + 10) * 1e-9)
+    assert set(red["fleet"]) == {"fleet.bm25", "fleet.dispatch"}
+    # the bench spans alone name the gaps and count as spans
+    assert set(red["gap_s"]) == {"bench.flush", "host:untraced"}
+    assert red["spans"] == 1
+    assert red["host_s"] == pytest.approx((90 - 30) * 1e-9)
+
+
+def test_load_keeps_the_programs_spans_and_counters(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation(tr.WINDOW_SPAN):
+        with jax.profiler.TraceAnnotation("fleet.bm25", queries=3, padded=4):
+            jnp.ones(3).sum().block_until_ready()
+        with jax.profiler.TraceAnnotation("fleet.hydrate") as sp:
+            sp.set_metadata(h2d_bytes=12345678901)
+        with jax.profiler.TraceAnnotation("other.span"):
+            pass
+    jax.profiler.stop_trace()
+    t = tr.load(next(tmp_path.glob("**/*.xplane.pb")))
+    assert [(n, c) for _, _, n, c in t.fleet] == [
+        ("fleet.bm25", {"queries": 3, "padded": 4}),
+        ("fleet.hydrate", {"h2d_bytes": 12345678901})]
+    assert [n for _, _, n in t.spans] == [tr.WINDOW_SPAN]

@@ -4,6 +4,7 @@ every seed gets the same amount of work."""
 import dataclasses
 
 import numpy as np
+import pytest
 
 from bench.corpus import draw as make_draw
 from bench.corpus import make_corpus, spell, term_string
@@ -118,3 +119,42 @@ def test_every_seed_warms_shapes_that_hold_its_windows():
         s = make_schedule(mix, c, 51.0, SEED + seed)
         assert s.max_in_span(policy.max_window_s) <= bound
     assert bound < policy.max_batch
+
+
+def test_todays_mixes_load_as_they_did():
+    from bench.run import BENCH
+    for name, rate, mode in (("bm25-steady", 44.0, "sparse"),
+                             ("hybrid-steady", 2.4, "hybrid")):
+        mix = Mix.load(BENCH / "traffic" / f"{name}.json")
+        assert mix == Mix(rate_qps=rate, terms_per_query=[1, 2, 3, 4],
+                          max_cf=8192, mode=mode, k=10, fetch_docs=True)
+        assert not mix.writes and mix.n_added_docs(51.0) == 0
+
+
+# sha256 of the corpus (texts, token ids, offsets), the arrival times and
+# the queries each committed cell makes at SEED, 3,000 documents and a 51 s
+# window, as the generator made them before it could write
+PARENT_DIGESTS = {
+    "bm25-w1-steady":
+        "002bd5b5735a87277885876fc89006992c20972067ab394fa19fe121b06e4993",
+    "hybrid768-steady":
+        "3bc707738b37ab7f5f35f76c3dc400e3c2d47045583d486dce56f333a52122fa",
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_DIGESTS))
+def test_committed_cells_make_the_corpus_and_schedule_they_made(name):
+    import hashlib
+    import json
+
+    from bench.corpus import cell_corpus
+    from bench.run import load_cell
+    cell = load_cell(name)
+    c = cell_corpus(dict(cell.config, n_docs=3000), SEED)
+    s = make_schedule(cell.mix, c, 51.0, SEED)
+    h = hashlib.sha256()
+    h.update("\n".join(c.texts).encode())
+    for a in (c.tids, c.starts, s.due):
+        h.update(np.ascontiguousarray(a).tobytes())
+    h.update(json.dumps([s.queries, s.query_tids]).encode())
+    assert h.hexdigest() == PARENT_DIGESTS[name]

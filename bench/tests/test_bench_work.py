@@ -20,7 +20,7 @@ def test_bm25_bytes_count_every_queried_posting_once():
                 want += 9 * sum(term_string(t) in w
                                 for w in words[p * per:(p + 1) * per])
         want += 4 * 10 * 8
-    assert work.bm25_bytes(post, queries, 4, 10) == want
+    assert work.bm25_bytes([post] * 3, queries, 4, 10) == want
 
 
 def test_partition_sizes_are_contiguous_ceilings():

@@ -4,15 +4,21 @@ The run writes JAX's profiler trace (an ``.xplane.pb``) of its window. In
 it, every TPU device plane (``/device:TPU:<n>``) has an ``XLA Ops`` line,
 one event per executed operation, and an ``XLA Modules`` line, one event
 per executed program, named ``<jit name>(<fingerprint>)``. The host plane
-(``/host:CPU``) carries the benchmark's own spans (``bench.*``), written
-with ``jax.profiler.TraceAnnotation``, on the same clock.
+(``/host:CPU``) carries the benchmark's own spans (``bench.*``) and the
+program's (``fleet.*``, ``src/repro/core/trace.py``, with their counters
+as the events' stats), both written with ``jax.profiler.TraceAnnotation``,
+on the same clock.
 
 * busy: the union of the operation intervals inside the window, averaged
   over the devices used;
 * per-module time: the summed durations of a program's executions;
 * idle gaps: the stretches of the window with no operation on a device,
   each named by the innermost benchmark span it falls in (by its middle);
-* host time: a span's length less the device-busy time inside it.
+* host time: a span's length less the device-busy time inside it;
+* per ``fleet.`` name: how many spans, their summed length and host time,
+  their counters summed, and each ``*_max`` counter as its maximum.
+
+Idle gaps are named by the ``bench.*`` spans alone.
 """
 
 from __future__ import annotations
@@ -23,6 +29,7 @@ from collections import defaultdict
 import numpy as np
 
 SPAN_PREFIX = "bench."
+FLEET_PREFIX = "fleet."
 WINDOW_SPAN = "bench.window"
 
 
@@ -31,6 +38,9 @@ class Trace:
     ops: dict[str, list[tuple[int, int, str]]]       # device -> (start, end, name)
     modules: dict[str, list[tuple[int, int, str]]]   # device -> (start, end, name)
     spans: list[tuple[int, int, str]]                # host (start, end, name)
+    # the program's spans: host (start, end, name, counters)
+    fleet: list[tuple[int, int, str, dict]] = dataclasses.field(
+        default_factory=list)
 
 
 def load(path: str) -> Trace:
@@ -38,7 +48,7 @@ def load(path: str) -> Trace:
     data = ProfileData.from_file(str(path))
     ops: dict = {}
     modules: dict = {}
-    spans = []
+    spans, fleet = [], []
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             for line in plane.lines:
@@ -51,10 +61,14 @@ def load(path: str) -> Trace:
                         for e in line.events]
         elif plane.name == "/host:CPU":
             for line in plane.lines:
-                spans.extend((int(e.start_ns), int(e.end_ns), e.name)
-                             for e in line.events
-                             if e.name.startswith(SPAN_PREFIX))
-    return Trace(ops=ops, modules=modules, spans=sorted(spans))
+                for e in line.events:
+                    if e.name.startswith(SPAN_PREFIX):
+                        spans.append((int(e.start_ns), int(e.end_ns), e.name))
+                    elif e.name.startswith(FLEET_PREFIX):
+                        fleet.append((int(e.start_ns), int(e.end_ns), e.name,
+                                      dict(e.stats)))
+    return Trace(ops=ops, modules=modules, spans=sorted(spans),
+                 fleet=sorted(fleet, key=lambda f: f[:3]))
 
 
 def union(intervals, lo: int, hi: int) -> list[tuple[int, int]]:
@@ -169,12 +183,13 @@ def reduce(trace: Trace, *, host_spans: tuple[str, ...] = ()) -> dict:
         for (a, b), name in zip(gaps, names):
             gap_ns[name] += b - a
     busy = [Busy(merged[d]) for d in devices]
-    host_ns = 0.0
-    for s, e, name in inner:
-        if name in host_spans:
-            host_ns += (e - s) - float(np.mean([b.within(s, e) for b in busy]))
+
+    def host(s: int, e: int) -> float:
+        return (e - s) - float(np.mean([b.within(s, e) for b in busy]))
+
+    host_ns = sum(host(s, e) for s, e, name in inner if name in host_spans)
     n_dev = len(devices)
-    return {
+    out = {
         "window_s": window_ns / 1e9,
         "busy_s": float(np.mean(busy_ns)) / 1e9,
         "module_s": {k: v / 1e9 / n_dev for k, v in module_ns.items()},
@@ -183,6 +198,34 @@ def reduce(trace: Trace, *, host_spans: tuple[str, ...] = ()) -> dict:
         "host_s": host_ns / 1e9,
         "spans": len(inner),
     }
+    fleet = fleet_blocks([f for f in trace.fleet if lo <= f[0] and f[1] <= hi],
+                         host)
+    if fleet:
+        out["fleet"] = fleet
+    return out
+
+
+def fleet_blocks(events, host) -> dict:
+    """One block per ``fleet.`` name: ``count``, ``span_s`` (summed
+    length), ``host_s`` (summed length less the device busy inside each,
+    by ``host(start, end)`` in ns) and ``counters``: summed, but a
+    ``*_max`` counter keeps its maximum."""
+    out: dict[str, dict] = {}
+    for s, e, name, stats in events:
+        b = out.setdefault(name, {"count": 0, "span_s": 0.0, "host_s": 0.0,
+                                  "counters": {}})
+        b["count"] += 1
+        b["span_s"] += (e - s) / 1e9
+        b["host_s"] += host(s, e) / 1e9
+        c = b["counters"]
+        for key, v in stats.items():
+            if not isinstance(v, (int, float)) or isinstance(v, bool):
+                continue
+            if key.endswith("_max"):
+                c[key] = max(c.get(key, v), v)
+            else:
+                c[key] = c.get(key, 0) + v
+    return out
 
 
 def breakdown(red: dict, top: int = 10) -> dict:

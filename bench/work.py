@@ -28,11 +28,12 @@ def partition_sizes(n_docs: int, n_parts: int) -> list[int]:
     return [max(0, min(per, n_docs - p * per)) for p in range(n_parts)]
 
 
-def bm25_bytes(post, query_tids: list[list[int]], n_parts: int,
+def bm25_bytes(posts: list, query_tids: list[list[int]], n_parts: int,
                k: int) -> int:
-    """A term's postings over all partitions are its document frequency."""
+    """A term's postings over all partitions are its document frequency,
+    in the postings of its query (``posts``, one a query)."""
     total = 0
-    for q in query_tids:
+    for post, q in zip(posts, query_tids):
         total += sum(post.df(t) for t in set(q)) * POSTING_BYTES
         total += n_parts * k * HIT_BYTES
     return total
@@ -54,11 +55,14 @@ def least_time(nbytes: float, ops: float, peak: dict) -> float:
                ops / peak["bf16_flops_per_s"])
 
 
-def window_work(post, query_tids, batch_sizes, config: dict, mix) -> dict:
-    out = {"bm25_bytes": bm25_bytes(post, query_tids, config["n_parts"],
+def window_work(posts: list, query_tids, batch_sizes, config: dict,
+                mix) -> dict:
+    """``posts``: each query's postings, those of the generation that
+    answered it."""
+    out = {"bm25_bytes": bm25_bytes(posts, query_tids, config["n_parts"],
                                     mix.k)}
     if mix.mode in ("dense", "hybrid"):
         out["dense"] = dense_work(
-            batch_sizes, partition_sizes(post.n_docs, config["n_parts"]),
+            batch_sizes, partition_sizes(posts[0].n_docs, config["n_parts"]),
             config["vector_dim"], mix.k)
     return out
