@@ -129,6 +129,13 @@ class HydrationCache:
                     dropped += 1
         return dropped
 
+    def entries(self, name: str) -> list[tuple[str, Any]]:
+        """(version, asset) of every cached version of ``name``, without
+        touching recency."""
+        with self._lock:
+            return [(k[1], v[0]) for k, v in self._entries.items()
+                    if k[0] == name]
+
     def __contains__(self, key: tuple[str, str]) -> bool:
         with self._lock:
             return key in self._entries

@@ -23,14 +23,18 @@ is a tiny manifest version (``generation.json``) that REFERENCES immutable
 segments published under ``segments/`` — one base segment plus an ordered
 delta tier — with a tombstone set for deletes and the live corpus-wide BM25
 stats/vocab. Committing a batch publishes only the new delta's bytes (the
-Airphant-style small-immutable-increment story), then CAS-flips the
-manifest; a torn publish between two concurrent writers surfaces as
+Airphant-style small-immutable-increment story) and the shared state as a
+delta of the last (:class:`GenState`), then CAS-flips the manifest; a torn publish between two concurrent writers surfaces as
 :class:`PublishConflict` on the loser, never as a half-visible generation.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import threading
+from collections import OrderedDict
+
+import numpy as np
 
 from repro.core import jsonutil as orjson   # orjson when installed
 
@@ -44,6 +48,142 @@ class PublishConflict(Exception):
 
 
 GENERATION_FILE = "generation.json"
+STATE_DELTA_FILE = "delta.json"
+# a shared state is written as a delta of its parent until the chain back
+# to the last whole-vocabulary snapshot is this long; then a snapshot again
+STATE_SNAPSHOT_EVERY = 64
+# the device idf is padded to a multiple of this, with at least this much
+# room for new terms, so that generations share their search programs
+IDF_HEADROOM = 1 << 14
+STATE_MEMO = 8          # resolved generation states a catalog keeps
+
+
+def _idf_cap(n_terms: int) -> int:
+    return -(-n_terms // IDF_HEADROOM) * IDF_HEADROOM + IDF_HEADROOM
+
+
+class GenState:
+    """One generation's shared scoring state, resolved: the live document
+    count and token total, df by term id, the vocabulary, and (derived on
+    demand) idf on the host and on the device.
+
+    A state is published either whole (a snapshot: ``stats.json`` and
+    ``vocab.json``, the ``compute_global_stats`` dict and its vocab) or as
+    a delta of its parent (``delta.json``: the new terms, the df of every
+    term whose df changed, the counts). Resolving a delta applies it to
+    the parent's resolved state, so a generation costs what its commit
+    changed. Along a chain the vocabulary dict and term list are SHARED
+    and append-only: this generation's terms are the first ``n_terms``.
+    The device idf is padded to ``idf_cap``, which a child keeps while its
+    terms fit, so that every generation's search program is the same."""
+
+    def __init__(self, gen: int, seg: str, chain: list, n_docs: int,
+                 total_len: int, fields, vocab: dict, terms: list,
+                 df: np.ndarray, idf_cap: int) -> None:
+        self.gen, self.seg, self.chain = gen, seg, chain
+        self.n_docs, self.total_len, self.fields = n_docs, total_len, fields
+        self.vocab, self.terms, self.df = vocab, terms, df
+        self.n_terms = len(df)
+        self.idf_cap = idf_cap
+        self._idf = None
+        self._device_idf = None
+        self._lock = threading.Lock()
+
+    @property
+    def avgdl(self) -> float:
+        return self.total_len / max(1, self.n_docs)
+
+    @classmethod
+    def from_snapshot(cls, gen: int, seg: str, stats: dict,
+                      vocab: dict) -> "GenState":
+        terms: list = [None] * len(vocab)
+        for t, i in vocab.items():
+            terms[i] = t
+        df_map = stats["df"]
+        df = np.fromiter((df_map.get(t, 0) for t in terms), np.int64,
+                         count=len(terms))
+        n = int(stats["n_docs"])
+        total = stats.get("_total_len")
+        if total is None:
+            total = round(stats["avgdl"] * max(1, n)) if n else 0
+        return cls(gen, seg, [seg], n, int(total), stats.get("fields"),
+                   dict(vocab), terms, df, _idf_cap(len(terms)))
+
+    def child(self, seg: str, delta: dict) -> "GenState":
+        """The state ``delta`` (a ``delta.json``) makes of this one."""
+        new = delta["new_terms"]
+        vocab, terms = self.vocab, self.terms
+        if len(terms) != self.n_terms:
+            # another chain already grew the shared vocabulary past this
+            # state (two writers raced a generation): fork a copy
+            terms = terms[:self.n_terms]
+            vocab = {t: i for i, t in enumerate(terms)}
+        for t in new:
+            vocab[t] = len(terms)
+            terms.append(t)
+        df = np.concatenate([self.df, np.asarray(delta["new_df"], np.int64)])
+        df[np.asarray(delta["df_ids"], np.int64)] = delta["df_vals"]
+        cap = (self.idf_cap if len(df) <= self.idf_cap
+               else _idf_cap(len(df)))
+        return GenState(int(delta["gen"]), seg, list(delta["chain"]),
+                        int(delta["n_docs"]), int(delta["total_len"]),
+                        delta.get("fields"), vocab, terms, df, cap)
+
+    def delta_of(self, gen: int, seg: str, stats) -> "dict | None":
+        """``stats`` (a :class:`~repro.index.builder.LiveStats` grown from
+        this state) as a ``delta.json`` of this state, or None where it
+        was not grown from it. The changed df are found by one vectorized
+        compare."""
+        n0 = self.n_terms
+        if (stats.n_terms < n0 or len(self.chain) >= STATE_SNAPSHOT_EVERY
+                or (n0 and stats.terms[n0 - 1] != self.terms[n0 - 1])):
+            return None
+        cur = stats.df
+        changed = np.flatnonzero(cur[:n0] != self.df)
+        return {"gen": gen, "prev": self.seg, "chain": self.chain + [seg],
+                "n_docs": stats.n_docs, "total_len": stats.total_len,
+                "fields": stats.fields, "new_terms": stats.terms[n0:],
+                "new_df": cur[n0:].tolist(), "df_ids": changed.tolist(),
+                "df_vals": cur[changed].tolist()}
+
+    def idf(self) -> np.ndarray:
+        """(n_terms,) float32, the formula ``combine_segments`` applies."""
+        with self._lock:
+            if self._idf is None:
+                df = self.df.astype(np.float64)
+                self._idf = np.log(1.0 + (self.n_docs - df + 0.5)
+                                   / (df + 0.5)).astype(np.float32)
+            return self._idf
+
+    def device_idf(self) -> tuple:
+        """(idf on the device, padded with zeros to ``idf_cap``; the bytes
+        this call placed there): one copy serves every partition."""
+        idf = self.idf()
+        with self._lock:
+            if self._device_idf is not None:
+                return self._device_idf, 0
+            import jax
+            host = np.zeros(self.idf_cap, np.float32)
+            host[:len(idf)] = idf
+            self._device_idf = jax.device_put(host)
+            return self._device_idf, host.nbytes
+
+    def vocab_view(self) -> dict:
+        """This generation's term -> id map (a copy where the shared one
+        has grown past it)."""
+        if len(self.vocab) == self.n_terms:
+            return self.vocab
+        return {t: i for i, t in enumerate(self.terms[:self.n_terms])}
+
+    def as_stats(self) -> dict:
+        """The ``compute_global_stats`` dict (O(vocabulary))."""
+        out = {"n_docs": self.n_docs, "avgdl": self.avgdl,
+               "df": {self.terms[i]: int(self.df[i])
+                      for i in np.flatnonzero(self.df)},
+               "_total_len": self.total_len}
+        if self.fields is not None:
+            out["fields"] = self.fields
+        return out
 
 
 def generation_version(gen: int) -> str:
@@ -125,6 +265,10 @@ class AssetCatalog:
     def __init__(self, store: ObjectStore, root: str = "assets") -> None:
         self.store = store
         self.root = root.rstrip("/")
+        # resolved shared states, by (asset, segment): segments are
+        # immutable, so a resolution never goes stale
+        self._states: "OrderedDict[tuple, GenState]" = OrderedDict()
+        self._states_lock = threading.Lock()
 
     # -- paths -----------------------------------------------------------------
 
@@ -307,7 +451,7 @@ class AssetCatalog:
     def current_generation(self, name: str) -> GenerationManifest:
         return self.read_generation(name)
 
-    def publish_generation_state(self, name: str, gen: int, stats: dict,
+    def publish_generation_state(self, name: str, gen: int, stats,
                                  vocab: dict,
                                  writer: dict | None = None) -> list:
         """Publish one generation's SHARED scoring state (live stats +
@@ -315,18 +459,76 @@ class AssetCatalog:
         manifests should carry. One copy per generation, however many
         partitions reference it.
 
+        A :class:`~repro.index.builder.LiveStats` whose ``ref`` names a
+        state it grew from is written as a delta of that state (see
+        :class:`GenState`): what the commit changed, not the vocabulary.
+        Anything else, or a chain ``STATE_SNAPSHOT_EVERY`` long, is written
+        whole.
+
         ``writer`` optionally rides along as ``writer.json`` — coordinator
         bookkeeping (e.g. the round-robin placement cursor) a SECOND writer
         must adopt when it rebases on this generation, so a raced commit
         converges on the same document placement a serialized pair of
         commits would have produced."""
         seg = f"g{gen:06d}-state"
-        files = {"stats.json": orjson.dumps(stats),
-                 "vocab.json": orjson.dumps(vocab)}
+        delta = state = None
+        parent = self._grown_from(name, stats)
+        if parent is not None:
+            delta = parent.delta_of(gen, seg, stats)
+        if delta is not None:
+            files = {STATE_DELTA_FILE: orjson.dumps(delta)}
+            state = parent.child(seg, delta)
+        else:
+            whole = dict(stats)
+            if hasattr(stats, "total_len"):
+                whole["_total_len"] = stats.total_len
+            files = {"stats.json": orjson.dumps(whole),
+                     "vocab.json": orjson.dumps(vocab)}
         if writer is not None:
             files["writer.json"] = orjson.dumps(writer)
         self.publish_segment(name, seg, RamDirectory(files))
+        if state is not None:
+            self._remember((name, seg), state)
         return [name, seg]
+
+    def _grown_from(self, name: str, stats) -> "GenState | None":
+        ref = getattr(stats, "ref", None)
+        if not ref or ref[0] != name:
+            return None
+        try:
+            return self.generation_state(ref)
+        except (NoSuchKey, KeyError, ValueError):
+            return None
+
+    def _remember(self, key: tuple, state: GenState) -> None:
+        with self._states_lock:
+            self._states[key] = state
+            self._states.move_to_end(key)
+            while len(self._states) > STATE_MEMO:
+                self._states.popitem(last=False)
+
+    def generation_state(self, stats_ref) -> GenState:
+        """The resolved shared state a ``stats_ref`` names: from the
+        catalog's memo, else read — a delta applied to its parent's
+        resolution, a snapshot parsed whole."""
+        key = (stats_ref[0], stats_ref[1])
+        with self._states_lock:
+            hit = self._states.get(key)
+            if hit is not None:
+                self._states.move_to_end(key)
+                return hit
+        d = self.open_segment(*key)
+        if STATE_DELTA_FILE in d.list():
+            delta = orjson.loads(d.open_input(STATE_DELTA_FILE).read_all())
+            state = self.generation_state([key[0], delta["prev"]]).child(
+                key[1], delta)
+        else:
+            stats = orjson.loads(d.open_input("stats.json").read_all())
+            vocab = orjson.loads(d.open_input("vocab.json").read_all())
+            gen = int(key[1][1:7]) if key[1][1:7].isdigit() else 0
+            state = GenState.from_snapshot(gen, key[1], stats, vocab)
+        self._remember(key, state)
+        return state
 
     def resolve_generation_writer(self, manifest: GenerationManifest) -> dict:
         """The coordinator bookkeeping published with a generation's shared
@@ -349,10 +551,8 @@ class AssetCatalog:
             raise ValueError(
                 f"generation {manifest.gen} manifest carries neither inline "
                 "stats/vocab nor a stats_ref")
-        asset, seg = manifest.stats_ref
-        d = self.open_segment(asset, seg)
-        return (orjson.loads(d.open_input("stats.json").read_all()),
-                orjson.loads(d.open_input("vocab.json").read_all()))
+        state = self.generation_state(manifest.stats_ref)
+        return state.as_stats(), state.vocab_view()
 
     # -- resolve (the serving side) ------------------------------------------------
 

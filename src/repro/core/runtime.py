@@ -158,9 +158,11 @@ class InvocationRecord:
 class Instance:
     _ids = itertools.count()
 
-    def __init__(self, memory_bytes: int, now: float, fn: str = "") -> None:
+    def __init__(self, memory_bytes: int, now: float, fn: str = "",
+                 writer: bool = False) -> None:
         self.id = next(Instance._ids)
         self.fn = fn                  # Lambda pins environments per function
+        self.writer = writer          # an indexer's environment (see _acquire)
         self.cache = HydrationCache(memory_bytes)
         self.busy_until = now
         self.last_used = now
@@ -223,24 +225,31 @@ class FaaSRuntime:
             and not (i.fn in self._retired and i.busy_until <= now)
         ]
 
-    def _acquire(self, now: float, fn: str = "") -> tuple[Instance, bool]:
+    def _acquire(self, now: float, fn: str = "",
+                 write: bool = False) -> tuple[Instance, bool]:
         """Find an idle warm instance FOR THIS FUNCTION, else provision.
 
         Lambda execution environments are per-function: an instance that
         booted for function A is never handed a request for function B (a
         partitioned fleet would otherwise thrash each other's hydration
         caches). Within a function's pool, prefer the most-recently-used
-        idle instance (AWS's observed bin-packing; maximizes warmth)."""
+        idle instance (AWS's observed bin-packing; maximizes warmth).
+
+        ``max_instances`` bounds the SERVING environments. An indexer's
+        invocation (``write``) runs in an environment of its own, outside
+        that bound: it holds no index, and reclaiming a warm search
+        instance to run it would make every commit cost a cold partition."""
         self._reap_idle(now)
         idle = [i for i in self._instances
                 if i.busy_until <= now and i.fn == fn]
         if idle:
             inst = max(idle, key=lambda i: i.last_used)
             return inst, False
-        if len(self._instances) >= self.config.max_instances:
+        serving = [i for i in self._instances if not i.writer]
+        if not write and len(serving) >= self.config.max_instances:
             # throttled: wait for the earliest-free same-function instance
             # (429 + retry in real Lambda; modeled as queueing delay)
-            pool = [i for i in self._instances if i.fn == fn]
+            pool = [i for i in serving if i.fn == fn]
             if pool:
                 inst = min(pool, key=lambda i: i.busy_until)
                 return inst, False
@@ -248,13 +257,13 @@ class FaaSRuntime:
             # earliest-free one and boot a fresh environment for this fn in
             # its place (never hand fn a foreign instance's cache) — the
             # request queues until the victim frees, then pays a cold boot.
-            victim = min(self._instances, key=lambda i: i.busy_until)
+            victim = min(serving, key=lambda i: i.busy_until)
             self._instances.remove(victim)
             inst = Instance(self.config.memory_bytes, now, fn)
             inst.busy_until = max(now, victim.busy_until)
             self._instances.append(inst)
             return inst, True
-        inst = Instance(self.config.memory_bytes, now, fn)
+        inst = Instance(self.config.memory_bytes, now, fn, writer=write)
         self._instances.append(inst)
         return inst, True
 
@@ -337,7 +346,8 @@ class FaaSRuntime:
         now = self.clock if t_arrival is None else max(t_arrival, 0.0)
         cfg = self.config
         live = [i for i in self._instances
-                if i.alive and (now - i.last_used) <= cfg.idle_timeout_s]
+                if i.alive and (now - i.last_used) <= cfg.idle_timeout_s
+                and not i.writer]
         if any(i.busy_until <= now and i.fn == fn for i in live):
             return 0.0, 0.0
         if len(live) >= cfg.max_instances:
@@ -438,7 +448,7 @@ class FaaSRuntime:
                      record: bool = True, hedge: bool = False,
                      keepalive: bool = False, write: bool = False):
         cfg = self.config
-        inst, fresh = self._acquire(now, fn)
+        inst, fresh = self._acquire(now, fn, write)
         queue_wait = max(0.0, inst.busy_until - now)
         t_start = now + queue_wait
         cold_boot = cfg.provision_s if fresh else 0.0
@@ -473,7 +483,7 @@ class FaaSRuntime:
         result_duration = duration         # what the CALLER waits for
         if cfg.hedge_after_s is not None and exec_s > cfg.hedge_after_s:
             t_hedge = t_start + cfg.hedge_after_s
-            inst2, fresh2 = self._acquire(t_hedge, fn)
+            inst2, fresh2 = self._acquire(t_hedge, fn, write)
             # a capped 1-instance fleet hands back the busy primary — there
             # is no second instance to back up on, so don't pretend to hedge
             if inst2 is not inst:

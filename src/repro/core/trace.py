@@ -19,8 +19,9 @@ The spans, from the gateway down:
   ``window_ms``); the spans below it belong to that window by nesting;
 * ``fleet.scatter`` — the fan-out over partitions;
 * ``fleet.leg`` — one partition's leg (``partition``, ``cold``);
-* ``fleet.hydrate`` — index state read, grown or rebuilt in a handler
-  (``h2d_bytes``: state placed on the device);
+* ``fleet.hydrate`` — index state read, grown, rebuilt or derived from an
+  earlier generation in a handler (``h2d_bytes``: state placed on the
+  device);
 * ``fleet.encode`` — tokenizing and encoding a BM25 batch (``queries``);
 * ``fleet.bm25`` / ``fleet.dense`` — one tier's device call, until its
   outputs are on the host (``queries``, ``padded``, ``h2d_bytes``: the
@@ -28,7 +29,16 @@ The spans, from the gateway down:
 * ``fleet.backfill`` — a lazy partition's off-path backfill;
 * ``fleet.merge``, ``fleet.kv``, ``fleet.materialize`` — the
   coordinator's gather: merge and fusion, the KV fetch (``keys``), the
-  response bodies.
+  response bodies;
+* ``fleet.commit`` — one refresh, from the call until every pool holds the
+  new generation (``added``, ``deleted``, ``merged`` partitions,
+  ``published_bytes`` put to the store, ``h2d_bytes`` it placed on the
+  device outside the pools' hydrations), over its phases
+  ``fleet.commit.apply`` (stats, vocabulary, tiers), ``.write`` (the
+  writers' pack and upload), ``.publish`` (shared state and manifests),
+  ``.kv`` (``keys``: passages put), ``.prewarm`` (the generation's idf,
+  then the pools' pings, whose ``fleet.hydrate`` spans nest inside;
+  ``pings``) and ``.gc``.
 """
 
 from __future__ import annotations

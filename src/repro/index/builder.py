@@ -27,9 +27,11 @@ we follow Lucene.)
 
 from __future__ import annotations
 
+import copy
 import dataclasses
 import io
 import math
+from collections.abc import Mapping
 from typing import Iterable
 
 import numpy as np
@@ -239,14 +241,185 @@ def update_stats(stats: dict, text: str, *, sign: int = 1,
     # totals the same incremental way, staying exactly equal to a
     # from-scratch compute_global_stats(fields=True) over the live corpus
     if "fields" in stats:
-        fs = stats["fields"]
-        for field, ftext in field_items(text):
-            e = fs.setdefault(field, {"total": 0, "docs": 0})
-            e["total"] += sign * len(tokenize(ftext))
-            e["docs"] += sign
-            if e["docs"] <= 0:
-                fs.pop(field, None)
+        _fold_fields(stats["fields"], text, sign)
     return stats
+
+
+def _fold_fields(fs: dict, text, sign: int) -> None:
+    """Fold one document's per-field totals into ``fs`` (``stats["fields"]``)."""
+    for field, ftext in field_items(text):
+        e = fs.setdefault(field, {"total": 0, "docs": 0})
+        e["total"] += sign * len(tokenize(ftext))
+        e["docs"] += sign
+        if e["docs"] <= 0:
+            fs.pop(field, None)
+
+
+class LiveStats(Mapping):
+    """The live corpus's BM25 statistics held by term id, for a writer that
+    folds documents in and out a few at a time.
+
+    ``df`` is an int64 array over the vocabulary (``terms`` in id order,
+    append-only, ``vocab`` its inverse), beside the live document count and
+    the exact token total. Folding a document in or out (:meth:`update`)
+    costs its distinct terms; :meth:`idf` is one vectorized pass. From
+    :meth:`mark` every change is journaled, so a failed commit undoes what
+    it changed (:meth:`rollback`) instead of restoring a copy.
+
+    It reads as the ``compute_global_stats`` dict (``n_docs``, ``avgdl``,
+    ``df`` by term, and ``fields`` on structured corpora), so code written
+    for that dict takes it too; ``["df"]`` builds that dict on each read.
+    ``ref`` names the published state it was last published as or loaded
+    from: the parent its next publish is written against."""
+
+    def __init__(self, vocab: dict, terms: list, df: np.ndarray, n_docs: int,
+                 total_len: int, fields: "dict | None" = None,
+                 ref: "list | None" = None) -> None:
+        self.vocab = vocab
+        self.terms = terms
+        self._df = df
+        self.n_docs = int(n_docs)
+        self.total_len = int(total_len)
+        self.fields = fields
+        self.ref = ref
+        self._journal: "dict[int, int] | None" = None
+        self._idf: "np.ndarray | None" = None
+
+    @classmethod
+    def from_stats(cls, stats: Mapping, vocab: dict,
+                   ref: "list | None" = None) -> "LiveStats":
+        """From ``compute_global_stats``-shaped stats over ``vocab``."""
+        if isinstance(stats, LiveStats):
+            out = stats.copy()
+            out.ref = ref if ref is not None else out.ref
+            return out
+        terms: list = [None] * len(vocab)
+        for t, i in vocab.items():
+            terms[i] = t
+        df_map = stats["df"]
+        df = np.zeros(max(16, len(terms)), np.int64)
+        df[:len(terms)] = np.fromiter((df_map.get(t, 0) for t in terms),
+                                      np.int64, count=len(terms))
+        n = int(stats["n_docs"])
+        total = stats.get("_total_len")
+        if total is None:
+            total = round(stats["avgdl"] * max(1, n)) if n else 0
+        fields = copy.deepcopy(stats["fields"]) if "fields" in stats else None
+        return cls(dict(vocab), terms, df, n, total, fields, ref)
+
+    def copy(self) -> "LiveStats":
+        return LiveStats(dict(self.vocab), list(self.terms),
+                         self._df.copy(), self.n_docs, self.total_len,
+                         copy.deepcopy(self.fields),
+                         list(self.ref) if self.ref else None)
+
+    @property
+    def n_terms(self) -> int:
+        return len(self.terms)
+
+    @property
+    def df(self) -> np.ndarray:
+        """(n_terms,) int64 live document frequency by term id."""
+        return self._df[:len(self.terms)]
+
+    @property
+    def avgdl(self) -> float:
+        return self.total_len / max(1, self.n_docs)
+
+    def idf(self) -> np.ndarray:
+        """(n_terms,) float32 BM25 idf over the live documents — the
+        formula ``combine_segments`` applies to a generation's stats.
+        Computed once per state: every partition's delta of one commit
+        shares it (treat it as read-only)."""
+        if self._idf is None:
+            df = self.df.astype(np.float64)
+            self._idf = np.log(1.0 + (self.n_docs - df + 0.5)
+                               / (df + 0.5)).astype(np.float32)
+        return self._idf
+
+    # -- the compute_global_stats view ----------------------------------------
+
+    def _keys(self) -> tuple:
+        return (("n_docs", "avgdl", "df")
+                + (("fields",) if self.fields is not None else ()))
+
+    def __getitem__(self, key: str):
+        if key == "n_docs":
+            return self.n_docs
+        if key == "avgdl":
+            return self.avgdl
+        if key == "df":
+            df = self.df
+            return {self.terms[i]: int(df[i]) for i in np.flatnonzero(df)}
+        if key == "fields" and self.fields is not None:
+            return self.fields
+        raise KeyError(key)
+
+    def __iter__(self):
+        return iter(self._keys())
+
+    def __len__(self) -> int:
+        return len(self._keys())
+
+    # -- folding documents in and out -----------------------------------------
+
+    def add_terms(self, terms: Iterable[str]) -> list[str]:
+        """Append the terms not yet in the vocabulary, sorted, under fresh
+        ids (existing ids never move: :func:`extend_vocab`'s rule).
+        Returns the terms added."""
+        new = sorted({t for t in terms if t not in self.vocab})
+        if not new:
+            return new
+        self._idf = None
+        need = len(self.terms) + len(new)
+        if need > len(self._df):
+            grown = np.zeros(max(need, 2 * len(self._df)), np.int64)
+            grown[:len(self._df)] = self._df
+            self._df = grown
+        for t in new:
+            self.vocab[t] = len(self.terms)
+            self.terms.append(t)
+        return new
+
+    def update(self, counts: Mapping, text=None, *, sign: int = 1) -> None:
+        """Fold one document (its ``token_counts``) in (+1) or out (-1);
+        its terms must be in the vocabulary. ``text`` feeds the per-field
+        totals of a structured corpus."""
+        ids = np.fromiter((self.vocab[t] for t in counts), np.int64,
+                          count=len(counts))
+        self._idf = None
+        if self._journal is not None:
+            for i in ids.tolist():
+                self._journal.setdefault(i, int(self._df[i]))
+        self._df[ids] += sign
+        self.n_docs += sign
+        self.total_len += sign * sum(counts.values())
+        if self.fields is not None and text is not None:
+            _fold_fields(self.fields, text, sign)
+
+    def mark(self) -> tuple:
+        """Start journaling; the token :meth:`rollback` returns to."""
+        self._journal = {}
+        return (self.n_docs, self.total_len, len(self.terms),
+                copy.deepcopy(self.fields), self.ref)
+
+    def rollback(self, token: tuple) -> None:
+        """Undo every change since :meth:`mark` returned ``token``."""
+        self.n_docs, self.total_len, n_terms, self.fields, self.ref = token
+        if self._journal:
+            ids = np.fromiter(self._journal, np.int64, len(self._journal))
+            self._df[ids] = np.fromiter(self._journal.values(), np.int64,
+                                        len(self._journal))
+        for t in self.terms[n_terms:]:
+            del self.vocab[t]
+        del self.terms[n_terms:]
+        self._df[n_terms:] = 0
+        self._journal = None
+        self._idf = None
+
+    def release(self) -> None:
+        """Stop journaling: the changes since :meth:`mark` stand."""
+        self._journal = None
 
 
 class IndexWriter:
@@ -369,52 +542,97 @@ class IndexWriter:
 
     # -- packing ----------------------------------------------------------------
 
-    def pack(self) -> PackedIndex:
-        n_docs = len(self._doc_ids)
-        if self.vocab is not None:
-            vocab = dict(self.vocab)
-            uncovered = [t for t in self._postings if t not in vocab]
-            if uncovered:        # a stale vocab would silently lose postings
-                raise ValueError(
-                    f"{len(uncovered)} added term(s) missing from the fixed "
-                    f"vocab (e.g. {sorted(uncovered)[:5]}) — rebuild the "
-                    "global vocab before packing")
-            terms = [None] * len(vocab)
-            for t, i in vocab.items():
-                terms[i] = t
-        else:
-            if n_docs == 0:
-                raise ValueError("empty index")
-            terms = sorted(self._postings)
-            vocab = {t: i for i, t in enumerate(terms)}
-        V = len(terms)
-        avgdl = float(np.mean(self._doc_len)) if self._doc_len else 0.0
+    def _idf(self, vocab: dict, present: list, n_docs: int) -> np.ndarray:
+        """(V,) float32 idf of every vocabulary term: over the global stats
+        when given (a term they lack takes its local df), else local. A
+        :class:`LiveStats` gives it in one vectorized pass; a dict of
+        stats one term at a time, in id order."""
+        V = len(vocab)
         gs = self.global_stats
+        if isinstance(gs, LiveStats):
+            live = gs.df
+            lone = [(ti, len(self._postings[term])) for ti, term in present
+                    if ti >= len(live) or live[ti] == 0]
+            if V == gs.n_terms and not lone:
+                return gs.idf()
+            df = np.zeros(V, np.float64)
+            n = min(V, gs.n_terms)
+            df[:n] = live[:n]
+            for ti, local in lone:
+                df[ti] = local
+            return np.log(1.0 + (gs.n_docs - df + 0.5)
+                          / (df + 0.5)).astype(np.float32)
+        terms: list = [None] * V
+        for t, i in vocab.items():
+            terms[i] = t
         stat_docs = gs["n_docs"] if gs else n_docs
-        if gs:
-            avgdl = gs["avgdl"]
-        doc_len = np.asarray(self._doc_len + [1.0], dtype=np.float32)  # +dump
-
         idf = np.zeros(V, dtype=np.float32)
+        for ti, term in enumerate(terms):
+            local_df = len(self._postings.get(term) or ())
+            df = gs["df"].get(term, local_df) if gs else local_df   # global
+            idf[ti] = math.log(1.0 + (stat_docs - df + 0.5) / (df + 0.5))
+        return idf
+
+    def _blocks(self, present: list, V: int, idf: np.ndarray,
+                doc_len: np.ndarray, avgdl: float, n_docs: int):
+        """Format-v1 posting blocks of the ``present`` (id, term) pairs, in
+        one vectorized pass: each term's postings impact-sorted descending
+        (ties in insertion order) and cut into B-wide blocks padded with
+        the dump doc. Returns (block_docs, block_tf, block_max, blocks per
+        term id)."""
+        from itertools import chain
+        B = self.block
+        k1, b = self.k1, self.b
+        plists = [self._postings[t] for _, t in present]
+        counts = np.fromiter(map(len, plists), np.int64, count=len(plists))
+        total = int(counts.sum())
+        tids = np.repeat(np.fromiter((i for i, _ in present), np.int64,
+                                     count=len(present)), counts)
+        docs = np.fromiter(chain.from_iterable(p.keys() for p in plists),
+                           np.int32, count=total)
+        tfs = np.fromiter(chain.from_iterable(p.values() for p in plists),
+                          np.int64, count=total)
+        # per-posting impact for ordering (the first blocks of each term
+        # carry its highest-scoring docs)
+        dl = doc_len[docs]
+        imp = idf[tids] * tfs / (tfs + k1 * (1 - b + b * dl / avgdl))
+        order = np.lexsort((-imp, tids))
+        docs, tfs, imp = docs[order], tfs[order], imp[order]
+        n_blk = -(-counts // B)
+        start = np.concatenate([[0], np.cumsum(n_blk)[:-1]])
+        first = np.concatenate([[0], np.cumsum(counts)[:-1]])
+        dest = (np.repeat(start * B - first, counts)
+                + np.arange(total, dtype=np.int64))
+        NB = int(n_blk.sum())
+        flat_docs = np.full(NB * B, n_docs, np.int32)
+        flat_tf = np.zeros(NB * B, np.uint8)
+        flat_imp = np.zeros(NB * B)
+        flat_docs[dest] = docs
+        flat_tf[dest] = np.minimum(tfs, 255)
+        flat_imp[dest] = imp
+        n_blocks = np.zeros(V, dtype=np.int32)
+        n_blocks[[i for i, _ in present]] = n_blk
+        return (flat_docs.reshape(NB, B), flat_tf.reshape(NB, B),
+                flat_imp.reshape(NB, B).max(axis=1, initial=0.0
+                                            ).astype(np.float32), n_blocks)
+
+    def _blocks_structured(self, present: list, V: int, idf: np.ndarray,
+                           doc_len: np.ndarray, avgdl: float, n_docs: int):
+        """:meth:`_blocks` for format v2, term by term, with each block's
+        stored occurrences alongside."""
         blocks_docs: list[np.ndarray] = []
         blocks_tf: list[np.ndarray] = []
         blocks_max: list[float] = []
-        offsets = np.zeros(V + 1, dtype=np.int32)
+        n_blocks = np.zeros(V, dtype=np.int32)
         P = self.pos_slots
         blocks_nocc: list[np.ndarray] = []
         blocks_occf: list[np.ndarray] = []
         blocks_occp: list[np.ndarray] = []
-
         B = self.block
         k1, b = self.k1, self.b
-        for ti, term in enumerate(terms):
-            plist = self._postings.get(term) or {}   # {} when the term is
-            local_df = len(plist)                    # global-vocab-only here
-            df = gs["df"].get(term, local_df) if gs else local_df  # global
-            idf[ti] = math.log(1.0 + (stat_docs - df + 0.5) / (df + 0.5))
-            if not local_df:                         # no blocks here
-                offsets[ti + 1] = offsets[ti]
-                continue
+        for ti, term in present:
+            plist = self._postings[term]
+            local_df = len(plist)
             docs = np.fromiter(plist.keys(), dtype=np.int32, count=local_df)
             tfs = np.fromiter(plist.values(), dtype=np.int64, count=local_df)
             # per-posting impact for ordering
@@ -426,24 +644,23 @@ class IndexWriter:
             docs, tfs, imp = docs[order], tfs[order], imp[order]
             n_blk = -(-local_df // B)
             pad = n_blk * B - local_df
-            if self.structured:
-                # stored occurrences, aligned with the impact-sorted
-                # postings then padded like docs/tf
-                occ_map = self._occ.get(term) or {}
-                nocc = np.zeros(n_blk * B, np.uint8)
-                occf = np.zeros((n_blk * B, P), np.uint8)
-                occp = np.zeros((n_blk * B, P), np.uint16)
-                for i, d in enumerate(docs[:local_df]):
-                    lst = occ_map.get(int(d), ())[:P]
-                    nocc[i] = len(lst)
-                    for s, (fid, pos) in enumerate(lst):
-                        occf[i, s] = fid
-                        occp[i, s] = pos
-                for j in range(n_blk):
-                    sl = slice(j * B, (j + 1) * B)
-                    blocks_nocc.append(nocc[sl])
-                    blocks_occf.append(occf[sl])
-                    blocks_occp.append(occp[sl])
+            # stored occurrences, aligned with the impact-sorted postings
+            # then padded like docs/tf
+            occ_map = self._occ.get(term) or {}
+            nocc = np.zeros(n_blk * B, np.uint8)
+            occf = np.zeros((n_blk * B, P), np.uint8)
+            occp = np.zeros((n_blk * B, P), np.uint16)
+            for i, d in enumerate(docs[:local_df]):
+                lst = occ_map.get(int(d), ())[:P]
+                nocc[i] = len(lst)
+                for s, (fid, pos) in enumerate(lst):
+                    occf[i, s] = fid
+                    occp[i, s] = pos
+            for j in range(n_blk):
+                sl = slice(j * B, (j + 1) * B)
+                blocks_nocc.append(nocc[sl])
+                blocks_occf.append(occf[sl])
+                blocks_occp.append(occp[sl])
             docs = np.concatenate([docs, np.full(pad, n_docs, np.int32)])
             tfs = np.concatenate([np.minimum(tfs, 255).astype(np.uint8),
                                   np.zeros(pad, np.uint8)])
@@ -453,9 +670,51 @@ class IndexWriter:
                 blocks_docs.append(docs[sl])
                 blocks_tf.append(tfs[sl])
                 blocks_max.append(float(imp[sl].max(initial=0.0)))
-            offsets[ti + 1] = offsets[ti] + n_blk
+            n_blocks[ti] = n_blk
+        block_docs = (np.stack(blocks_docs) if blocks_docs
+                      else np.zeros((0, B), np.int32))
+        block_tf = (np.stack(blocks_tf) if blocks_tf
+                    else np.zeros((0, B), np.uint8))
+        return (block_docs, block_tf, np.asarray(blocks_max, np.float32),
+                n_blocks, (blocks_nocc, blocks_occf, blocks_occp))
 
-        NB = len(blocks_docs)
+    def pack(self) -> PackedIndex:
+        n_docs = len(self._doc_ids)
+        if self.vocab is not None:
+            vocab = self.vocab
+            uncovered = [t for t in self._postings if t not in vocab]
+            if uncovered:        # a stale vocab would silently lose postings
+                raise ValueError(
+                    f"{len(uncovered)} added term(s) missing from the fixed "
+                    f"vocab (e.g. {sorted(uncovered)[:5]}) — rebuild the "
+                    "global vocab before packing")
+            present = sorted((vocab[t], t) for t in self._postings)
+        else:
+            if n_docs == 0:
+                raise ValueError("empty index")
+            present = list(enumerate(sorted(self._postings)))
+            vocab = {t: i for i, t in present}
+        V = len(vocab)
+        avgdl = float(np.mean(self._doc_len)) if self._doc_len else 0.0
+        gs = self.global_stats
+        if gs:
+            avgdl = gs["avgdl"]
+        doc_len = np.asarray(self._doc_len + [1.0], dtype=np.float32)  # +dump
+        idf = self._idf(vocab, present, n_docs)
+
+        B = self.block
+        k1, b = self.k1, self.b
+        if not self.structured:
+            block_docs, block_tf, block_max, n_blocks = self._blocks(
+                present, V, idf, doc_len, avgdl, n_docs)
+        else:
+            block_docs, block_tf, block_max, n_blocks, fblocks = \
+                self._blocks_structured(present, V, idf, doc_len, avgdl,
+                                        n_docs)
+        offsets = np.zeros(V + 1, dtype=np.int32)
+        np.cumsum(n_blocks, out=offsets[1:])
+
+        NB = len(block_max)
         meta = IndexMeta(
             n_docs=n_docs, n_terms=V, n_blocks=NB, block=B, avgdl=avgdl,
             k1=k1, b=b, doc_ids=self._doc_ids,
@@ -478,6 +737,8 @@ class IndexWriter:
                 for v, i in vmap.items():
                     vals[i] = v
                 facet_values.append(vals)
+            blocks_nocc, blocks_occf, blocks_occp = fblocks
+            P = self.pos_slots
             fields = FieldData(
                 field_names=list(self._field_names), pos_slots=P,
                 field_len=field_len,
@@ -493,9 +754,9 @@ class IndexWriter:
             meta=meta,
             vocab=vocab,
             term_offsets=offsets,
-            block_docs=np.stack(blocks_docs) if NB else np.zeros((0, B), np.int32),
-            block_tf=np.stack(blocks_tf) if NB else np.zeros((0, B), np.uint8),
-            block_max=np.asarray(blocks_max, dtype=np.float32),
+            block_docs=block_docs,
+            block_tf=block_tf,
+            block_max=block_max,
             doc_len=doc_len,
             idf=idf,
             fields=fields,
@@ -531,6 +792,7 @@ SUPERINDEX_FILE = "superindex.bin"
 PAYLOAD_FILE = "blocks.bin"
 _SUPERINDEX_MAGIC = b"SUPX"      # format v1: 6 sections, 5 B/lane payload
 _SUPERINDEX_MAGIC_V2 = b"SUP2"   # format v2: + fields/positions/facets
+_SUPERINDEX_MAGIC_LEAN = b"SUPL"  # format v1, a generation's: terms sparse
 
 # v2 superindex extra sections (after the 6 v1 sections): fields header
 # json, field_len npy, facet_ids npy
@@ -557,23 +819,35 @@ def _fields_header(fd: FieldData) -> dict:
             "facet_names": fd.facet_names, "facet_values": fd.facet_values}
 
 
-def pack_superindex(index: PackedIndex) -> bytes:
+def pack_superindex(index: PackedIndex, *, lean: bool = False) -> bytes:
     """The segment header: everything a query-sufficient partial view needs
     EXCEPT the posting blocks themselves, framed as length-prefixed
     sections (meta json, vocab json, then term_offsets / block_max /
     doc_len / idf as npy). A v2 segment (``index.fields``) appends the
     fields header json, field_len and facet_ids — still one ranged GET;
     the per-posting occurrence arrays live in the payload rows. A v1
-    segment's bytes are unchanged."""
-    sections = [
-        index.meta.to_json(),
-        orjson.dumps(index.vocab),
-        _npy_bytes(index.term_offsets),
-        _npy_bytes(index.block_max),
-        _npy_bytes(index.doc_len),
-        _npy_bytes(index.idf),
-    ]
+    segment's bytes are unchanged.
+
+    ``lean`` (a generation's segment, :func:`write_segment`) writes an
+    empty vocab and, for v1, the term index sparse: the ids of the terms
+    with blocks, each one's block end, and their idf in place of the
+    vocabulary-long ``term_offsets`` and ``idf`` — a delta's header then
+    costs its terms, not the vocabulary. :func:`unpack_superindex` gives
+    the dense arrays back (idf 0 for the terms without blocks: a
+    generation scores with its own idf, never a segment's)."""
+    sections = [index.meta.to_json(), orjson.dumps({} if lean else index.vocab)]
     magic = _SUPERINDEX_MAGIC
+    if lean and index.fields is None:
+        magic = _SUPERINDEX_MAGIC_LEAN
+        to = index.term_offsets
+        ids = np.flatnonzero(to[1:] > to[:-1]).astype(np.int32)
+        sections += [_npy_bytes(ids), _npy_bytes(to[ids + 1]),
+                     _npy_bytes(index.block_max), _npy_bytes(index.doc_len),
+                     _npy_bytes(index.idf[ids])]
+    else:
+        sections += [_npy_bytes(index.term_offsets),
+                     _npy_bytes(index.block_max),
+                     _npy_bytes(index.doc_len), _npy_bytes(index.idf)]
     if index.fields is not None:
         fd = index.fields
         magic = _SUPERINDEX_MAGIC_V2
@@ -598,9 +872,11 @@ def unpack_superindex(data: bytes) -> tuple[IndexMeta, dict,
     ``field_len`` and ``facet_ids`` arrays (the block-aligned occurrence
     arrays hydrate from payload rows, not the header)."""
     magic = data[:4]
-    if magic not in (_SUPERINDEX_MAGIC, _SUPERINDEX_MAGIC_V2):
+    if magic not in (_SUPERINDEX_MAGIC, _SUPERINDEX_MAGIC_V2,
+                     _SUPERINDEX_MAGIC_LEAN):
         raise ValueError("not a superindex blob")
-    n_sections = 6 + (_V2_SECTIONS if magic == _SUPERINDEX_MAGIC_V2 else 0)
+    n_sections = {_SUPERINDEX_MAGIC: 6, _SUPERINDEX_MAGIC_V2: 6 + _V2_SECTIONS,
+                  _SUPERINDEX_MAGIC_LEAN: 7}[magic]
     sections, pos = [], 4
     for _ in range(n_sections):
         n = int.from_bytes(data[pos:pos + 4], "little")
@@ -609,6 +885,15 @@ def unpack_superindex(data: bytes) -> tuple[IndexMeta, dict,
         pos += n
     meta = IndexMeta.from_json(sections[0])
     vocab = orjson.loads(sections[1])
+    if magic == _SUPERINDEX_MAGIC_LEAN:
+        ids, ends, block_max, doc_len, idf_ids = map(_npy_load, sections[2:])
+        n_blocks = np.zeros(meta.n_terms, np.int32)
+        n_blocks[ids] = np.diff(ends, prepend=0)
+        term_offsets = np.zeros(meta.n_terms + 1, np.int32)
+        np.cumsum(n_blocks, out=term_offsets[1:])
+        idf = np.zeros(meta.n_terms, np.float32)
+        idf[ids] = idf_ids
+        return meta, vocab, [term_offsets, block_max, doc_len, idf], None
     arrays = [_npy_load(s) for s in sections[2:6]]
     fields_header = None
     if magic == _SUPERINDEX_MAGIC_V2:
@@ -669,19 +954,28 @@ def unpack_payload_rows(chunk: bytes, block: int, pos_slots: int = 0):
     return docs, tf, nocc, occf, occp.reshape(n, B, P)
 
 
-def write_segment(index: PackedIndex, directory: RamDirectory | None = None) -> RamDirectory:
-    """Serialize to Directory files (then publish via AssetCatalog)."""
+def write_segment(index: PackedIndex, directory: RamDirectory | None = None,
+                  *, lean: bool = False) -> RamDirectory:
+    """Serialize to Directory files (then publish via AssetCatalog).
+
+    ``lean`` writes a generation's segment: an empty vocabulary, since a
+    generation's segments are read through its own shared vocabulary
+    (writing the whole vocabulary into every delta, twice, as JSON, would
+    make a commit cost the vocabulary, not the delta), and, for format v1,
+    no eager ``*.npy`` twins: the lazy layout holds the same arrays and
+    :func:`read_segment` reads it in their place."""
     d = directory if directory is not None else RamDirectory()
     d.write("meta.json", index.meta.to_json())
-    d.write("vocab.json", orjson.dumps(index.vocab))
-    for name in SEGMENT_FILES:
-        d.write(name + ".npy", _npy_bytes(getattr(index, name)))
+    d.write("vocab.json", orjson.dumps({} if lean else index.vocab))
+    if not lean or index.fields is not None:
+        for name in SEGMENT_FILES:
+            d.write(name + ".npy", _npy_bytes(getattr(index, name)))
     if index.fields is not None:        # v2 eager twin files
         d.write(FIELDS_FILE, orjson.dumps(_fields_header(index.fields)))
         for name in FIELD_NPY_FILES:
             d.write(name + ".npy", _npy_bytes(getattr(index.fields, name)))
     # lazy-hydration layout: header ahead of the interleaved block payload
-    d.write(SUPERINDEX_FILE, pack_superindex(index))
+    d.write(SUPERINDEX_FILE, pack_superindex(index, lean=lean))
     d.write(PAYLOAD_FILE, pack_payload(index))
     return d
 
@@ -695,10 +989,13 @@ def read_segment(directory: Directory) -> PackedIndex:
     """
     meta = IndexMeta.from_json(directory.open_input("meta.json").read_all())
     vocab = orjson.loads(directory.open_input("vocab.json").read_all())
-    arrays = {
-        name: _npy_load(directory.open_input(name + ".npy").read_all())
-        for name in SEGMENT_FILES
-    }
+    try:
+        arrays = {
+            name: _npy_load(directory.open_input(name + ".npy").read_all())
+            for name in SEGMENT_FILES
+        }
+    except DirectoryError:             # written without its eager twins
+        return read_lazy_layout(directory)
     fields = None
     try:
         # v2 sidecar probe: a miss raises before any simulated network
@@ -716,6 +1013,22 @@ def read_segment(directory: Directory) -> PackedIndex:
                            facet_names=hdr["facet_names"],
                            facet_values=hdr["facet_values"], **fnpy)
     return PackedIndex(meta=meta, vocab=vocab, fields=fields, **arrays)
+
+
+def read_lazy_layout(directory: Directory) -> PackedIndex:
+    """A format-v1 segment read whole from its lazy layout alone — the
+    header (meta included) and the block payload, two objects — as
+    ``write_segment(lean=True)`` leaves it. Raises ``ValueError`` for a
+    format-v2 segment, ``DirectoryError`` where a file is missing."""
+    meta, vocab, (term_offsets, block_max, doc_len, idf), fields = \
+        unpack_superindex(directory.open_input(SUPERINDEX_FILE).read_all())
+    if fields is not None:
+        raise ValueError("a format-v2 segment reads through read_segment")
+    docs, tf = unpack_payload_rows(
+        directory.open_input(PAYLOAD_FILE).read_all(), meta.block)
+    return PackedIndex(meta=meta, vocab=vocab, term_offsets=term_offsets,
+                       block_docs=docs, block_tf=tf, block_max=block_max,
+                       doc_len=doc_len, idf=idf)
 
 
 # -- NRT: combining base + delta segments at hydration ---------------------------
@@ -928,6 +1241,50 @@ def combine_segments(packs: list[PackedIndex], *, vocab: dict[str, int],
         doc_len=doc_len, idf=idf, fields=fields)
 
 
+def concat_segments(packs: list[PackedIndex]) -> PackedIndex:
+    """Concatenate delta segments into one, as they stand: the documents
+    keep their order (so every internal position, tombstones included,
+    stays where it was), each term's blocks follow pack order, and the
+    blocks keep their pack-time ``block_max``. A generation's live stats
+    re-derive idf and impact order at hydration, so nothing here depends
+    on them: this is the tiered delta merge, and its cost is the deltas'
+    postings. Format-v1 segments only (structured deltas re-pack)."""
+    if not packs:
+        raise ValueError("concat_segments needs at least one segment")
+    if any(p.fields is not None for p in packs):
+        raise ValueError("concat_segments takes format-v1 segments")
+    B = packs[0].meta.block
+    V = max(len(p.term_offsets) - 1 for p in packs)
+    offs = np.cumsum([0] + [p.meta.n_docs for p in packs])
+    n_docs = int(offs[-1])
+    cat_docs, cat_tf, cat_max, cat_term = [], [], [], []
+    for p, off in zip(packs, offs[:-1].tolist()):
+        docs = p.block_docs.astype(np.int64)
+        docs = np.where(docs >= p.meta.n_docs, n_docs, docs + off)
+        to = p.term_offsets.astype(np.int64)
+        cat_docs.append(docs.astype(np.int32))
+        cat_tf.append(p.block_tf)
+        cat_max.append(p.block_max)
+        cat_term.append(np.repeat(np.arange(len(to) - 1), to[1:] - to[:-1]))
+    term = np.concatenate(cat_term)
+    order = np.argsort(term, kind="stable")
+    offsets = np.zeros(V + 1, np.int32)
+    np.cumsum(np.bincount(term, minlength=V)[:V], out=offsets[1:])
+    last = max(packs, key=lambda p: len(p.term_offsets))
+    meta = IndexMeta(
+        n_docs=n_docs, n_terms=V, n_blocks=len(term), block=B,
+        avgdl=packs[-1].meta.avgdl, k1=packs[0].meta.k1, b=packs[0].meta.b,
+        doc_ids=[e for p in packs for e in p.meta.doc_ids])
+    return PackedIndex(
+        meta=meta, vocab=last.vocab, term_offsets=offsets,
+        block_docs=np.concatenate(cat_docs)[order].reshape(-1, B),
+        block_tf=np.concatenate(cat_tf)[order].reshape(-1, B),
+        block_max=np.concatenate(cat_max)[order].astype(np.float32),
+        doc_len=np.concatenate([p.doc_len[:p.meta.n_docs] for p in packs]
+                               + [[1.0]]).astype(np.float32),
+        idf=last.idf)
+
+
 # -- dense-vector tier (hybrid retrieval) -----------------------------------------
 #
 # "Vector Search with OpenAI Embeddings: Lucene Is All You Need" — a dense
@@ -1100,33 +1457,44 @@ def combine_vector_segments(packs: list[PackedVectors],
 
 @dataclasses.dataclass
 class MergePolicy:
-    """Size-tiered delta compaction: when does the delta tier fold back
-    into the base segment?
+    """Tiered compaction, after Lucene's ``TieredMergePolicy``: when do
+    segments merge, and which?
 
-    A growing delta tier costs on three axes — more blocks to hydrate and
-    evaluate per query, dead weight (a tombstoned posting's tf zeroes at
-    hydration, but it still occupies a block slot that gathers, scores to
-    0, and pads the doc-id space — wasted lanes and accumulator width),
-    and manifest bloat. Compaction rebuilds the partition's base from its
-    LIVE docs (purging tombstones) at the cost of one full re-pack +
-    re-upload. Triggers, any of:
+    A growing delta tier costs on three axes — more segments to open on a
+    cold hydration, dead weight (a tombstoned posting still occupies a
+    block slot that gathers, scores to 0, and pads the doc-id space), and
+    manifest bloat. Two merges bound it (:meth:`plan`):
 
-    * ``max_deltas``  — the tier is longer than this many segments;
-    * ``ratio``       — delta-tier docs outgrow ``ratio`` × base docs
-                        (the size-tiered criterion);
-    * ``tombstone_ratio`` — deleted docs outgrow this fraction of all docs
-                        (the dead-weight bound).
+    * ``"deltas"`` — the tier is longer than ``max_deltas`` segments: the
+      deltas (and the commit's own) concatenate into ONE delta. Documents
+      keep their positions, so the merge costs the deltas' postings and
+      the serving side's state carries over unchanged;
+    * ``"base"`` — delta-tier docs outgrow ``ratio`` × base docs (the
+      size-tiered criterion) or deleted docs outgrow ``tombstone_ratio``
+      of all docs (the dead-weight bound): the partition's LIVE docs re-pack
+      as a fresh base, purging tombstones — one full re-pack and
+      re-hydration. With ``max_deltas`` 0 no delta may stand, so a long
+      tier folds into the base too.
     """
 
     max_deltas: int = 4
     ratio: float = 0.5
     tombstone_ratio: float = 0.2
 
-    def should_merge(self, base_docs: int, delta_docs: int,
-                     n_deltas: int, n_tombstones: int) -> bool:
+    def plan(self, base_docs: int, delta_docs: int, n_deltas: int,
+             n_tombstones: int) -> "str | None":
+        """``"base"``, ``"deltas"`` or None (no merge)."""
         total = base_docs + delta_docs
         if n_deltas == 0 and n_tombstones == 0:
-            return False
-        return (n_deltas > self.max_deltas
-                or delta_docs > self.ratio * max(1, base_docs)
-                or n_tombstones > self.tombstone_ratio * max(1, total))
+            return None
+        if (delta_docs > self.ratio * max(1, base_docs)
+                or n_tombstones > self.tombstone_ratio * max(1, total)):
+            return "base"
+        if n_deltas > self.max_deltas:
+            return "deltas" if self.max_deltas else "base"
+        return None
+
+    def should_merge(self, base_docs: int, delta_docs: int,
+                     n_deltas: int, n_tombstones: int) -> bool:
+        return self.plan(base_docs, delta_docs, n_deltas,
+                         n_tombstones) is not None

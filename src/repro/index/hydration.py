@@ -368,10 +368,14 @@ class LazyIndex:
 
     def __init__(self, segments: list[PartialSegment], *,
                  vocab: dict | None = None, stats: dict | None = None,
-                 tombstones=()) -> None:
+                 tombstones=(), segment_ids: "list[str] | None" = None
+                 ) -> None:
         if not segments:
             raise ValueError("LazyIndex needs at least one segment")
         self.segments = segments
+        # a generation's segment ids, base first (None for a plain
+        # version): what a later generation is derived against
+        self.segment_ids = segment_ids
         self._gen_state = (vocab, stats) if stats is not None else None
         self.tombstones = list(tombstones)
         self.vocab = vocab if vocab is not None else segments[0].vocab

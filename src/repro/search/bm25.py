@@ -19,6 +19,13 @@ Fixed-shape, jit-compatible score-at-a-time evaluation:
                    reference with the identical keep mask.
 * top-k over accumulated scores.
 
+A state derived for a later index generation (``SearchState.tail``, dense
+accumulator only) scores its base segment as above and the documents its
+commits added since — the TAIL — in the same call: a small forward index,
+one row of (term, tf) slots a document, matched against the query's terms
+and written into the same accumulator after the base's documents. Deleted
+documents carry an infinite length, so they score 0 in either part.
+
 All strategies must agree with :class:`repro.search.oracle.OracleSearcher`
 whenever M·B covers every posting of every query term (tests enforce this);
 ``pruned`` must be BIT-identical — it only skips blocks provably unable to
@@ -36,10 +43,51 @@ import numpy as np
 from repro.index.builder import PackedIndex
 
 
+TAIL_MIN_DOCS = 1024   # the smallest tail capacity (a power of two)
+TAIL_MIN_SLOTS = 128   # the smallest row width: distinct terms a document
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class TailState:
+    """The documents a generation's commits added since its base segment,
+    as a forward index on the device: row d holds document d's distinct
+    terms (``terms``, -1 = empty slot) and their tfs. ``doc_len`` is
+    infinite for a deleted or empty row, so it scores 0. Both widths are
+    powers of two with a floor (``TAIL_MIN_DOCS`` rows, ``TAIL_MIN_SLOTS``
+    slots) and only grow, so that a run of generations shares one
+    compiled program."""
+
+    terms: jax.Array          # (cap, L) int32
+    tf: jax.Array             # (cap, L) uint8
+    doc_len: jax.Array        # (cap,) float32
+
+    def tree_flatten(self):
+        return (self.terms, self.tf, self.doc_len), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves)
+
+    @property
+    def cap(self) -> int:
+        return self.terms.shape[0]
+
+
+def pow2_at_least(n: int, floor: int) -> int:
+    out = floor
+    while out < n:
+        out *= 2
+    return out
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class SearchState:
-    """Device-resident index arrays (the hydrated 'warm' state)."""
+    """Device-resident index arrays (the hydrated 'warm' state). A state
+    derived for a later generation adds a ``tail`` and gives deleted base
+    documents an infinite ``doc_len``; one hydrated from a whole segment
+    set has no tail and today's shapes."""
 
     term_offsets: jax.Array   # (V+1,) int32
     block_docs: jax.Array     # (NB, B) int32
@@ -51,16 +99,23 @@ class SearchState:
     k1: jax.Array             # () float32
     b: jax.Array              # () float32
     n_docs: int               # static
+    tail: TailState | None = None
 
     def tree_flatten(self):
         leaves = (self.term_offsets, self.block_docs, self.block_tf,
                   self.block_max, self.doc_len, self.idf, self.avgdl,
-                  self.k1, self.b)
+                  self.k1, self.b, self.tail)
         return leaves, self.n_docs
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(*leaves, n_docs=aux)
+        *arrays, tail = leaves
+        return cls(*arrays, n_docs=aux, tail=tail)
+
+    @property
+    def n_slots(self) -> int:
+        """Accumulator width: base documents, then the tail's capacity."""
+        return self.n_docs + (self.tail.cap if self.tail is not None else 0)
 
     @classmethod
     def from_packed(cls, idx: PackedIndex) -> "SearchState":
@@ -114,7 +169,10 @@ def bm25_impacts(state: SearchState, term_ids: jax.Array, qtf: jax.Array,
         denom = tff + state.k1 * (1.0 - state.b + state.b * dl / state.avgdl)
         imp = idf[:, None, None] * tff / denom
     pad = docs >= state.n_docs
-    return jnp.where(valid & ~pad & (tf > 0), imp, 0.0)
+    keep = valid & ~pad & (tf > 0)
+    if state.tail is not None:           # a derived state: deletes are inf
+        keep = keep & jnp.isfinite(dl)
+    return jnp.where(keep, imp, 0.0)
 
 
 def score_dense(state: SearchState, term_ids: jax.Array, qtf: jax.Array,
@@ -127,9 +185,44 @@ def score_dense(state: SearchState, term_ids: jax.Array, qtf: jax.Array,
     """
     docs, tf, _, valid = gather_query_blocks(state, term_ids, max_blocks)
     docs = docs.astype(jnp.int32)        # block_docs may be uint16 (compact)
+    if state.tail is not None:
+        return _score_with_tail(state, term_ids, qtf, docs, tf, valid)
     imp = bm25_impacts(state, term_ids, qtf, docs, tf, valid,
                        use_kernel=use_kernel)
     return accumulate_dense(docs, imp, state.n_docs)
+
+
+def _score_with_tail(state: SearchState, term_ids, qtf, docs, tf, valid):
+    """A derived state's dense scores: the base's gathered postings and
+    one posting per (query term, tail document) — its tf from matching
+    the document's row, 0 where the term is absent — go through ONE
+    :func:`bm25_impacts` and ONE scatter-add, tail documents numbered
+    after the base's. Every document then gets the same per-posting
+    arithmetic, added term after term, as in a whole hydration's packed
+    layout, so a derived generation ranks and scores bit for bit like
+    one (on a backend whose scatter adds in order, as the CPU's does)."""
+    tail = state.tail
+    n, cap = state.n_docs, tail.cap
+    T = term_ids.shape[0]
+    match = ((tail.terms[None, :, :] == term_ids[:, None, None])
+             & (term_ids >= 0)[:, None, None])                  # (T, cap, L)
+    tail_tf = jnp.sum(jnp.where(match, tail.tf[None].astype(jnp.int32), 0),
+                      axis=-1).astype(jnp.uint8)                # (T, cap)
+    tail_docs = jnp.broadcast_to(n + jnp.arange(cap, dtype=jnp.int32),
+                                 (T, cap))
+    docs = jnp.concatenate(
+        [jnp.where(docs >= n, n + cap, docs).reshape(T, -1), tail_docs], 1)
+    valid = jnp.concatenate(
+        [jnp.broadcast_to(valid, tf.shape).reshape(T, -1),
+         jnp.broadcast_to((term_ids >= 0)[:, None], (T, cap))], 1)
+    tf = jnp.concatenate([tf.reshape(T, -1), tail_tf], 1)
+    view = dataclasses.replace(
+        state, n_docs=n + cap,
+        doc_len=jnp.concatenate([state.doc_len[:n], tail.doc_len,
+                                 jnp.ones(1, jnp.float32)]))
+    imp = bm25_impacts(view, term_ids, qtf, docs[..., None], tf[..., None],
+                       valid[..., None])
+    return accumulate_dense(docs, imp, n + cap)
 
 
 def pruned_keep(docs: jax.Array, imp: jax.Array, ub: jax.Array,
@@ -244,10 +337,12 @@ def make_search_fn(n_docs: int, *, max_terms: int, max_blocks: int, k: int,
     """
 
     def one_query(state: SearchState, term_ids, qtf):
+        if state.tail is not None and accumulator != "dense":
+            raise ValueError("a tail is scored by the dense accumulator only")
         if accumulator == "dense":
             acc = score_dense(state, term_ids, qtf, max_blocks=max_blocks,
                               use_kernel=use_kernel)
-            kk = min(k, n_docs)          # a tiny partition may hold < k docs
+            kk = min(k, state.n_slots)   # a tiny partition may hold < k docs
             if use_topk_kernel:
                 from repro.kernels import ops as kops
                 vals, ids = kops.topk(acc, kk)
@@ -257,7 +352,7 @@ def make_search_fn(n_docs: int, *, max_terms: int, max_blocks: int, k: int,
                 vals = jnp.concatenate([vals, jnp.zeros(k - kk, vals.dtype)])
                 ids = jnp.concatenate(
                     [ids.astype(jnp.int32),
-                     jnp.full(k - kk, n_docs, jnp.int32)])
+                     jnp.full(k - kk, state.n_slots, jnp.int32)])
             return vals, ids.astype(jnp.int32)
         elif accumulator == "sorted":
             docs, tf, _, valid = gather_query_blocks(state, term_ids, max_blocks)
@@ -282,17 +377,35 @@ def make_search_fn(n_docs: int, *, max_terms: int, max_blocks: int, k: int,
     return search
 
 
+_JITTED: dict = {}
+
+
+def jitted_search_fn(n_docs: int, **kw):
+    """``jax.jit(make_search_fn(n_docs, **kw))``, one per configuration in
+    the process: every searcher of one partition size — each generation's
+    included — calls the same jitted program, so a new generation neither
+    traces nor compiles it again."""
+    key = (n_docs, tuple(sorted(kw.items())))
+    fn = _JITTED.get(key)
+    if fn is None:
+        fn = _JITTED[key] = jax.jit(make_search_fn(n_docs, **kw))
+    return fn
+
+
 # -- host-side query encoding ------------------------------------------------------
 
 
 def encode_queries(vocab: dict[str, int], queries: list[str], *,
                    max_terms: int,
-                   idf: np.ndarray | None = None) -> tuple[np.ndarray, np.ndarray]:
+                   idf: np.ndarray | None = None,
+                   n_terms: int | None = None) -> tuple[np.ndarray, np.ndarray]:
     """Tokenize + map to term ids + qtf weights, padded to (Q, T).
 
     When a query has more than ``max_terms`` distinct terms, pass ``idf`` to
     keep the highest-idf (most selective) terms — long queries then degrade
     by shedding stopword-ish terms instead of whatever dict order gives.
+    ``n_terms`` drops ids at or past it: a generation's view of a vocab
+    shared with later generations.
     """
     from collections import Counter
 
@@ -304,6 +417,8 @@ def encode_queries(vocab: dict[str, int], queries: list[str], *,
     for qi, q in enumerate(queries):
         counts = Counter(tokenize(q))
         items = [(vocab[t], c) for t, c in counts.items() if t in vocab]
+        if n_terms is not None:
+            items = [(i, c) for i, c in items if i < n_terms]
         if idf is not None and len(items) > max_terms:
             items.sort(key=lambda tc: -float(idf[tc[0]]))
         items = items[:max_terms]

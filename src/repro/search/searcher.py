@@ -18,18 +18,23 @@ import numpy as np
 
 from repro.core import trace
 from repro.core.cache import HydrationCache, pytree_nbytes
+from repro.core.directory import DirectoryError
 from repro.core.kvstore import KVStore
 from repro.core.object_store import ObjectStore
-from repro.core.refresh import GENERATION_FILE, AssetCatalog, generation_version
-from repro.index.builder import (VECTOR_META_FILE, PackedIndex,
+from repro.core.refresh import (GENERATION_FILE, AssetCatalog,
+                                generation_version, parse_generation)
+from repro.index.builder import (VECTOR_META_FILE, IndexMeta, PackedIndex,
                                  combine_segments, combine_vector_segments,
-                                 read_segment, read_vector_segment)
+                                 read_lazy_layout, read_segment,
+                                 read_vector_segment)
 from repro.index.hydration import (LazyIndex, LazyVectors, SuperIndexMissing,
                                    open_partial_segment,
                                    open_partial_vector_segment)
 from repro.index.tokenizer import tokenize
 from repro.kernels.dot_topk import dot_topk_batch, padded_rows
-from repro.search.bm25 import SearchState, encode_queries, make_search_fn
+from repro.search.bm25 import (TAIL_MIN_DOCS, TAIL_MIN_SLOTS, SearchState,
+                               TailState, encode_queries, jitted_search_fn,
+                               pow2_at_least)
 from repro.search.query import query_from_payload
 from repro.search.structured import (StructuredUnsupported,
                                      evaluate_structured, facet_counts,
@@ -91,6 +96,18 @@ class DenseTierMissing(Exception):
     """This asset version carries no dense-vector tier."""
 
 
+# batch shapes each jitted search program has served, and the (program,
+# batch, state signature) triples already run: a derived generation runs
+# the shapes its program has seen once, off the query path
+_SHAPES_SEEN: dict[int, set] = {}
+_WARMED: set = set()
+
+
+def _signature(state: SearchState) -> tuple:
+    leaves, tree = jax.tree_util.tree_flatten(state)
+    return tree, tuple((x.shape, str(x.dtype)) for x in leaves)
+
+
 class Searcher:
     """Holds the hydrated state + compiled search fn for one index version."""
 
@@ -99,13 +116,22 @@ class Searcher:
         self.packed = packed
         self.state = SearchState.from_packed(packed)
         self.vocab = packed.vocab
+        self.n_terms: int | None = None     # every id of ``vocab`` counts
+        self.host_idf = packed.idf
+        self.doc_ids = packed.meta.doc_ids
+        self.n_docs = packed.meta.n_docs
+        self._fn = self._jitted(packed.meta.n_docs)
+        # the shapes a generation derived from this one will have (set by
+        # LazySearcher): run once in every batch shape this one serves, so
+        # that a later generation finds its programs compiled at set-up
+        self.twin: SearchState | None = None
+
+    def _jitted(self, n_docs: int):
         cfg = self.config
-        self._fn = jax.jit(make_search_fn(
-            packed.meta.n_docs, max_terms=cfg.max_terms,
-            max_blocks=cfg.max_blocks, k=cfg.k,
-            accumulator=cfg.accumulator, use_kernel=cfg.use_kernel,
-            use_topk_kernel=cfg.use_topk_kernel,
-        ))
+        return jitted_search_fn(
+            n_docs, max_terms=cfg.max_terms, max_blocks=cfg.max_blocks,
+            k=cfg.k, accumulator=cfg.accumulator, use_kernel=cfg.use_kernel,
+            use_topk_kernel=cfg.use_topk_kernel)
 
     def search(self, queries: list[str]) -> tuple[np.ndarray, np.ndarray]:
         # Pad the batch to the next power of two: the jitted fn specializes
@@ -116,18 +142,36 @@ class Searcher:
         with trace.span("encode", queries=Q):
             tids, qtf = encode_queries(self.vocab, queries + [""] * (Qp - Q),
                                        max_terms=self.config.max_terms,
-                                       idf=self.packed.idf)
+                                       idf=self.host_idf,
+                                       n_terms=self.n_terms)
         with trace.span("bm25", queries=Q, padded=Qp,
                         h2d_bytes=trace.host_nbytes(tids, qtf)):
             vals, ids = self._fn(self.state, tids, qtf)
-            return np.asarray(vals)[:Q], np.asarray(ids)[:Q]
+            out = np.asarray(vals)[:Q], np.asarray(ids)[:Q]
+        _SHAPES_SEEN.setdefault(id(self._fn), set()).add(Qp)
+        _WARMED.add((id(self._fn), Qp, _signature(self.state)))
+        if self.twin is not None:
+            self._warm(self.twin, Qp)
+        return out
+
+    def _warm(self, state: SearchState, qp: int) -> bool:
+        """Run ``state`` once in batch shape ``qp`` unless that program
+        already ran; True if it ran."""
+        key = (id(self._fn), qp, _signature(state))
+        if key in _WARMED:
+            return False
+        tids = np.full((qp, self.config.max_terms), -1, np.int32)
+        qtf = np.zeros((qp, self.config.max_terms), np.float32)
+        jax.block_until_ready(self._fn(state, tids, qtf))
+        _WARMED.add(key)
+        return True
 
     def search_batch(self, queries: list[str],
                      k: int | None = None) -> list[list[tuple[int, float]]]:
         """Evaluate Q queries in ONE vmapped device call (the micro-batch
         path); returns per-query [(internal_id, score), ...] hit lists."""
         vals, ids = self.search(queries)
-        n = self.packed.meta.n_docs
+        n = self.n_docs
         out = []
         for qi in range(len(queries)):
             hits = [(int(i), float(v)) for v, i in zip(vals[qi], ids[qi])
@@ -137,6 +181,277 @@ class Searcher:
 
     def search_one(self, query: str, k: int | None = None):
         return self.search_batch([query], k)[0]
+
+    def warm_programs(self) -> int:
+        """Run this state once in every batch shape its program has served
+        and this state's shapes have not (a tail that outgrew its
+        capacity, say), off the query path. Returns the shapes run."""
+        return sum(self._warm(self.state, qp)
+                   for qp in sorted(_SHAPES_SEEN.get(id(self._fn), ())))
+
+
+# -- generations derived from an earlier one's hydrated state -------------------
+
+
+@dataclasses.dataclass
+class HostTail:
+    """A derived generation's tail on the host, the twin of
+    :class:`~repro.search.bm25.TailState`: the ``n_docs`` documents its
+    commits added since the fixed layout, one row each. Immutable once
+    built: :meth:`extend` and :meth:`kill` return a new tail, so an older
+    generation's tail never changes under it."""
+
+    terms: np.ndarray          # (cap, L) int32, -1 = empty slot
+    tf: np.ndarray             # (cap, L) uint8
+    doc_len: np.ndarray        # (cap,) float32, inf = deleted or empty
+    n_docs: int
+    doc_ids: list
+
+    @property
+    def nbytes(self) -> int:
+        return self.terms.nbytes + self.tf.nbytes + self.doc_len.nbytes
+
+    @classmethod
+    def empty(cls) -> "HostTail":
+        return cls._alloc(TAIL_MIN_DOCS, TAIL_MIN_SLOTS, None)
+
+    @classmethod
+    def _alloc(cls, cap: int, slots: int, old: "HostTail | None"
+               ) -> "HostTail":
+        out = cls(terms=np.full((cap, slots), -1, np.int32),
+                  tf=np.zeros((cap, slots), np.uint8),
+                  doc_len=np.full(cap, np.inf, np.float32),
+                  n_docs=0, doc_ids=[])
+        if old is not None:
+            n, w = old.n_docs, old.terms.shape[1]
+            out.terms[:n, :w], out.tf[:n, :w] = old.terms[:n], old.tf[:n]
+            out.doc_len[:n] = old.doc_len[:n]
+            out.n_docs, out.doc_ids = n, list(old.doc_ids)
+        return out
+
+    def extend(self, docs: np.ndarray, terms: np.ndarray, tfs: np.ndarray,
+               doc_len: np.ndarray, doc_ids: list) -> "HostTail":
+        """Append documents: postings (``docs[i]``, 0-based among the new
+        documents; ``terms[i]``; ``tfs[i]``), their lengths and ids."""
+        m = len(doc_ids)
+        order = np.argsort(docs, kind="stable")
+        docs, terms, tfs = docs[order], terms[order], tfs[order]
+        counts = np.bincount(docs, minlength=m)
+        first = np.concatenate([[0], np.cumsum(counts)[:-1]])
+        slot = np.arange(len(docs)) - first[docs]
+        n = self.n_docs + m
+        out = self._alloc(
+            pow2_at_least(n, self.terms.shape[0]),
+            pow2_at_least(int(counts.max(initial=0)), self.terms.shape[1]),
+            self)
+        out.terms[self.n_docs + docs, slot] = terms
+        out.tf[self.n_docs + docs, slot] = np.minimum(tfs, 255)
+        out.doc_len[self.n_docs:n] = doc_len
+        out.n_docs = n
+        out.doc_ids.extend(doc_ids)
+        return out
+
+    def kill(self, local: np.ndarray) -> "HostTail":
+        """The tail with documents ``local`` deleted (infinite length)."""
+        doc_len = self.doc_len.copy()
+        doc_len[local] = np.inf
+        return dataclasses.replace(self, doc_len=doc_len)
+
+    def to_device(self) -> TailState:
+        return TailState(terms=jax.device_put(self.terms),
+                         tf=jax.device_put(self.tf),
+                         doc_len=jax.device_put(self.doc_len))
+
+
+def segment_postings(pack: PackedIndex, start: int):
+    """The postings of ``pack``'s documents ``start..``: (document, 0-based
+    from ``start``; term id; tf), and those documents' lengths and ids."""
+    to = pack.term_offsets.astype(np.int64)
+    term = np.repeat(np.arange(len(to) - 1), to[1:] - to[:-1])
+    B, n = pack.meta.block, pack.meta.n_docs
+    docs = pack.block_docs.astype(np.int64).reshape(-1)
+    tf = pack.block_tf.reshape(-1)
+    keep = (docs >= start) & (docs < n) & (tf > 0)
+    return (docs[keep] - start, np.repeat(term, B)[keep],
+            tf[keep].astype(np.int64), pack.doc_len[start:n],
+            pack.meta.doc_ids[start:])
+
+
+@dataclasses.dataclass
+class Lineage:
+    """What a generation's hydrated state hands the next one: the FIXED
+    layout (one whole-segment-set hydration: its device arrays, host doc
+    lengths and ids, its ``n_fixed`` documents), the tail grown since, the
+    segments covered (id -> documents, base first) and the tombstones
+    applied."""
+
+    base_seg: str
+    seg_docs: dict
+    n_fixed: int
+    fixed: SearchState
+    fixed_doc_len: np.ndarray
+    fixed_ids: list
+    tail: HostTail
+    tail_dev: "TailState | None"
+    dead: np.ndarray
+
+    def ids(self, lo: int, hi: int) -> list:
+        """External ids of internal positions [lo, hi)."""
+        n = self.n_fixed
+        out = list(self.fixed_ids[lo:min(hi, n)])
+        if hi > n:
+            out += self.tail.doc_ids[max(lo, n) - n:hi - n]
+        return out
+
+
+class TailSearcher(Searcher):
+    """A generation derived from an earlier one (:func:`derive_searcher`):
+    the earlier generation's fixed layout stays on the device as it is,
+    and what this generation changed travels as small arrays — its idf
+    (shared by every partition, ``GenState.device_idf``), avgdl, the fixed
+    layout's doc lengths where it deleted documents, and the tail. Full
+    from the first query: nothing backfills."""
+
+    def __init__(self, lineage: Lineage, state: SearchState, gstate,
+                 config: SearchConfig, *, gen: int, parent_gen: int,
+                 h2d_bytes: int) -> None:
+        self.config = config
+        self.lineage = lineage
+        self.state = state
+        self.vocab = gstate.vocab
+        self.n_terms = gstate.n_terms
+        self.host_idf = gstate.idf()
+        self.doc_ids = lineage.fixed_ids + lineage.tail.doc_ids
+        self.n_docs = lineage.n_fixed + lineage.tail.n_docs
+        self.gen, self.parent_gen = gen, parent_gen
+        self.h2d_bytes = h2d_bytes
+        self._fn = self._jitted(lineage.n_fixed)
+        self.twin = None
+
+    @property
+    def nbytes(self) -> int:
+        # what this generation added: the rest is its parent's
+        return self.h2d_bytes
+
+
+def _lazy_lineage(parent: "LazySearcher") -> "Lineage | None":
+    idx = parent.index
+    if idx.segment_ids is None or any(
+            s.fields_header is not None for s in idx.segments):
+        return None
+    if not parent.full:
+        parent.backfill()
+    searcher = parent.searcher
+    packed = searcher.packed
+    return Lineage(
+        base_seg=idx.segment_ids[0],
+        seg_docs={sid: seg.meta.n_docs
+                  for sid, seg in zip(idx.segment_ids, idx.segments)},
+        n_fixed=packed.meta.n_docs, fixed=searcher.state,
+        fixed_doc_len=packed.doc_len, fixed_ids=packed.meta.doc_ids,
+        tail=HostTail.empty(), tail_dev=None,
+        dead=np.asarray(sorted(idx.tombstones), np.int64))
+
+
+def derive_searcher(parent, parent_gen: int, catalog: AssetCatalog,
+                    asset: str, version: str, config: SearchConfig
+                    ) -> "tuple[TailSearcher, float] | None":
+    """Generation ``version`` of ``asset``, derived from ``parent`` — the
+    hydrated entry (a :class:`LazySearcher` or a :class:`TailSearcher`) of
+    an earlier generation over the same base segment — the way Lucene's
+    near-real-time reader reuses every segment it shares with the last:
+    reads only the segments ``parent`` has not covered (their documents
+    join the tail), applies the new tombstones and takes the generation's
+    shared state, which the catalog resolves from its delta. Returns
+    (searcher, simulated seconds), or None where this generation cannot be
+    derived from ``parent`` (another base, a structured index, another
+    accumulator than the dense one, the Pallas impact kernel): the caller
+    hydrates it whole.
+
+    Answers equal a whole hydration of the same generation (see
+    ``bm25._score_with_tail``) while every term fits ``max_blocks``
+    blocks; a longer term keeps the fixed layout's block order, not one
+    re-sorted under the new stats, so its truncation may differ."""
+    if config.accumulator != "dense" or config.use_kernel:
+        return None
+    store = catalog.store
+    before = store.stats.sim_seconds
+    manifest = catalog.read_generation(asset, version)
+    if manifest.stats_ref is None:
+        return None
+    if isinstance(parent, TailSearcher):
+        lin = parent.lineage
+    else:
+        lin = _lazy_lineage(parent)
+    if lin is None or manifest.base != lin.base_seg:
+        return None
+    gstate = catalog.generation_state(manifest.stats_ref)
+
+    covered = lin.n_fixed + lin.tail.n_docs
+    tail, seg_docs, off = lin.tail, {}, 0
+    for seg in manifest.segments:
+        n = lin.seg_docs.get(seg)
+        if n is None:
+            d = catalog.open_segment(asset, seg)
+            pack = None
+            try:
+                if off < covered:      # a merge of segments parent holds
+                    meta = IndexMeta.from_json(
+                        d.open_input("meta.json").read_all())
+                if off >= covered or off + meta.n_docs > covered:
+                    # new documents: the header and payload, nothing else
+                    pack = read_lazy_layout(d)
+                    meta = pack.meta
+            except (ValueError, DirectoryError):
+                return None            # format v2, or written before
+            n = meta.n_docs
+            old = max(0, min(n, covered - off))
+            if meta.doc_ids[:old] != lin.ids(off, off + old):
+                return None            # not the documents parent holds
+            if pack is not None:
+                tail = tail.extend(*segment_postings(pack, covered - off))
+                covered = off + n
+        seg_docs[seg] = n
+        off += n
+    if off < covered:
+        return None                    # fewer documents than parent holds
+
+    dead = np.asarray(manifest.tombstones, np.int64)
+    new_dead = np.setdiff1d(dead, lin.dead)
+    h2d = 0
+    fixed_doc_len = lin.fixed_doc_len
+    doc_len_dev = (parent.state.doc_len if isinstance(parent, TailSearcher)
+                   else lin.fixed.doc_len)
+    gone = new_dead[new_dead < lin.n_fixed]
+    if len(gone):
+        fixed_doc_len = fixed_doc_len.copy()
+        fixed_doc_len[gone] = np.inf
+        doc_len_dev = jax.device_put(fixed_doc_len)
+        h2d += fixed_doc_len.nbytes
+    gone = new_dead[new_dead >= lin.n_fixed] - lin.n_fixed
+    if len(gone):
+        tail = tail.kill(gone)
+    tail_dev = lin.tail_dev
+    if tail is not lin.tail or tail_dev is None:
+        tail_dev = tail.to_device()
+        h2d += tail.nbytes
+    idf_dev, placed = gstate.device_idf()
+    h2d += placed
+    fixed = lin.fixed
+    state = SearchState(
+        term_offsets=fixed.term_offsets, block_docs=fixed.block_docs,
+        block_tf=fixed.block_tf, block_max=fixed.block_max,
+        doc_len=doc_len_dev, idf=idf_dev,
+        avgdl=jax.device_put(np.float32(gstate.avgdl or 1.0)),
+        k1=fixed.k1, b=fixed.b, n_docs=lin.n_fixed, tail=tail_dev)
+    lineage = dataclasses.replace(
+        lin, seg_docs=seg_docs, fixed_doc_len=fixed_doc_len, tail=tail,
+        tail_dev=tail_dev, dead=dead)
+    searcher = TailSearcher(
+        lineage, state, gstate, config, gen=parse_generation(version),
+        parent_gen=parent_gen, h2d_bytes=h2d)
+    sim_s = store.stats.sim_seconds - before + h2d / config.hydrate_Bps
+    return searcher, sim_s
 
 
 def hydrate_searcher(catalog: AssetCatalog, asset: str,
@@ -343,11 +658,14 @@ class LazySearcher:
     """
 
     def __init__(self, index: LazyIndex, config: SearchConfig,
-                 store: ObjectStore) -> None:
+                 store: ObjectStore, twin_idf_cap: int | None = None) -> None:
         self.index = index
         self.config = config
         self._store = store           # billing seam: range-read sim seconds
         self._searcher: Searcher | None = None
+        # a later generation can be derived from this one: the padded idf
+        # length its states will have (None: it cannot)
+        self.twin_idf_cap = twin_idf_cap
 
     @property
     def full(self) -> bool:
@@ -403,9 +721,18 @@ class LazySearcher:
     def searcher(self) -> Searcher:
         if self._searcher is None:
             with trace.span("hydrate") as sp:
-                self._searcher = Searcher(self.index.packed(), self.config)
-                sp.set_metadata(
-                    h2d_bytes=pytree_nbytes(self._searcher.state))
+                searcher = Searcher(self.index.packed(), self.config)
+                nbytes = pytree_nbytes(searcher.state)
+                if self.twin_idf_cap is not None:
+                    searcher.twin = dataclasses.replace(
+                        searcher.state,
+                        idf=jax.device_put(
+                            np.zeros(self.twin_idf_cap, np.float32)),
+                        tail=HostTail.empty().to_device())
+                    nbytes += pytree_nbytes(searcher.twin.tail)
+                    nbytes += searcher.twin.idf.nbytes
+                self._searcher = searcher
+                sp.set_metadata(h2d_bytes=nbytes)
         return self._searcher
 
 
@@ -430,12 +757,21 @@ def lazy_hydrate_searcher(catalog: AssetCatalog, asset: str,
         segments = [open_partial_segment(catalog.open_segment(asset, seg))
                     for seg in manifest.segments]
         index = LazyIndex(segments, vocab=vocab, stats=stats,
-                          tombstones=manifest.tombstones)
+                          tombstones=manifest.tombstones,
+                          segment_ids=manifest.segments)
+        derivable = (manifest.stats_ref is not None
+                     and config.accumulator == "dense"
+                     and not config.use_kernel
+                     and all(s.fields_header is None for s in segments))
+        twin = (catalog.generation_state(manifest.stats_ref).idf_cap
+                if derivable else None)
     else:
         index = LazyIndex([open_partial_segment(directory)])
+        twin = None
     network_s = store.stats.sim_seconds - before
     deserialize_s = index.bytes_read / config.hydrate_Bps
-    return LazySearcher(index, config, store), network_s + deserialize_s
+    return (LazySearcher(index, config, store, twin_idf_cap=twin),
+            network_s + deserialize_s)
 
 
 def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
@@ -476,9 +812,10 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
     generation check can refuse cross-tier generation skew.
 
     ``payload["prewarm_terms"]`` (with optional ``prewarm_dense``) marks a
-    rollover-prewarm ping: hydrate the n highest-df terms' blocks (and the
-    dense tier's live rows) on a lazy instance WITHOUT evaluating a query
-    and WITHOUT triggering backfill.
+    rollover-prewarm ping: derive the generation from an earlier one the
+    instance holds (:func:`derive_searcher`), or else hydrate the n
+    highest-df terms' blocks (and the dense tier's live rows) on a lazy
+    instance WITHOUT evaluating a query and WITHOUT triggering backfill.
 
     ``payload["gen"]`` (an int) PINS the index generation: the handler
     serves exactly that generation, hydrating it if this instance hasn't
@@ -492,6 +829,21 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
     cfg = config or SearchConfig()
     lazy = bool(cfg.lazy_hydration)   # None (resolver's choice) → eager
 
+    def _derive(cache: HydrationCache, version: str):
+        """This generation derived from the newest earlier one this
+        instance holds, if it can be (:func:`derive_searcher`)."""
+        gen = parse_generation(version)
+        if gen is None:
+            return None
+        held = [(g, e) for v, e in cache.entries(asset)
+                if (g := parse_generation(v)) is not None and g < gen
+                and isinstance(e, (LazySearcher, TailSearcher))]
+        if not held:
+            return None
+        parent_gen, parent = max(held, key=lambda ge: ge[0])
+        return derive_searcher(parent, parent_gen, catalog, asset, version,
+                               cfg)
+
     def handler(cache: HydrationCache, payload: dict) -> tuple[dict, float]:
         gen = payload.get("gen")
         version = (generation_version(gen) if gen is not None
@@ -502,6 +854,10 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
 
         def _hydrate():
             with trace.span("hydrate") as sp:
+                derived = _derive(cache, version) if lazy else None
+                if derived is not None:
+                    sp.set_metadata(h2d_bytes=derived[0].h2d_bytes)
+                    return derived
                 if lazy:
                     try:
                         hydrated = lazy_hydrate_searcher(catalog, asset, cfg,
@@ -542,13 +898,23 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
                 sp.set_metadata(h2d_bytes=hydrated[0].rows.nbytes)
                 return hydrated
 
-        # Rollover prewarm ping: warm the head-term working set (and the
-        # dense tier when asked) without evaluating a query and without
-        # backfilling — hot terms serve warm post-rollover while the cold
-        # tail still lazy-loads on demand.
+        # Rollover prewarm ping: derive the new generation from the one
+        # this instance holds where it can (whole, nothing left to load);
+        # else warm the head-term working set (and the dense tier when
+        # asked) without evaluating a query and without backfilling — hot
+        # terms serve warm post-rollover while the cold tail still
+        # lazy-loads on demand.
         if "prewarm_terms" in payload:
             entry = cache.get_or_hydrate(asset, version, _hydrate)
-            if isinstance(entry, LazySearcher) and not entry.full:
+            if isinstance(entry, TailSearcher):
+                # its programs run once here, and the generations before
+                # its parent (gone from the catalog) leave the cache
+                entry.warm_programs()
+                for v, _ in cache.entries(asset):
+                    g = parse_generation(v.split("+", 1)[0])
+                    if g is not None and g < entry.parent_gen:
+                        cache.invalidate(asset, v)
+            elif isinstance(entry, LazySearcher) and not entry.full:
                 changed, sim_s = entry.ensure_top_terms(
                     int(payload["prewarm_terms"]))
                 if changed:
@@ -600,8 +966,8 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
                     searcher = entry.searcher
                 else:
                     searcher = entry
-                packed = searcher.packed
-                if packed.fields is None:
+                packed = getattr(searcher, "packed", None)
+                if packed is None or packed.fields is None:
                     raise StructuredUnsupported(
                         "structured query against a v1 segment (publish "
                         "with IndexSpec(structured=True, ...))")
@@ -641,7 +1007,7 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
                 exec_s += (cfg.sim_exec_s
                            + cfg.sim_exec_per_query_s * (n_q - 1)
                            + cfg.sim_exec_per_kdoc_s
-                           * searcher.packed.meta.n_docs / 1000.0)
+                           * searcher.n_docs / 1000.0)
         if need_dense:
             dentry = cache.get_or_hydrate(asset, version + "+vec",
                                           _hydrate_dense)
@@ -659,7 +1025,7 @@ def make_search_handler(catalog: AssetCatalog, doc_store: KVStore,
             exec_s = time.perf_counter() - t0
 
         primary = sparse_hits if need_sparse else dense_hits
-        ext_sparse = searcher.packed.meta.doc_ids if searcher else None
+        ext_sparse = searcher.doc_ids if searcher else None
         ext_dense = dsearcher.doc_ids if dsearcher is not None else None
         primary_ext = ext_sparse if need_sparse else ext_dense
         fetch = payload.get("fetch_docs", True)

@@ -38,10 +38,10 @@ from repro.core.refresh import (AssetCatalog, GenerationManifest,
                                 rollover_fleet)
 from repro.core.runtime import FaaSRuntime, InvocationRecord, RuntimeConfig
 from repro.data.corpus import hash_embedder
-from repro.index.builder import (IndexWriter, MergePolicy,
-                                 compute_global_stats, extend_vocab,
+from repro.index.builder import (IndexWriter, LiveStats, MergePolicy,
+                                 compute_global_stats, concat_segments,
                                  field_avgdl, global_vocab, pack_vectors,
-                                 read_segment, update_stats, write_segment,
+                                 read_segment, write_segment,
                                  write_vector_segment)
 from repro.index.tokenizer import flatten_text, token_counts
 from repro.search.distributed import partition_corpus
@@ -153,18 +153,6 @@ def build_search_app(
 ENQUEUE_COST_S = 0.0005    # staging one add/delete batch at the coordinator
 
 
-def _copy_stats(stats: dict) -> dict:
-    """Deep-enough copy of compute_global_stats-shaped stats: ``df`` and
-    (on structured fleets) every ``fields`` entry are fresh containers.
-    ``update_stats`` mutates the per-field dicts IN PLACE, so a shallow
-    ``dict(stats, df=...)`` checkpoint would let a failed commit's
-    mutations leak into what gets restored."""
-    out = dict(stats, df=dict(stats["df"]))
-    if "fields" in stats:
-        out["fields"] = {f: dict(e) for f, e in stats["fields"].items()}
-    return out
-
-
 @dataclasses.dataclass
 class _PartitionState:
     """One partition's segment tier, as the writer tracks it."""
@@ -203,14 +191,22 @@ class FleetIndexer:
 
     Invariants the tests pin:
 
-    * global stats/vocab are maintained INCREMENTALLY (``update_stats`` /
-      ``extend_vocab``) and stay exactly equal to ``compute_global_stats``
-      over the live corpus — so a delta-served index ranks identically to
-      a from-scratch rebuild, always;
+    * global stats/vocab are maintained INCREMENTALLY, by term id
+      (:class:`~repro.index.builder.LiveStats`), and stay exactly equal to
+      ``compute_global_stats`` over the live corpus — so a delta-served
+      index ranks identically to a from-scratch rebuild, always;
     * every partition gets a manifest at every generation (a delete in
       partition 0 moves idf for ALL partitions — stats refresh is global);
-    * deletes are tombstones until the :class:`MergePolicy` folds the
-      delta tier back into the base (one full re-pack, purging them).
+      the shared state each generation publishes is what its commit
+      changed (a delta of the previous state), not the vocabulary;
+    * deletes are tombstones until the :class:`MergePolicy` re-packs the
+      base (purging them); a long delta tier merges into one delta;
+    * a deleted passage stays in the KV store while a generation gc keeps
+      can still rank it (a query pinned to the previous generation fetches
+      what that generation ranked), and goes at the commit that retires
+      the last such generation;
+    * a commit costs what it changed: journals of what it overwrote (not
+      copies of the corpus) let a failed one roll back.
     """
 
     def __init__(self, catalog: AssetCatalog, doc_store: KVStore,
@@ -226,8 +222,8 @@ class FleetIndexer:
         self.catalog = catalog
         self.doc_store = doc_store
         self.runtime = runtime
-        self.stats = stats
-        self.vocab = vocab
+        # the live stats, by term id; ``self.vocab`` is its term -> id map
+        self.stats = LiveStats.from_stats(stats, vocab)
         self.merge_policy = merge_policy or MergePolicy()
         self.sim_write_s = sim_write_s
         self.sim_write_per_doc_s = sim_write_per_doc_s
@@ -251,7 +247,11 @@ class FleetIndexer:
         self._pending_ids: set[str] = set()   # O(1) dedup over pending_adds
         # ext id -> (partition, internal position, text) for LIVE docs
         self._ext_index: dict[str, tuple[int, int, str]] = {}
+        self._ext_journal: dict = {}     # a commit's overwritten entries
         self._rr = 0                      # round-robin add assignment
+        # (generation, ext ids) deleted at that generation whose passages
+        # stay fetchable until gc retires the generation before it
+        self._kv_doomed: list[tuple[int, list[str]]] = []
         # segment-id sequence: every writer execution publishes under a
         # FRESH id, so a hedged re-execution (FaaSRuntime.hedge_after_s
         # runs handlers twice) or a post-failure retry can never collide
@@ -260,12 +260,20 @@ class FleetIndexer:
         # the reference-based gc. NEVER rolled back by _restore: a retry
         # must keep advancing past the failed attempt's ids.
         self._seg_seq = 0
+        # the delta segments this writer packed, by id (immutable): a tier
+        # merge concatenates them without reading them back
+        self._packs: dict = {}
         self.commits: list[dict] = []     # commit log (gen, merged, counts)
         # multi-writer identity: 0 is the primary; ``fork`` mints clones
         # with nonzero ids (distinct handler names + segment-id tags so two
         # writers racing one generation never collide before the CAS).
         self.writer_id = 0
         self._forked = False    # once True, commits publish writer.json
+
+    @property
+    def vocab(self) -> dict:
+        """The live term -> id map (append-only; ids never move)."""
+        return self.stats.vocab
 
     # -- bootstrap (the offline batch build, now generation-shaped) ------------
 
@@ -277,6 +285,7 @@ class FleetIndexer:
         if self._stats_ref is None:       # once per generation, not per part
             self._stats_ref = self.catalog.publish_generation_state(
                 self.stats_asset, self.gen, self.stats, self.vocab)
+            self.stats.ref = self._stats_ref
         i = len(self.parts)
         writer = IndexWriter(global_stats=self.stats, vocab=self.vocab,
                              structured=self.structured,
@@ -284,7 +293,7 @@ class FleetIndexer:
         writer.add_many(docs)
         base_seg = f"g{self.gen:06d}-base"
         self.catalog.publish_segment(asset, base_seg,
-                                     write_segment(writer.pack()))
+                                     write_segment(writer.pack(), lean=True))
         st = _PartitionState(asset=asset, seg_docs=list(docs),
                              tombstones=set(), base_seg=base_seg,
                              deltas=[], base_docs=len(docs), delta_docs=0)
@@ -369,98 +378,156 @@ class FleetIndexer:
 
     def _make_indexer_handler(self, i: int):
         """Handler for ``indexer-p{i}``: pack this partition's staged docs
-        as a delta (or re-pack its live docs as a fresh base, for a merge)
-        and publish the segment. Stateless w.r.t. the instance cache; the
-        staged inputs live at the coordinator, exactly like the query
-        coordinator owns the scatter."""
+        as a delta, merge its delta tier (and the staged docs) into one
+        delta, or re-pack its live docs as a fresh base — the ops
+        :meth:`MergePolicy.plan` names — and publish the segment.
+        Stateless w.r.t. the instance cache; the staged inputs live at the
+        coordinator, exactly like the query coordinator owns the scatter."""
         st_ref = self.parts
 
         def handler(cache, payload: dict) -> tuple[dict, float]:
             st = st_ref[i]
             op, gen = payload["op"], payload["gen"]
             t0 = time.perf_counter()
-            self._seg_seq += 1
-            tag = self._seg_tag()
+            staged = list(st.staged_docs)
+            # (kind, docs, packed) of each segment this op publishes
             if op == "delta":
-                docs = list(st.staged_docs)
-                packed = IndexWriter.delta(docs, self.stats, vocab=self.vocab,
-                                           structured=self.structured,
-                                           facet_fields=self.facet_fields)
-                seg = f"g{gen:06d}-delta-{tag}{self._seg_seq:04d}"
+                out = [("delta", staged, self._pack_delta(staged))]
+            elif op == "merge_deltas":
+                # the tier merges with itself, positions kept; the staged
+                # docs follow as a delta of their own, so the merged
+                # segment holds only what every pool already serves
+                tier = st.seg_docs[st.base_docs:]
+                if self.structured:
+                    merged = self._pack_delta(tier)
+                else:
+                    merged = concat_segments([
+                        self._packs.get(d) or read_segment(
+                            self.catalog.open_segment(st.asset, d))
+                        for d in st.deltas])
+                out = [("delta", tier, merged)]
+                if staged:
+                    out.append(("delta", staged, self._pack_delta(staged)))
             elif op == "merge":
-                docs = st.live_docs() + list(st.staged_docs)
+                docs = st.live_docs() + staged
                 writer = IndexWriter(global_stats=self.stats,
                                      vocab=self.vocab,
                                      structured=self.structured,
                                      facet_fields=self.facet_fields)
                 writer.add_many(docs)
-                packed = writer.pack()
-                seg = f"g{gen:06d}-base-{tag}{self._seg_seq:04d}"
+                out = [("base", docs, writer.pack())]
             else:
                 raise ValueError(f"unknown indexer op {op!r}")
-            self.catalog.publish_segment(st.asset, seg, write_segment(packed))
-            vec_seg = None
-            if self.embedder is not None:
-                # the dense twin packs in the SAME invocation over the SAME
-                # doc list: rows stay doc-for-doc aligned with the sparse
-                # segment, and both tiers flip together at publish
-                kind = "vecbase" if op == "merge" else "vecdelta"
-                vec_seg = f"g{gen:06d}-{kind}-{tag}{self._seg_seq:04d}"
+            tag = self._seg_tag()
+            segs, vec_segs = [], []
+            for kind, docs, packed in out:
+                self._seg_seq += 1
+                seg = f"g{gen:06d}-{kind}-{tag}{self._seg_seq:04d}"
                 self.catalog.publish_segment(
-                    st.asset, vec_seg,
-                    write_vector_segment(self._pack_vecs(docs)))
+                    st.asset, seg, write_segment(packed, lean=True))
+                segs.append(seg)
+                if kind == "delta" and not self.structured:
+                    self._packs[seg] = packed
+                if self.embedder is not None:
+                    # the dense twin packs in the SAME invocation over the
+                    # SAME doc list: rows stay doc-for-doc aligned with the
+                    # sparse segment, and both tiers flip together
+                    vec_seg = f"g{gen:06d}-vec{kind}-{tag}{self._seg_seq:04d}"
+                    self.catalog.publish_segment(
+                        st.asset, vec_seg,
+                        write_vector_segment(self._pack_vecs(docs)))
+                    vec_segs.append(vec_seg)
             if self.sim_write_s is not None:
-                exec_s = self.sim_write_s + self.sim_write_per_doc_s * len(docs)
+                exec_s = self.sim_write_s + self.sim_write_per_doc_s * sum(
+                    len(docs) for _, docs, _ in out)
             else:
                 exec_s = time.perf_counter() - t0
-            return {"op": op, "seg": seg, "gen": gen, "vec_seg": vec_seg,
-                    "n_docs": packed.meta.n_docs}, exec_s
+            return {"op": op, "segs": segs, "vec_segs": vec_segs,
+                    "gen": gen}, exec_s
 
         return handler
+
+    def _pack_delta(self, docs: list):
+        return IndexWriter.delta(docs, self.stats, vocab=self.vocab,
+                                 structured=self.structured,
+                                 facet_fields=self.facet_fields)
 
     # -- commit: delta pack → CAS publish → prewarmed rollover -------------------
 
     def _checkpoint(self) -> dict:
-        """Everything ``commit`` mutates, cheap-copied. A failed commit
-        (handler error, PublishConflict from a racing writer) restores this
-        so the staged work is NOT lost and the writer can rebase + retry —
-        without it, a partial multi-partition publish would wedge every
-        future commit and silently drop the pending batch."""
+        """What a failed commit (handler error, PublishConflict from a
+        racing writer) returns to, so the staged work is NOT lost and the
+        writer can rebase + retry — without it, a partial multi-partition
+        publish would wedge every future commit and silently drop the
+        pending batch. It costs the commit, not the corpus: references to
+        the containers a commit or rebase REPLACES (pending sets, tier
+        lists, tombstone sets, a rebased index), the lengths of those it
+        GROWS in place (each partition's doc list), and journals of the
+        entries it overwrites (the live stats from ``mark``, the ext-id
+        index through ``_ext_put``)."""
+        self._ext_journal = {}
         return {
-            "stats": _copy_stats(self.stats),
-            "vocab": self.vocab,        # rebound by extend_vocab, never mutated
-            "ext_index": dict(self._ext_index),
-            "pending_adds": list(self.pending_adds),
-            "pending_ids": set(self._pending_ids),
-            "pending_deletes": set(self.pending_deletes),
+            "stats": self.stats,
+            "stats_mark": self.stats.mark(),
+            "ext_index": self._ext_index,
+            "pending_adds": self.pending_adds,
+            "pending_ids": self._pending_ids,
+            "pending_deletes": self.pending_deletes,
             "rr": self._rr,
             "gen": self.gen,
             "stats_ref": self._stats_ref,
-            "parts": [(list(st.seg_docs), set(st.tombstones), st.base_seg,
-                       list(st.deltas), st.base_docs, st.delta_docs,
-                       st.vec_base, list(st.vec_deltas))
+            "parts": [(st.seg_docs, len(st.seg_docs), st.tombstones,
+                       st.base_seg, st.deltas, st.base_docs, st.delta_docs,
+                       st.vec_base, st.vec_deltas)
                       for st in self.parts],
         }
 
     def _restore(self, cp: dict) -> None:
-        # every restored container is a COPY: ``commit``'s conflict-retry
-        # loop restores the same checkpoint repeatedly, and handing out
-        # the checkpoint's own objects would let attempt N's mutations
-        # corrupt what attempt N+1 restores
-        self.stats = _copy_stats(cp["stats"])
-        self.vocab = cp["vocab"]        # rebound by extend_vocab, never mutated
-        self._ext_index = dict(cp["ext_index"])
-        self.pending_adds = list(cp["pending_adds"])
-        self._pending_ids = set(cp["pending_ids"])
-        self.pending_deletes = set(cp["pending_deletes"])
+        """Undo everything since ``cp`` was taken, and leave ``cp`` ready
+        to be restored again (``commit``'s conflict-retry loop restores
+        the same checkpoint after every failed attempt)."""
+        stats = cp["stats"]
+        stats.rollback(cp["stats_mark"])
+        cp["stats_mark"] = stats.mark()
+        if self.stats is not stats:        # a rebase replaced it: drop that
+            self.stats.release()
+            self.stats = stats
+        if self._ext_index is cp["ext_index"]:
+            for ext, old in self._ext_journal.items():
+                if old is None:
+                    self._ext_index.pop(ext, None)
+                else:
+                    self._ext_index[ext] = old
+        self._ext_index = cp["ext_index"]
+        self._ext_journal = {}
+        self.pending_adds = cp["pending_adds"]
+        self._pending_ids = cp["pending_ids"]
+        self.pending_deletes = cp["pending_deletes"]
         self._rr, self.gen = cp["rr"], cp["gen"]
         self._stats_ref = cp["stats_ref"]
-        for st, (sd, tb, bs, dl, bd, dd, vb, vd) in zip(self.parts,
-                                                        cp["parts"]):
-            st.seg_docs, st.tombstones, st.base_seg = list(sd), set(tb), bs
-            st.deltas, st.base_docs, st.delta_docs = list(dl), bd, dd
-            st.vec_base, st.vec_deltas = vb, list(vd)
+        for st, (sd, n, tb, bs, dl, bd, dd, vb, vd) in zip(self.parts,
+                                                           cp["parts"]):
+            del sd[n:]
+            st.seg_docs, st.tombstones, st.base_seg = sd, tb, bs
+            st.deltas, st.base_docs, st.delta_docs = dl, bd, dd
+            st.vec_base, st.vec_deltas = vb, vd
             st.staged_docs = []
+
+    def _release(self, cp: dict) -> None:
+        """The commit stood: stop journaling."""
+        self.stats.release()
+        cp["stats"].release()
+        self._ext_journal = {}
+
+    def _ext_put(self, ext: str, value) -> None:
+        """Set (or, with None, drop) an ext-id index entry, journaling the
+        entry it overwrites for ``_restore``."""
+        if ext not in self._ext_journal:
+            self._ext_journal[ext] = self._ext_index.get(ext)
+        if value is None:
+            self._ext_index.pop(ext, None)
+        else:
+            self._ext_index[ext] = value
 
     def _published_gen(self) -> int:
         """Highest generation any partition's manifest currently serves.
@@ -511,9 +578,10 @@ class FleetIndexer:
         manifests = [self.catalog.read_generation(st.asset)
                      for st in self.parts]
         stats, vocab = self.catalog.resolve_generation_state(manifests[0])
-        self.stats = _copy_stats(stats)
-        self.vocab = dict(vocab)
+        self.stats = LiveStats.from_stats(stats, vocab,
+                                          ref=manifests[0].stats_ref)
         self._ext_index = {}
+        self._ext_journal = {}
         for i, (st, m) in enumerate(zip(self.parts, manifests)):
             tombs = set(m.tombstones)
             seg_docs: list[tuple[str, str]] = []
@@ -546,8 +614,9 @@ class FleetIndexer:
         ref = manifests[0].stats_ref
         self._stats_ref = list(ref) if ref is not None else None
         self.gen = gen
-        # revalidate the still-pending batch against the adopted view
-        self.pending_deletes &= set(self._ext_index)
+        # revalidate the still-pending batch against the adopted view (a
+        # new set: the checkpoint holds the old one)
+        self.pending_deletes = self.pending_deletes & set(self._ext_index)
         for ext, _ in self.pending_adds:
             if ext in self._ext_index and ext not in self.pending_deletes:
                 raise ValueError(
@@ -566,7 +635,9 @@ class FleetIndexer:
             self._rebase()
         except Exception:
             self._restore(cp)
+            self._release(cp)
             raise
+        self._release(cp)
         return True
 
     def fork(self, writer_id: int) -> "FleetIndexer":
@@ -585,8 +656,8 @@ class FleetIndexer:
             raise ValueError("forked writer needs a distinct writer_id")
         w = FleetIndexer(
             self.catalog, self.doc_store, self.runtime,
-            stats=_copy_stats(self.stats),
-            vocab=self.vocab, merge_policy=self.merge_policy,
+            stats=self.stats, vocab=self.vocab,
+            merge_policy=self.merge_policy,
             sim_write_s=self.sim_write_s,
             sim_write_per_doc_s=self.sim_write_per_doc_s,
             stats_asset=self.stats_asset, embedder=self.embedder,
@@ -622,8 +693,10 @@ class FleetIndexer:
         instant, like a scatter) plus the rollover prewarm pings. The
         serving pointer (``self.gen``) flips together with the manifests;
         the prewarm pings then hydrate every pool on the new generation
-        off the query path, and any query already dispatched keeps its own
-        pinned generation (still readable), so nothing is dropped or torn.
+        off the query path — deriving it from the generation each pool
+        holds, so that no query finds a partition cold — and any query
+        already dispatched keeps its own pinned generation (still
+        readable), so nothing is dropped or torn.
         On ANY failure the writer state rolls back to the pre-commit
         checkpoint (already-uploaded segments remain as unreferenced
         orphans for gc) and the staged batch stays pending; queries keep
@@ -636,10 +709,30 @@ class FleetIndexer:
         create-once segment upload), it rolls back, rebases on the new
         winner, and retries, up to ``max_publish_retries`` extra attempts.
         Exhaustion re-raises the conflict with the checkpoint restored and
-        the batch still staged."""
+        the batch still staged.
+
+        The whole commit is the span ``fleet.commit`` (counters ``added``,
+        ``deleted``, ``merged`` partitions, ``published_bytes`` put to the
+        store, ``h2d_bytes`` the commit placed on the device itself), its
+        phases ``fleet.commit.apply`` (stats, vocab, tiers),
+        ``.write`` (the writers' pack and upload), ``.publish`` (the shared
+        state and every manifest), ``.kv`` (passages), ``.prewarm`` (the
+        generation's idf, then the pools' pings) and ``.gc``."""
         t0 = self.runtime.clock if t_arrival is None else t_arrival
         if not self.pending_adds and not self.pending_deletes:
             return {"gen": self.gen, "committed": False}, 0.0
+        with trace.span("commit") as sp:
+            bytes_in = self.catalog.store.stats.bytes_in
+            result, lat, h2d = self._commit(fn_groups, t0, ping_payload,
+                                            max_publish_retries)
+            sp.set_metadata(
+                added=result["indexed"], deleted=result["deleted"],
+                merged=len(result["merged"]), h2d_bytes=h2d,
+                published_bytes=self.catalog.store.stats.bytes_in - bytes_in)
+        return result, lat
+
+    def _commit(self, fn_groups, t0: float, ping_payload: "dict | None",
+                max_publish_retries: int) -> tuple[dict, float, int]:
         cp = self._checkpoint()
         conflicts = rebased = 0
         while True:
@@ -654,51 +747,85 @@ class FleetIndexer:
                 self._restore(cp)
                 conflicts += 1
                 if conflicts > max_publish_retries:
+                    self._release(cp)
                     raise
             except Exception:
                 self._restore(cp)
+                self._release(cp)
                 raise
+        self._release(cp)
         result["publish_conflicts"] = conflicts
         result["rebased"] = rebased
         # KV content changes land only AFTER the publishes succeeded — a
         # rolled-back commit must neither lose deleted docs' content nor
-        # orphan never-published adds in the doc store. Deletes skip ext
-        # ids this same commit re-added (the put below writes the new
-        # content); adds become fetchable exactly when they become
-        # searchable.
-        for ext in result.pop("_deleted_ids"):
-            if ext not in self._ext_index:
-                self.doc_store.delete(ext)
-        for ext, text in result.pop("_added_docs"):
-            self.doc_store.put(ext, {"id": ext, "contents": text})
+        # orphan never-published adds in the doc store. Adds become
+        # fetchable exactly when they become searchable; a deleted
+        # passage stays while a kept generation can rank it (below).
+        with trace.span("commit.kv", keys=len(result["_added_docs"])):
+            for ext, text in result.pop("_added_docs"):
+                self.doc_store.put(ext, {"id": ext, "contents": text})
+        deleted = result.pop("_deleted_ids")
+        if deleted:
+            self._kv_doomed.append((next_gen, deleted))
 
-        # zero-downtime rollover: hydrate every pool on the new generation
-        # OFF the query path, then gc superseded generations (the serving
-        # and previous manifests — and every segment they pin — survive)
-        pings = rollover_fleet(
-            self.runtime, fn_groups, next_gen,
-            ping_payload=ping_payload, t_arrival=t0 + write_lat)
+        # zero-downtime rollover: the generation's idf goes to the device
+        # once (every pool of the chip shares it), then every pool hydrates
+        # the new generation OFF the query path
+        with trace.span("commit.prewarm") as sp:
+            _, h2d = self.catalog.generation_state(
+                self._stats_ref).device_idf()
+            pings = rollover_fleet(
+                self.runtime, fn_groups, next_gen,
+                ping_payload=ping_payload, t_arrival=t0 + write_lat)
+            sp.set_metadata(h2d_bytes=h2d, pings=len(pings))
         ping_lat = max((r.latency_s for r in pings), default=0.0)
-        for st in self.parts:
-            self.catalog.gc(st.asset, keep=2)
-        self._gc_state_segments()
+        # gc superseded generations (the serving and previous manifests —
+        # and every segment they pin — survive), then the passages no
+        # surviving generation can rank
+        with trace.span("commit.gc"):
+            for st in self.parts:
+                self.catalog.gc(st.asset, keep=2)
+            self._gc_state_segments()
+            self._drop_passages()
+            held = {d for st in self.parts for d in st.deltas}
+            self._packs = {d: p for d, p in self._packs.items() if d in held}
         result["pings"] = len(pings)
         self.commits.append(dict(result, t=t0))
-        return result, write_lat + ping_lat
+        return result, write_lat + ping_lat, h2d
+
+    def _drop_passages(self) -> None:
+        """Delete the passages of documents deleted at generation D once
+        gc keeps no generation older than D — none can rank them any more
+        — unless the id is live again (re-added)."""
+        gens = [parse_generation(v) for st in self.parts
+                for v in self.catalog.versions(st.asset)]
+        oldest = min((g for g in gens if g is not None), default=self.gen)
+        keep = []
+        for gen, ids in self._kv_doomed:
+            if gen > oldest:
+                keep.append((gen, ids))
+                continue
+            for ext in ids:
+                if ext not in self._ext_index:
+                    self.doc_store.delete(ext)
+        self._kv_doomed = keep
 
     def _gc_state_segments(self) -> None:
         """Reclaim shared stats/vocab segments that NO surviving partition
-        manifest references — the same reference-based rule the catalog's
-        own segment gc uses. An age cutoff would be wrong: after a partial
-        publish failure the generation sequence can skip, leaving a kept
-        rollback manifest pointing at a state segment older than the
-        naive keep window. Also sweeps orphans failed commits left."""
+        manifest references, directly or as an ancestor its delta-encoded
+        state resolves through — the same reference-based rule the
+        catalog's own segment gc uses. An age cutoff would be wrong: after
+        a partial publish failure the generation sequence can skip,
+        leaving a kept rollback manifest pointing at a state segment older
+        than the naive keep window. Also sweeps orphans failed commits
+        left."""
         live: set[str] = set()
         for st in self.parts:
             for v in self.catalog.versions(st.asset):
                 m = self.catalog.read_generation(st.asset, v)
                 if m.stats_ref and m.stats_ref[0] == self.stats_asset:
-                    live.add(m.stats_ref[1])
+                    live.update(self.catalog.generation_state(
+                        m.stats_ref).chain)
         self.catalog.sweep_unreferenced(self.stats_asset, live)
 
     def _commit_locked(self, next_gen: int, t0: float) -> tuple[dict, float]:
@@ -706,89 +833,101 @@ class FleetIndexer:
         the billed writer fan-out, and the CAS manifest publishes. Runs
         under ``commit``'s checkpoint — any exception here rolls everything
         back."""
-        # deletes first: tombstone the internal POSITION (a re-add of the
-        # same ext id gets a fresh position the tombstone can't touch) and
-        # fold the doc out of the global stats
-        new_tombs: list[set] = [set() for _ in self.parts]
-        n_del = 0
-        deleted_ids = []
-        for ext in sorted(self.pending_deletes):
-            p, pos, text = self._ext_index.pop(ext)
-            new_tombs[p].add(pos)
-            update_stats(self.stats, text, sign=-1)
-            deleted_ids.append(ext)
-            n_del += 1
-        # adds: round-robin over partitions, fold INTO the global stats
-        # (each doc tokenized ONCE here, shared by stats + vocab growth)
-        staged: list[list] = [[] for _ in self.parts]
-        new_terms: set[str] = set()
-        for ext, text in self.pending_adds:
-            p = self._rr % len(self.parts)
-            self._rr += 1
-            pos = len(self.parts[p].seg_docs) + len(staged[p])
-            staged[p].append((ext, text))
-            self._ext_index[ext] = (p, pos, text)
-            counts = token_counts(text)
-            new_terms.update(counts)
-            update_stats(self.stats, text, sign=1, counts=counts)
-        self.vocab = extend_vocab(self.vocab, new_terms)
-        n_add = len(self.pending_adds)
-        self.pending_adds, self.pending_deletes = [], set()
-        self._pending_ids = set()
+        with trace.span("commit.apply"):
+            # deletes first: tombstone the internal POSITION (a re-add of
+            # the same ext id gets a fresh position the tombstone can't
+            # touch) and fold the doc out of the global stats
+            new_tombs: list[set] = [set() for _ in self.parts]
+            deleted_ids = []
+            for ext in sorted(self.pending_deletes):
+                p, pos, text = self._ext_index[ext]
+                self._ext_put(ext, None)
+                new_tombs[p].add(pos)
+                self.stats.update(token_counts(text), text, sign=-1)
+                deleted_ids.append(ext)
+            # adds: round-robin over partitions, fold INTO the global
+            # stats (each doc tokenized ONCE here, shared by stats + vocab)
+            staged: list[list] = [[] for _ in self.parts]
+            counts = []
+            for ext, text in self.pending_adds:
+                p = self._rr % len(self.parts)
+                self._rr += 1
+                pos = len(self.parts[p].seg_docs) + len(staged[p])
+                staged[p].append((ext, text))
+                self._ext_put(ext, (p, pos, text))
+                counts.append(token_counts(text))
+            self.stats.add_terms(t for c in counts for t in c)
+            for (_, text), c in zip(self.pending_adds, counts):
+                self.stats.update(c, text, sign=1)
+            n_add = len(self.pending_adds)
+            self.pending_adds, self.pending_deletes = [], set()
+            self._pending_ids = set()
 
         # writer fan-out: every touched partition packs at one arrival
-        recs, plans = [], []
-        for i, st in enumerate(self.parts):
-            st.tombstones |= new_tombs[i]
-            do_merge = self.merge_policy.should_merge(
-                st.base_docs, st.delta_docs + len(staged[i]),
-                len(st.deltas) + (1 if staged[i] else 0),
-                len(st.tombstones))
-            if not staged[i] and not do_merge:
-                plans.append(None)
-                continue
-            st.staged_docs = staged[i]
-            op = "merge" if do_merge else "delta"
-            out, rec = self.runtime.invoke(
-                self._writer_fn(i), {"op": op, "gen": next_gen},
-                t_arrival=t0, write=True)
-            recs.append(rec)
-            plans.append(out)
-        write_lat = max((r.latency_s for r in recs), default=0.0)
+        with trace.span("commit.write"):
+            recs, plans = [], []
+            for i, st in enumerate(self.parts):
+                if new_tombs[i]:
+                    st.tombstones = st.tombstones | new_tombs[i]
+                op = self.merge_policy.plan(
+                    st.base_docs, st.delta_docs + len(staged[i]),
+                    len(st.deltas) + (1 if staged[i] else 0),
+                    len(st.tombstones))
+                if not staged[i] and op is None:
+                    plans.append(None)
+                    continue
+                st.staged_docs = staged[i]
+                out, rec = self.runtime.invoke(
+                    self._writer_fn(i),
+                    {"op": {"base": "merge", "deltas": "merge_deltas",
+                            None: "delta"}[op], "gen": next_gen},
+                    t_arrival=t0, write=True)
+                recs.append(rec)
+                plans.append(out)
+            write_lat = max((r.latency_s for r in recs), default=0.0)
 
         # apply the writers' results, then CAS-publish EVERY partition's
         # manifest at next_gen (global stats moved, so every partition's
         # scoring state did too — untouched segment tiers just re-point)
-        merged_parts = []
-        for i, (st, out) in enumerate(zip(self.parts, plans)):
-            if out is not None and out["op"] == "merge":
-                st.seg_docs = st.live_docs() + st.staged_docs
-                st.base_seg, st.deltas = out["seg"], []
-                st.base_docs, st.delta_docs = len(st.seg_docs), 0
-                st.tombstones = set()
-                if out.get("vec_seg"):
-                    st.vec_base, st.vec_deltas = out["vec_seg"], []
-                # a merge renumbers the partition's internal positions
-                for pos, (ext, text) in enumerate(st.seg_docs):
-                    self._ext_index[ext] = (i, pos, text)
-                merged_parts.append(i)
-            elif out is not None:
-                st.seg_docs = st.seg_docs + st.staged_docs
-                st.deltas = st.deltas + [out["seg"]]
-                st.delta_docs += len(st.staged_docs)
-                if out.get("vec_seg"):
-                    st.vec_deltas = st.vec_deltas + [out["vec_seg"]]
-            st.staged_docs = []
-        self.gen = next_gen
-        # ONE shared stats/vocab segment per generation; every partition's
-        # manifest references it instead of inlining O(vocab) bytes each
-        self._stats_ref = self.catalog.publish_generation_state(
-            self.stats_asset, next_gen, self.stats, self.vocab,
-            writer={"rr": self._rr} if self._forked else None)
-        for st in self.parts:
-            self.catalog.publish_generation(st.asset, self._manifest(st))
+        with trace.span("commit.publish"):
+            merged_parts, base_merged = [], []
+            for i, (st, out) in enumerate(zip(self.parts, plans)):
+                if out is not None and out["op"] == "merge":
+                    st.seg_docs = st.live_docs() + st.staged_docs
+                    st.base_seg, st.deltas = out["segs"][0], []
+                    st.base_docs, st.delta_docs = len(st.seg_docs), 0
+                    st.tombstones = set()
+                    if out["vec_segs"]:
+                        st.vec_base, st.vec_deltas = out["vec_segs"][0], []
+                    # a merge renumbers the partition's internal positions
+                    for pos, (ext, text) in enumerate(st.seg_docs):
+                        self._ext_put(ext, (i, pos, text))
+                    merged_parts.append(i)
+                    base_merged.append(i)
+                elif out is not None:
+                    merge = out["op"] == "merge_deltas"
+                    st.seg_docs.extend(st.staged_docs)
+                    st.deltas = ([] if merge else st.deltas) + out["segs"]
+                    st.delta_docs += len(st.staged_docs)
+                    if out["vec_segs"]:
+                        st.vec_deltas = (([] if merge else st.vec_deltas)
+                                         + out["vec_segs"])
+                    if merge:
+                        merged_parts.append(i)
+                st.staged_docs = []
+            self.gen = next_gen
+            # ONE shared stats/vocab segment per generation, written as
+            # what this commit changed; every partition's manifest
+            # references it instead of inlining O(vocab) bytes each
+            self._stats_ref = self.catalog.publish_generation_state(
+                self.stats_asset, next_gen, self.stats, self.vocab,
+                writer={"rr": self._rr} if self._forked else None)
+            self.stats.ref = self._stats_ref
+            for st in self.parts:
+                self.catalog.publish_generation(st.asset, self._manifest(st))
         return {"gen": next_gen, "committed": True, "indexed": n_add,
-                "deleted": n_del, "merged": merged_parts,
+                "deleted": len(deleted_ids), "merged": merged_parts,
+                "base_merged": base_merged,
                 "writers": len(recs), "_deleted_ids": deleted_ids,
                 "_added_docs": [d for part in staged for d in part]}, write_lat
 
@@ -970,12 +1109,11 @@ class PartitionedSearchApp:
             n = ix.stage_delete(body["ids"])
             return {"staged": True, "pending_deletes": n}, ENQUEUE_COST_S, None
         if op == "commit":
-            # rollover prewarm: partial, term-frequency-ranked — each ping
-            # hydrates the new generation's superindex + the top-df terms'
-            # blocks (and the dense tier's live rows, when one exists)
-            # instead of backfilling the whole partition; the cold tail
-            # still lazy-loads on demand. Eager fleets hydrate fully, as
-            # before.
+            # rollover prewarm: each ping derives the new generation from
+            # the one its pool holds (searcher.derive_searcher); where it
+            # cannot, it hydrates the superindex + the top-df terms' blocks
+            # (and the dense tier's live rows, when one exists) and the
+            # cold tail lazy-loads on demand. Eager fleets hydrate fully.
             ping = {"q": "", "k": 1, "fetch_docs": False,
                     "prewarm_terms": PREWARM_TOP_TERMS}
             if self.embedder is not None:

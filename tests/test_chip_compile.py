@@ -25,7 +25,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 from repro.kernels.dot_topk import padded_rows
-from repro.search.bm25 import SearchState, make_search_fn
+from repro.search.bm25 import (TAIL_MIN_DOCS, TAIL_MIN_SLOTS, SearchState,
+                               TailState, make_search_fn)
 
 W1_CHIP_DOCS = 2_210_456        # 8,841,823 passages / 4 chips
 W1_VOCAB = 1 << 19
@@ -140,6 +141,28 @@ def test_dense_search_fn_compiles(one_chip):
         idf=S((W1_VOCAB,), jnp.float32),
         avgdl=S((), jnp.float32), k1=S((), jnp.float32), b=S((), jnp.float32),
         n_docs=W1_CHIP_DOCS)
+    fn = make_search_fn(W1_CHIP_DOCS, max_terms=T, max_blocks=M, k=10,
+                        accumulator="dense")
+    _compile(fn, state, S((Q, T), jnp.int32), S((Q, T), jnp.float32))
+
+
+def test_dense_search_fn_with_a_tail_compiles(one_chip):
+    """A generation derived after a commit: the same program over the
+    base's arrays plus the tail (a row of (term, tf) slots per added
+    document) and an idf padded past the vocabulary."""
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    tail = TailState(terms=S((TAIL_MIN_DOCS, TAIL_MIN_SLOTS), jnp.int32),
+                     tf=S((TAIL_MIN_DOCS, TAIL_MIN_SLOTS), jnp.uint8),
+                     doc_len=S((TAIL_MIN_DOCS,), jnp.float32))
+    state = SearchState(
+        term_offsets=S((W1_VOCAB + 1,), jnp.int32),
+        block_docs=S((W1_BLOCKS, B), jnp.int32),
+        block_tf=S((W1_BLOCKS, B), jnp.uint8),
+        block_max=S((W1_BLOCKS,), jnp.float32),
+        doc_len=S((W1_CHIP_DOCS + 1,), jnp.float32),
+        idf=S((W1_VOCAB + (1 << 15),), jnp.float32),
+        avgdl=S((), jnp.float32), k1=S((), jnp.float32), b=S((), jnp.float32),
+        n_docs=W1_CHIP_DOCS, tail=tail)
     fn = make_search_fn(W1_CHIP_DOCS, max_terms=T, max_blocks=M, k=10,
                         accumulator="dense")
     _compile(fn, state, S((Q, T), jnp.int32), S((Q, T), jnp.float32))

@@ -528,16 +528,23 @@ def test_commit_survives_runtime_straggler_hedge():
 def test_delete_removes_raw_document_content():
     """An index tombstone alone is cosmetic — the KV record must go too
     (data deletion is the usual reason to delete), except when the same
-    commit re-adds the id (update: new content survives)."""
+    commit re-adds the id (update: new content survives). It goes once no
+    generation gc keeps can rank it: the commit that deletes it keeps the
+    previous generation (a query pinned there still fetches it), the next
+    commit retires that generation and the passage with it."""
     docs = synth_corpus(60, vocab=150, seed=12)
     app = build_app(docs[:50], n_parts=2)
     gone, updated = docs[0][0], docs[1][0]
     assert gone in app.doc_store and updated in app.doc_store
     app.delete_documents([gone, updated])
-    app.add_documents([(updated, "replacement text body")] + docs[50:])
+    app.add_documents([(updated, "replacement text body")] + docs[50:55])
     # staged only — content still fetchable until the commit lands
     assert gone in app.doc_store
     assert app.commit().ok
+    assert gone in app.doc_store          # generation 1 still ranks it
+    assert app.doc_store.get(updated)["contents"] == "replacement text body"
+    app.add_documents(docs[55:])
+    assert app.commit().ok                # generation 1 retired
     assert gone not in app.doc_store              # content really deleted
     assert app.doc_store.get(updated)["contents"] == "replacement text body"
 
