@@ -26,6 +26,9 @@ The spans, from the gateway down:
 * ``fleet.bm25`` / ``fleet.dense`` — one tier's device call, until its
   outputs are on the host (``queries``, ``padded``, ``h2d_bytes``: the
   host arrays handed to the device, :func:`host_nbytes`, once per call);
+  ``fleet.bm25`` also counts ``gathered``, the postings its (T, M) rung
+  gathers (padded · T · M · block), and ``full``, 1 when it ran the full
+  (``max_terms``, ``max_blocks``) rung, else 0;
 * ``fleet.backfill`` — a lazy partition's off-path backfill;
 * ``fleet.merge``, ``fleet.kv``, ``fleet.materialize`` — the
   coordinator's gather: merge and fusion, the KV fetch (``keys``), the

@@ -19,6 +19,12 @@ Fixed-shape, jit-compatible score-at-a-time evaluation:
                    reference with the identical keep mask.
 * top-k over accumulated scores.
 
+T and M are the shape of one compiled program. A call runs the smallest
+rung of a fixed ladder (:func:`rung_ladder`) that holds its batch's terms
+and their blocks (:func:`batch_need`); a smaller rung gathers the same
+postings in the same order and leaves out only invalid ones, which add
+0.0, so it scores and ranks bit for bit as the full shape does.
+
 A state derived for a later index generation (``SearchState.tail``, dense
 accumulator only) scores its base segment as above and the documents its
 commits added since — the TAIL — in the same call: a small forward index,
@@ -375,6 +381,42 @@ def make_search_fn(n_docs: int, *, max_terms: int, max_blocks: int, k: int,
         return jax.vmap(lambda t, w: one_query(state, t, w))(term_ids, qtf)
 
     return search
+
+
+# The (T, M) shapes a call may run, smallest first, as caps on max_terms
+# and max_blocks; the last rung is the configured caps themselves.
+SMALL_RUNG = (4, 16)
+
+
+def rung_ladder(max_terms: int, max_blocks: int) -> list[tuple[int, int]]:
+    """The ladder of (T, M) rungs under the caps: the small rung, cut to
+    them, then the full (``max_terms``, ``max_blocks``)."""
+    small = (min(SMALL_RUNG[0], max_terms), min(SMALL_RUNG[1], max_blocks))
+    full = (max_terms, max_blocks)
+    return [small, full] if small != full else [full]
+
+
+def batch_need(term_ids: np.ndarray, term_offsets: np.ndarray
+               ) -> tuple[int, int]:
+    """(T, M) a batch of encoded queries needs: the columns that hold a
+    term, and the most blocks any of its terms has under ``term_offsets``
+    (the partition's, on the host). A term id past them has none, as the
+    device's clamped gather reads it."""
+    valid = term_ids >= 0
+    cols = np.flatnonzero(valid.any(axis=0))
+    v = len(term_offsets) - 1
+    tid = np.minimum(term_ids[valid], v)
+    n_blk = term_offsets[np.minimum(tid + 1, v)] - term_offsets[tid]
+    return (int(cols[-1]) + 1 if len(cols) else 0,
+            int(n_blk.max(initial=0)))
+
+
+def choose_rung(ladder: list[tuple[int, int]], need: tuple[int, int]
+                ) -> tuple[int, int]:
+    """The smallest rung that holds ``need``; else the full one, which
+    reads at most ``max_blocks`` blocks a term."""
+    t, m = need
+    return next((r for r in ladder if r[0] >= t and r[1] >= m), ladder[-1])
 
 
 _JITTED: dict = {}

@@ -33,8 +33,9 @@ from repro.index.hydration import (LazyIndex, LazyVectors, SuperIndexMissing,
 from repro.index.tokenizer import tokenize
 from repro.kernels.dot_topk import dot_topk_batch, padded_rows
 from repro.search.bm25 import (TAIL_MIN_DOCS, TAIL_MIN_SLOTS, SearchState,
-                               TailState, encode_queries, jitted_search_fn,
-                               pow2_at_least)
+                               TailState, batch_need, choose_rung,
+                               encode_queries, jitted_search_fn,
+                               pow2_at_least, rung_ladder)
 from repro.search.query import query_from_payload
 from repro.search.structured import (StructuredUnsupported,
                                      evaluate_structured, facet_counts,
@@ -96,9 +97,9 @@ class DenseTierMissing(Exception):
     """This asset version carries no dense-vector tier."""
 
 
-# batch shapes each jitted search program has served, and the (program,
-# batch, state signature) triples already run: a derived generation runs
-# the shapes its program has seen once, off the query path
+# batch shapes each jitted search program (one a rung) has run, and the
+# (program, batch, state signature) triples already run: a derived
+# generation runs the shapes its programs have seen once, off the query path
 _SHAPES_SEEN: dict[int, set] = {}
 _WARMED: set = set()
 
@@ -120,18 +121,22 @@ class Searcher:
         self.host_idf = packed.idf
         self.doc_ids = packed.meta.doc_ids
         self.n_docs = packed.meta.n_docs
-        self._fn = self._jitted(packed.meta.n_docs)
+        self.term_offsets = packed.term_offsets   # the device's, on the host
+        self._rungs = self._programs(packed.meta.n_docs)
         # the shapes a generation derived from this one will have (set by
         # LazySearcher): run once in every batch shape this one serves, so
         # that a later generation finds its programs compiled at set-up
         self.twin: SearchState | None = None
 
-    def _jitted(self, n_docs: int):
+    def _programs(self, n_docs: int) -> dict:
+        """{(T, M): jitted program} for each rung of the ladder, smallest
+        first."""
         cfg = self.config
-        return jitted_search_fn(
-            n_docs, max_terms=cfg.max_terms, max_blocks=cfg.max_blocks,
-            k=cfg.k, accumulator=cfg.accumulator, use_kernel=cfg.use_kernel,
+        return {(t, m): jitted_search_fn(
+            n_docs, max_terms=t, max_blocks=m, k=cfg.k,
+            accumulator=cfg.accumulator, use_kernel=cfg.use_kernel,
             use_topk_kernel=cfg.use_topk_kernel)
+            for t, m in rung_ladder(cfg.max_terms, cfg.max_blocks)}
 
     def search(self, queries: list[str]) -> tuple[np.ndarray, np.ndarray]:
         # Pad the batch to the next power of two: the jitted fn specializes
@@ -144,27 +149,38 @@ class Searcher:
                                        max_terms=self.config.max_terms,
                                        idf=self.host_idf,
                                        n_terms=self.n_terms)
+            ladder = list(self._rungs)
+            t, m = rung = choose_rung(ladder,
+                                      batch_need(tids, self.term_offsets))
+            fn, tids, qtf = self._rungs[rung], tids[:, :t], qtf[:, :t]
         with trace.span("bm25", queries=Q, padded=Qp,
-                        h2d_bytes=trace.host_nbytes(tids, qtf)):
-            vals, ids = self._fn(self.state, tids, qtf)
+                        h2d_bytes=trace.host_nbytes(tids, qtf),
+                        gathered=Qp * t * m * self.state.block_docs.shape[1],
+                        full=int(rung == ladder[-1])):
+            vals, ids = fn(self.state, tids, qtf)
             out = np.asarray(vals)[:Q], np.asarray(ids)[:Q]
-        _SHAPES_SEEN.setdefault(id(self._fn), set()).add(Qp)
-        _WARMED.add((id(self._fn), Qp, _signature(self.state)))
+        _WARMED.add((id(fn), Qp, _signature(self.state)))
+        self._warm(self.state, Qp)
         if self.twin is not None:
             self._warm(self.twin, Qp)
         return out
 
-    def _warm(self, state: SearchState, qp: int) -> bool:
-        """Run ``state`` once in batch shape ``qp`` unless that program
-        already ran; True if it ran."""
-        key = (id(self._fn), qp, _signature(state))
-        if key in _WARMED:
-            return False
-        tids = np.full((qp, self.config.max_terms), -1, np.int32)
-        qtf = np.zeros((qp, self.config.max_terms), np.float32)
-        jax.block_until_ready(self._fn(state, tids, qtf))
-        _WARMED.add(key)
-        return True
+    def _warm(self, state: SearchState, qp: int) -> int:
+        """Run ``state`` once in batch shape ``qp`` in each rung whose
+        program has not run it; returns the programs run."""
+        ran = 0
+        sig = _signature(state)
+        for (t, _), fn in self._rungs.items():
+            _SHAPES_SEEN.setdefault(id(fn), set()).add(qp)
+            key = (id(fn), qp, sig)
+            if key in _WARMED:
+                continue
+            tids = np.full((qp, t), -1, np.int32)
+            qtf = np.zeros((qp, t), np.float32)
+            jax.block_until_ready(fn(state, tids, qtf))
+            _WARMED.add(key)
+            ran += 1
+        return ran
 
     def search_batch(self, queries: list[str],
                      k: int | None = None) -> list[list[tuple[int, float]]]:
@@ -183,11 +199,12 @@ class Searcher:
         return self.search_batch([query], k)[0]
 
     def warm_programs(self) -> int:
-        """Run this state once in every batch shape its program has served
-        and this state's shapes have not (a tail that outgrew its
-        capacity, say), off the query path. Returns the shapes run."""
-        return sum(self._warm(self.state, qp)
-                   for qp in sorted(_SHAPES_SEEN.get(id(self._fn), ())))
+        """Run this state once in every rung and batch shape its programs
+        have run and this state's shapes have not (a tail that outgrew its
+        capacity, say), off the query path. Returns the programs run."""
+        seen = set().union(*(_SHAPES_SEEN.get(id(fn), ())
+                             for fn in self._rungs.values()))
+        return sum(self._warm(self.state, qp) for qp in sorted(seen))
 
 
 # -- generations derived from an earlier one's hydrated state -------------------
@@ -280,15 +297,16 @@ def segment_postings(pack: PackedIndex, start: int):
 @dataclasses.dataclass
 class Lineage:
     """What a generation's hydrated state hands the next one: the FIXED
-    layout (one whole-segment-set hydration: its device arrays, host doc
-    lengths and ids, its ``n_fixed`` documents), the tail grown since, the
-    segments covered (id -> documents, base first) and the tombstones
-    applied."""
+    layout (one whole-segment-set hydration: its device arrays, host term
+    offsets, doc lengths and ids, its ``n_fixed`` documents), the tail
+    grown since, the segments covered (id -> documents, base first) and
+    the tombstones applied."""
 
     base_seg: str
     seg_docs: dict
     n_fixed: int
     fixed: SearchState
+    fixed_offsets: np.ndarray
     fixed_doc_len: np.ndarray
     fixed_ids: list
     tail: HostTail
@@ -323,9 +341,10 @@ class TailSearcher(Searcher):
         self.host_idf = gstate.idf()
         self.doc_ids = lineage.fixed_ids + lineage.tail.doc_ids
         self.n_docs = lineage.n_fixed + lineage.tail.n_docs
+        self.term_offsets = lineage.fixed_offsets
         self.gen, self.parent_gen = gen, parent_gen
         self.h2d_bytes = h2d_bytes
-        self._fn = self._jitted(lineage.n_fixed)
+        self._rungs = self._programs(lineage.n_fixed)
         self.twin = None
 
     @property
@@ -348,7 +367,8 @@ def _lazy_lineage(parent: "LazySearcher") -> "Lineage | None":
         seg_docs={sid: seg.meta.n_docs
                   for sid, seg in zip(idx.segment_ids, idx.segments)},
         n_fixed=packed.meta.n_docs, fixed=searcher.state,
-        fixed_doc_len=packed.doc_len, fixed_ids=packed.meta.doc_ids,
+        fixed_offsets=packed.term_offsets, fixed_doc_len=packed.doc_len,
+        fixed_ids=packed.meta.doc_ids,
         tail=HostTail.empty(), tail_dev=None,
         dead=np.asarray(sorted(idx.tombstones), np.int64))
 

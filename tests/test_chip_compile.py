@@ -26,12 +26,14 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops
 from repro.kernels.dot_topk import padded_rows
 from repro.search.bm25 import (TAIL_MIN_DOCS, TAIL_MIN_SLOTS, SearchState,
-                               TailState, make_search_fn)
+                               TailState, make_search_fn, rung_ladder)
 
 W1_CHIP_DOCS = 2_210_456        # 8,841,823 passages / 4 chips
 W1_VOCAB = 1 << 19
 W1_BLOCKS = 1_300_000           # ~ postings/128 + one tail block per term
 Q, T, M, B = 16, 16, 64, 128    # window batch, max_terms, max_blocks, lanes
+RUNGS = pytest.mark.parametrize(
+    "rung", rung_ladder(T, M), ids=lambda r: f"{r[0]}x{r[1]}")
 
 PRUNED_REFUSAL = (
     "Mosaic refuses bm25_pruned_topk: 'Unsupported cast: uint8 -> float32'; "
@@ -129,8 +131,10 @@ def test_bm25_pruned_topk_compiles(one_chip):
         S((T, M), jnp.float32), S((T, M), jnp.bool_))
 
 
-def test_dense_search_fn_compiles(one_chip):
-    """The serving default: pure-XLA gather, scatter-add and top-k."""
+@RUNGS
+def test_dense_search_fn_compiles(one_chip, rung):
+    """The serving default: pure-XLA gather, scatter-add and top-k, in
+    each (T, M) rung a call may run."""
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     state = SearchState(
         term_offsets=S((W1_VOCAB + 1,), jnp.int32),
@@ -141,12 +145,14 @@ def test_dense_search_fn_compiles(one_chip):
         idf=S((W1_VOCAB,), jnp.float32),
         avgdl=S((), jnp.float32), k1=S((), jnp.float32), b=S((), jnp.float32),
         n_docs=W1_CHIP_DOCS)
-    fn = make_search_fn(W1_CHIP_DOCS, max_terms=T, max_blocks=M, k=10,
+    t, m = rung
+    fn = make_search_fn(W1_CHIP_DOCS, max_terms=t, max_blocks=m, k=10,
                         accumulator="dense")
-    _compile(fn, state, S((Q, T), jnp.int32), S((Q, T), jnp.float32))
+    _compile(fn, state, S((Q, t), jnp.int32), S((Q, t), jnp.float32))
 
 
-def test_dense_search_fn_with_a_tail_compiles(one_chip):
+@RUNGS
+def test_dense_search_fn_with_a_tail_compiles(one_chip, rung):
     """A generation derived after a commit: the same program over the
     base's arrays plus the tail (a row of (term, tf) slots per added
     document) and an idf padded past the vocabulary."""
@@ -163,6 +169,7 @@ def test_dense_search_fn_with_a_tail_compiles(one_chip):
         idf=S((W1_VOCAB + (1 << 15),), jnp.float32),
         avgdl=S((), jnp.float32), k1=S((), jnp.float32), b=S((), jnp.float32),
         n_docs=W1_CHIP_DOCS, tail=tail)
-    fn = make_search_fn(W1_CHIP_DOCS, max_terms=T, max_blocks=M, k=10,
+    t, m = rung
+    fn = make_search_fn(W1_CHIP_DOCS, max_terms=t, max_blocks=m, k=10,
                         accumulator="dense")
-    _compile(fn, state, S((Q, T), jnp.int32), S((Q, T), jnp.float32))
+    _compile(fn, state, S((Q, t), jnp.int32), S((Q, t), jnp.float32))

@@ -5,7 +5,8 @@ The contract under test: every ``fleet.`` span appears, nested as the
 served path nests (dispatch ⊃ scatter ⊃ leg ⊃ encode, bm25 | dense;
 dispatch ⊃ merge, kv, materialize); each device call counts the queries
 the gateway batched, the power of two the BM25 call padded them to (the
-dense tier makes one call a query, so pads none) and the bytes of the host
+dense tier makes one call a query, so pads none), the postings a BM25 call
+gathered and whether it ran the full rung, and the bytes of the host
 arrays it handed over (an array already on the device counts none); a
 dense hydration counts its matrix placed on the device once; a window-0
 dispatch waited for nothing; and each request keeps the number of the
@@ -24,12 +25,14 @@ from repro.core.partition import FleetSpec, IndexSpec, VectorSpec
 from repro.core.runtime import RuntimeConfig
 from repro.kernels.dot_topk import padded_rows
 from repro.data.corpus import synth_corpus, synth_queries
+from repro.search.bm25 import rung_ladder
 from repro.search.searcher import DenseSearcher, SearchConfig
 from repro.search.service import build_partitioned_search_app
 
 N_PARTS = 2
 DIM = 16
 CFG = SearchConfig(sim_exec_s=0.002, sim_write_s=0.02)
+(T_SMALL, M_SMALL), _ = rung_ladder(CFG.max_terms, CFG.max_blocks)
 # windows of 1 (sparse traffic: window 0), 5, 1, 3 requests
 GROUPS = (6, 4)
 
@@ -126,8 +129,12 @@ def test_calls_count_batch_padding_and_host_bytes(served):
         bm25 = [s for s in _by_name(spans, "bm25") if _inside(s, d)]
         for s in bm25:
             assert (s[3]["queries"], s[3]["padded"]) == (q, padded)
-            # int32 term ids and f32 term weights, max_terms a query
-            assert s[3]["h2d_bytes"] == padded * CFG.max_terms * (4 + 4)
+            # queries of at most 3 terms, each one block of 128 postings:
+            # the small rung, its T int32 term ids and f32 term weights a
+            # query, T·M blocks of B postings gathered
+            assert s[3]["h2d_bytes"] == padded * T_SMALL * (4 + 4)
+            assert s[3]["gathered"] == padded * T_SMALL * M_SMALL * 128
+            assert s[3]["full"] == 0
         if mode == "hybrid":
             dense = [s for s in _by_name(spans, "dense") if _inside(s, d)]
             assert len(dense) == N_PARTS
